@@ -30,7 +30,7 @@ import numpy as np  # noqa: E402
 
 # One source of truth for per-device peaks: the live MFU accounting and
 # this offline sweep must never disagree on the denominator.
-from mercury_tpu.obs.accounting import PEAK_FLOPS  # noqa: E402
+from mercury_tpu.obs.accounting import peak_flops  # noqa: E402
 
 
 def measure(batch: int, args) -> dict:
@@ -70,12 +70,8 @@ def measure(batch: int, args) -> dict:
     cost = step_fn.lower(
         state, ds.x_train, ds.y_train, ds.shard_indices
     ).compile().cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
     flops_per_img = float(cost.get("flops", 0.0)) / (batch * args.scan)
-    dev = jax.devices()[0]
-    peak = next((v for k, v in PEAK_FLOPS.items()
-                 if dev.device_kind.startswith(k)), None)
+    peak = peak_flops(jax.devices()[0].device_kind)  # None on the CPU
     mfu = (flops_per_img * ips / peak) if (peak and flops_per_img) else None
     return {
         "batch": batch,

@@ -108,10 +108,7 @@ def scoring_flops(trainer, n: int):
         .lower(state.params, state.batch_stats, imgs)
         .compile()
     )
-    costs = compiled.cost_analysis()
-    if isinstance(costs, (list, tuple)):  # older jax returns [dict]
-        costs = costs[0]
-    return float(costs.get("flops", float("nan")))
+    return float(compiled.cost_analysis().get("flops", float("nan")))
 
 
 def _segment(label, trainer, n, counters, scored=None) -> float:

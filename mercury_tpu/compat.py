@@ -1,13 +1,8 @@
-"""Version-compatibility shims for the jax API surface this codebase uses.
+"""The jax API surface this codebase shares across modules, in one place.
 
-The training code targets the modern ``jax.shard_map`` entry point
-(keyword ``check_vma``, manual axes named via ``axis_names``). Older jax
-releases (< 0.5) ship the same machinery as
-``jax.experimental.shard_map.shard_map`` with the complementary spelling:
-``check_rep`` for the replication check and ``auto`` naming the axes that
-stay automatic instead of the axes that go manual. Importing
-:func:`shard_map` from here gives every call site one stable signature —
-the modern one — regardless of which jax is installed.
+The training code uses ``jax.shard_map`` (keyword ``check_vma``, manual
+axes named via ``axis_names``) through :func:`shard_map`, whose one
+convenience is ``axis_names=None`` meaning "manual over every mesh axis".
 
 This module must import nothing from the rest of the package (it is the
 first thing ``parallel/__init__`` pulls in).
@@ -15,128 +10,18 @@ first thing ``parallel/__init__`` pulls in).
 
 from __future__ import annotations
 
-try:  # jax >= 0.5: top-level export, modern keywords — pass through.
-    from jax import shard_map as _shard_map
-
-    _MODERN = True
-except ImportError:  # jax < 0.5: experimental location, legacy keywords.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _MODERN = False
-
-#: True on jax >= 0.5. Legacy jax has sharp edges beyond the shard_map
-#: spelling — e.g. jit out_shardings on PRNG key arrays under a
-#: partial-manual mesh trip a GSPMD rank-validation bug (the hidden
-#: [..., 2] key payload dim is not appended to the tile assignment).
-MODERN_JAX = _MODERN
+from jax import shard_map as _shard_map
+from jax.lax import axis_size as axis_size  # noqa: F401
+from jax.lax import pcast as pcast  # noqa: F401
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
               axis_names=None):
-    """``jax.shard_map`` with the modern keyword surface on any jax.
-
-    ``axis_names`` names the axes the body is manual over (None = all of
-    them); ``check_vma`` toggles the varying-manual-axes / replication
-    check. On legacy jax these translate to ``auto`` (the complement of
-    ``axis_names`` within the mesh) and ``check_rep``.
-    """
-    if _MODERN:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma, **kw)
-    auto = frozenset()
+    """``jax.shard_map``; ``axis_names`` names the axes the body is manual
+    over (None = all of them), ``check_vma`` toggles the varying-manual-
+    axes check."""
+    kw = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    # check_rep stays off on legacy jax regardless of check_vma: the old
-    # replication checker cannot see through psum_scatter/ppermute chains
-    # (e.g. the sequence-parallel step on a data×seq mesh) and rejects
-    # valid replicated out_specs that the modern check_vma accepts. The
-    # check is advisory — partitioning semantics are unchanged.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, auto=auto)
-
-
-try:  # modern jax: first-class query for a named axis's size.
-    from jax.lax import axis_size as axis_size  # noqa: F401
-except ImportError:  # legacy jax: psum of the Python literal 1 is
-    # constant-folded to the same static integer (this was the idiomatic
-    # spelling before lax.axis_size existed), so shapes derived from it
-    # stay static.
-    def axis_size(axis_name):
-        """Static size of the named mesh axis inside a shard_map body."""
-        from jax import lax
-
-        return lax.psum(1, axis_name)
-
-
-def donate_argnums(*argnums):
-    """Buffer-donation argnums for ``jax.jit`` — empty on legacy jax.
-
-    Legacy jax (< 0.5) has a CPU correctness bug in the persistent
-    compilation cache: an executable deserialized from a cache *hit*
-    mishandles the input-output aliasing that donation sets up, so a
-    donated train step can silently drop its parameter update (the same
-    program compiled on a cache miss is correct). Donation is purely a
-    memory optimization — disabling it on legacy jax trades peak memory
-    for correctness and keeps the cache usable. Modern jax donates as
-    written.
-    """
-    return tuple(argnums) if MODERN_JAX else ()
-
-
-try:  # modern jax: cast a value's varying-manual-axes (vma) type.
-    from jax.lax import pcast as pcast  # noqa: F401
-except ImportError:  # legacy jax has no vma type system (and the
-    # replication check above is off), so the annotation is a no-op.
-    def pcast(x, axis_name, *, to):
-        """Identity on legacy jax; vma cast on modern jax."""
-        del axis_name, to
-        return x
-
-
-def register_compile_listener(callback):
-    """Subscribe ``callback(event_name)`` to jax's trace/compile events.
-
-    On jax builds that ship ``jax.monitoring``, the duration events
-    ``.../jaxpr_trace_duration`` and ``.../backend_compile_duration``
-    fire once per trace / per XLA compile — exactly the signal the
-    retrace guard (lint/tracecheck.py) counts. Listener registration is
-    permanent on these jax versions (there is no per-listener
-    unregister, only a clear-all that would stomp other subscribers),
-    so callers install ONE process-wide callback and gate it
-    themselves.
-
-    Returns True when the listener was installed; False on legacy jax
-    without ``jax.monitoring``, where callers fall back to polling the
-    jit cache via :func:`jit_cache_size`.
-    """
-    try:
-        from jax import monitoring
-    except ImportError:
-        return False
-    register = getattr(monitoring,
-                       "register_event_duration_secs_listener", None)
-    if register is None:
-        return False
-
-    def _on_event(event, duration_secs, **kwargs):
-        del duration_secs, kwargs
-        callback(event)
-
-    register(_on_event)
-    return True
-
-
-def jit_cache_size(fn):
-    """Entries in ``fn``'s jit cache, or -1 when this jax build doesn't
-    expose it. The legacy-jax fallback for counting retraces: a growing
-    cache across steady-state calls IS a retrace, whoever caused it."""
-    probe = getattr(fn, "_cache_size", None)
-    if callable(probe):
-        try:
-            return int(probe())
-        except Exception:
-            return -1
-    return -1
+        kw["axis_names"] = frozenset(axis_names)
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=check_vma, **kw)

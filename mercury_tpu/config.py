@@ -401,7 +401,7 @@ class TrainConfig:
     # path can be exercised end-to-end. 0 disables.
     anomaly_inject_nan_step: int = 0
     # --- SLOs: declarative health floors, evaluated continuously by the
-    # anomaly engine and shared with bench.py's --strict-stale gate.
+    # anomaly engine.
     # MFU floor (fraction of peak). Checked only when the device peak is
     # known AND cost analysis produced FLOPs (never on CPU hosts). The
     # committed TPU headline is 0.0185; 0.01 trips on a >~2x regression.
@@ -539,22 +539,22 @@ class TrainConfig:
     # True/False force. Pallas path requires label_smoothing == 0.
     use_pallas: Optional[bool] = None
     # Fused uint8 ingest: replace the normalize_images + augment_batch HLO
-    # chain with ops.augment_normalize_pallas — dequant → per-channel
-    # normalize → crop/flip in one VMEM pass (raw bytes enter device
-    # memory as uint8, 4× less HBM traffic), under the mercury_input_fuse
-    # named scope. Bit-identical trajectories to the unfused path at f32
+    # chain with data.pipeline.augment_normalize — dequant → per-channel
+    # normalize → crop/flip as one chain on the raw bytes (they enter
+    # device memory as uint8), under the mercury_input_fuse named scope.
+    # Bit-identical trajectories to the unfused path at f32
     # (test-enforced); with scoring_dtype="bfloat16" the scorer-only
     # ingest emits bf16 directly (uint8 → bf16 scoring, no f32 round
-    # trip). Runs in interpret mode on CPU. Requires uint8 image data,
-    # augmentation="noniid", cutout=False.
+    # trip). Requires uint8 image data, augmentation="noniid",
+    # cutout=False.
     fused_input: bool = False
 
     # Dispatch --------------------------------------------------------------
     # Train steps fused into ONE device dispatch via lax.scan. The reference
     # pays a host round-trip per step (DataLoader pull + gloo sync,
     # pytorch_collab.py:119-199); with a device-resident dataset the whole
-    # K-step chunk runs as a single XLA program — essential when dispatch
-    # latency rivals step compute (small models, tunneled chips).
+    # K-step chunk runs as a single XLA program — it pays off where
+    # per-dispatch host cost rivals step compute (small models).
     scan_steps: int = 1
 
     @property

@@ -132,6 +132,45 @@ def augment_batch(
     return out
 
 
+def augment_normalize(
+    key: jax.Array,
+    raw: jax.Array,
+    mean,
+    std,
+    pad: int = 4,
+    out_dtype=jnp.float32,
+) -> jax.Array:
+    """Fused uint8 ingest (``fused_input=True``): dequant + per-channel
+    normalize + random crop(``pad``) + horizontal flip as one chain on the
+    raw bytes, bit-identical (at f32) to
+    ``augment_batch(key, normalize_images(raw, mean, std))``.
+
+    ``raw``: [N, H, W, C] uint8; ``mean``/``std``: per-channel constants.
+    ``out_dtype`` is applied as the LAST op, so the bf16 scoring path
+    (``scoring_dtype="bfloat16"`` + ``fused_input``) emits bf16 activations
+    directly — one rounding of the exact f32 value.
+
+    The crop/flip draws replay ``augment_batch``'s key consumption exactly
+    (split 3 ways; ``randint`` for offsets, ``bernoulli`` for flips), so a
+    trajectory is reproducible from the same JAX key on either path. Runs
+    under the ``mercury_input_fuse`` named scope — the profile-attribution
+    bucket (``prof/scope_frac/mercury_input_fuse``) and the jaxpr auditor
+    both key on this anchor."""
+    n, h, w, _ = raw.shape
+    # Mirror augment_batch's split even though cutout is unsupported here
+    # (config validation rejects fused_input + cutout): the draw STREAM
+    # must match so unfused trajectories replay bit-for-bit.
+    k_crop, k_flip, _k_cut = jax.random.split(key, 3)
+    off = jax.random.randint(k_crop, (n, 2), 0, 2 * pad + 1)
+    flip = jax.random.bernoulli(k_flip, shape=(n,))
+    with jax.named_scope("mercury_input_fuse"):
+        xn = normalize_images(raw, mean, std)
+        padded = jnp.pad(xn, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+        out = _take_crops(padded, off[:, 0], off[:, 1], h, w)
+        out = jnp.where(flip[:, None, None, None], out[:, :, ::-1, :], out)
+        return out.astype(jnp.dtype(out_dtype))
+
+
 def next_pool(
     stream: ShardStream,
     key: jax.Array,

@@ -13,10 +13,9 @@ of the traced program* as data:
 - **Zero host callbacks** when ``telemetry=False`` (hard invariant — a
   stray ``debug_callback`` would put a host round-trip on every step).
 - **Donation aliasing**: the count of ``tf.aliasing_output`` /
-  ``jax.buffer_donor`` markers in the lowered StableHLO must match what
-  :func:`mercury_tpu.compat.donate_argnums` configures (on legacy jax the
-  shim disables donation, so the recorded budget is 0 — the audit checks
-  *consistency*, not a hard-coded count).
+  ``jax.buffer_donor`` markers in the lowered StableHLO must cover what
+  ``make_train_step`` donates (the state; under ``host_stream`` the
+  streamed slab too).
 - **bf16 scoring stays bf16**: with ``scoring_dtype="bfloat16"``, zero
   f32×f32 dot/conv ops inside the ``mercury_scoring`` scope (hard
   invariant — a silent upcast would erase the plan's FLOP savings).
@@ -70,6 +69,7 @@ COLLECTIVE_PRIMS = frozenset({
 CALLBACK_PRIMS = frozenset({
     "pure_callback", "io_callback", "debug_callback", "outside_call",
     "host_callback_call", "python_callback",
+    "debug_print",  # what jax.debug.print traces to on jax 0.9
 })
 SCOPES = ("mercury_scoring", "mercury_grad_sync")
 DONATION_MARKERS = ("tf.aliasing_output", "jax.buffer_donor")
@@ -87,7 +87,7 @@ def ensure_cpu_devices(n: int = 8) -> None:
         # Probe device count ONLY when a backend is already live: calling
         # jax.devices() on a merely-imported jax would itself initialize
         # a 1-device backend and make the XLA_FLAGS below a no-op (the
-        # tracecheck CLI hits this — importing compat pulls in jax).
+        # tracecheck CLI hits this — importing the builders pulls in jax).
         xb = sys.modules.get("jax._src.xla_bridge")
         if xb is not None and getattr(xb, "_backends", None):
             import jax
@@ -198,16 +198,12 @@ def measure_step(step_fn, args: Tuple, plan: str,
     structural facts."""
     import jax
 
-    from mercury_tpu.compat import donate_argnums
-
     m = PlanMeasurement(plan=plan, config=config)
     # host_stream plans donate the streamed slab (arg 1) on top of the
-    # state (arg 0) — mirror make_train_step's donate_argnums call so the
+    # state (arg 0) — mirror make_train_step's donate_argnums so the
     # consistency check below audits what the step actually configures.
-    if config.get("data_placement") == "host_stream":
-        m.expected_donated_args = len(donate_argnums(0, 1))
-    else:
-        m.expected_donated_args = len(donate_argnums(0))
+    m.expected_donated_args = (
+        2 if config.get("data_placement") == "host_stream" else 1)
 
     closed = jax.make_jaxpr(step_fn)(*args)
     for scope in SCOPES:
@@ -420,12 +416,11 @@ def _build_hs(shard_mode: str = None):
 
 def _build_hs_fused():
     """host_stream with the fused uint8 ingest AND end-to-end bf16
-    scoring: ``augment_normalize_pallas`` replaces the normalize+augment
-    HLO chain (interpret-mode on the CPU audit — same jaxpr structure as
-    the Mosaic lowering) and the scoring forward runs bf16 from uint8 to
-    score. Gets its OWN plan entry so the fused program carries its own
+    scoring: ``data.pipeline.augment_normalize`` replaces the
+    normalize+augment HLO chain and the scoring forward runs bf16 from
+    uint8 to score. Gets its OWN plan entry so the fused program carries its own
     ``scoring_ops`` budget and donation-consistency check — the streamed
-    slab must stay donated when the kernel consumes it."""
+    slab must stay donated when the fused chain consumes it."""
     import jax
 
     from mercury_tpu.config import TrainConfig
@@ -586,12 +581,6 @@ def check_invariants(m: PlanMeasurement) -> List[str]:
                 f"scope {m.scoped_collectives['mercury_scoring']} with "
                 "refresh_mode=async (expected none: no scoring forward, "
                 "no scoring collectives)")
-    if m.donation_markers >= 0 and m.expected_donated_args == 0 \
-            and m.donation_markers != 0:
-        errors.append(
-            f"plan {m.plan}: {m.donation_markers} donation marker(s) in "
-            "the lowered program but compat.donate_argnums configures "
-            "none on this jax version")
     if m.donation_markers >= 0 \
             and m.donation_markers < m.expected_donated_args:
         # Donation consistency, the other direction: every configured

@@ -379,11 +379,8 @@ def measure_perf_step(step_fn, args: Tuple, plan: str,
     lower_fn = step_fn if hasattr(step_fn, "lower") else jax.jit(step_fn)
     compiled = lower_fn.lower(*args).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    if isinstance(cost, dict):
-        m.cost_flops = float(cost.get("flops", 0.0) or 0.0)
-        m.cost_bytes = float(cost.get("bytes accessed", 0.0) or 0.0)
+    m.cost_flops = float(cost.get("flops", 0.0) or 0.0)
+    m.cost_bytes = float(cost.get("bytes accessed", 0.0) or 0.0)
     scan = scan_hlo(compiled.as_text(), plan)
     m.f32_scoring_converts = scan["f32_scoring_converts"]
     m.scope_layout_ops = scan["scope_layout_ops"]

@@ -978,11 +978,7 @@ def _check_hop(findings: List[str], plan: str, hop: str,
     # GLE09 re-seed: new keys pairwise distinct and distinct from every
     # checkpointed key (a copy would replay the old draw sequence).
     def key_rows(rng):
-        try:
-            data = jax.random.key_data(rng)
-        except (TypeError, AttributeError):
-            data = rng  # raw uint32 key data under legacy jax
-        arr = np.asarray(data)
+        arr = np.asarray(jax.random.key_data(rng))
         return [bytes(row.tobytes()) for row in arr]
 
     old_keys = set(key_rows(s_old.rng))

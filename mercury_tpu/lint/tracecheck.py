@@ -9,24 +9,18 @@ the wall clock doesn't.
 
 The harness builds each plan from the shared Layer 2 builder matrix,
 then *executes* the step ``steps`` times on the CPU mesh while counting
-jax trace/compile events:
-
-- On jax builds with ``jax.monitoring``, one process-wide listener
-  (installed via :func:`mercury_tpu.compat.register_compile_listener`)
-  counts ``jaxpr_trace_duration`` / ``backend_compile_duration`` events
-  and fans them out to the active :class:`CompileMonitor`\\ s.
-- On legacy jax without it, the monitor falls back to polling the step
-  function's jit cache (:func:`mercury_tpu.compat.jit_cache_size`):
-  cache growth across steady-state calls IS a retrace, whoever caused
-  it.
+jax trace/compile events: one process-wide ``jax.monitoring`` listener
+counts ``jaxpr_trace_duration`` / ``backend_compile_duration`` events
+and fans them out to the active :class:`CompileMonitor`\\ s.
 
 The first :data:`WARMUP_CALLS` calls are the *warmup*: call 1 traces
-and compiles, and call 2 legitimately compiles once more on every plan
-— the trainer places its initial state as uncommitted
-``SingleDeviceSharding`` arrays, the step's output state comes back as
-committed ``NamedSharding``, so the second call is the first one with
-the steady-state placement. Calls 3..N are *steady state*, where the
-committed expectation is zero. Every call also records the argument
+and compiles; call 2 is kept in the warmup for the step builders that
+do not go through ``Trainer`` (sp / pp hand the step an uncommitted
+initial state, whose committed output then feeds back in — one
+"placement settle" compile). ``Trainer`` commits state and step inputs
+on the mesh at construction, so its plans compile on call 1 only. Calls
+3..N are *steady state*, where the committed expectation is zero. Every
+call also records the argument
 signature — ``(shape, dtype, weak_type, sharding)`` per leaf — so when
 steady state does compile, the finding names exactly which argument
 leaf churned (or states that the signatures were identical, pointing
@@ -53,11 +47,12 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from mercury_tpu import compat
 from mercury_tpu.lint.audit import PLAN_NAMES, _BUILDERS, ensure_cpu_devices
 
 _TRACE_SUFFIX = "jaxpr_trace_duration"
 _COMPILE_SUFFIX = "backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
 
 #: Calls whose trace/compile events count as warmup, not steady state:
 #: call 1 primes, call 2 settles the state placement (see module doc).
@@ -65,47 +60,71 @@ WARMUP_CALLS = 2
 
 _lock = threading.Lock()
 _active: List["CompileMonitor"] = []
-_listener_state: Optional[bool] = None  # None = not yet installed
+_listener_installed = False
 
 
-def _dispatch(event: str) -> None:
+def _dispatch(event: str, duration_secs: float = 0.0, **kwargs) -> None:
+    del kwargs
     if event.endswith(_TRACE_SUFFIX):
         kind = "trace"
     elif event.endswith(_COMPILE_SUFFIX):
         kind = "compile"
+    elif event == _CACHE_HIT:
+        kind = "cache_hit"
+    elif event == _CACHE_MISS:
+        kind = "cache_miss"
     else:
         return
     with _lock:
         monitors = list(_active)
     for m in monitors:
-        m._record(kind)
+        m._record(kind, duration_secs)
 
 
-def _ensure_listener() -> bool:
-    """Install the process-wide listener once; True when event counting
-    is available on this jax build."""
-    global _listener_state
-    if _listener_state is None:
-        _listener_state = compat.register_compile_listener(_dispatch)
-    return _listener_state
+def _ensure_listener() -> None:
+    """Install the process-wide listener once. jax offers no per-listener
+    unregister (only a clear-all that would stomp other subscribers), so
+    ONE permanent callback fans out to whichever monitors are active."""
+    global _listener_installed
+    with _lock:
+        if _listener_installed:
+            return
+        _listener_installed = True
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_dispatch)
+    monitoring.register_event_listener(_dispatch)
 
 
 class CompileMonitor:
     """Counts jax trace/compile events between ``start()`` and
     ``stop()``. Usable as a context manager; thread-safe (scorer-fleet
-    threads compile too, and their events belong in the count)."""
+    threads compile too, and their events belong in the count).
+
+    ``compiles`` counts every trip into the backend compiler, including
+    those the persistent compilation cache answers; ``compile_secs`` is
+    their total duration, and ``cache_hits`` / ``cache_misses`` say how
+    many of them the persistent cache did and did not have."""
 
     def __init__(self) -> None:
         self.traces = 0
         self.compiles = 0
-        self.supported = _ensure_listener()
+        self.compile_secs = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        _ensure_listener()
 
-    def _record(self, kind: str) -> None:
+    def _record(self, kind: str, duration_secs: float) -> None:
         with _lock:
             if kind == "trace":
                 self.traces += 1
-            else:
+            elif kind == "compile":
                 self.compiles += 1
+                self.compile_secs += duration_secs
+            elif kind == "cache_hit":
+                self.cache_hits += 1
+            else:
+                self.cache_misses += 1
 
     def start(self) -> "CompileMonitor":
         with _lock:
@@ -243,8 +262,6 @@ class RetraceMeasurement:
     steady_compiles: int = 0
     #: which call compiled in steady state, and what churned
     churn: List[str] = field(default_factory=list)
-    #: monitor backend: "events" (jax.monitoring) or "jit-cache"
-    backend: str = "events"
 
     def as_budget(self) -> Dict[str, Any]:
         return {
@@ -254,7 +271,7 @@ class RetraceMeasurement:
             "warmup_compiles": self.warmup_compiles,
             "steady_traces": self.steady_traces,
             "steady_compiles": self.steady_compiles,
-            "backend": self.backend,
+            "backend": "events",  # golden schema field; the only backend
         }
 
 
@@ -266,26 +283,15 @@ def measure_step_retraces(step_fn, args: Tuple, plan: str,
     the rest must not."""
     m = RetraceMeasurement(plan=plan, steps=steps)
     args = _materialize(args)
-    monitor = CompileMonitor()
-    use_cache_poll = not monitor.supported
-    if use_cache_poll:
-        m.backend = "jit-cache"
-
     prev_sig = None
-    with monitor:
+    with CompileMonitor() as monitor:
         for call in range(steps):
             before = monitor.snapshot()
-            cache_before = (compat.jit_cache_size(step_fn)
-                            if use_cache_poll else -1)
             sig = signature_of(args)
             out = step_fn(*args)
             after = monitor.snapshot()
             traces = after[0] - before[0]
             compiles = after[1] - before[1]
-            if use_cache_poll:
-                cache_after = compat.jit_cache_size(step_fn)
-                if cache_before >= 0 and cache_after > cache_before:
-                    compiles += cache_after - cache_before
             if call < WARMUP_CALLS:
                 m.warmup_traces += traces
                 m.warmup_compiles += compiles

@@ -1,9 +1,9 @@
 """Throughput and MFU accounting — the ``benchmarks/mfu_sweep.py``
 numbers, available live on the log cadence instead of only offline.
 
-- :data:`PEAK_FLOPS` — per-device-kind peak (bf16) FLOP/s table (moved
-  here from ``mfu_sweep`` so the live path and the offline sweep share
-  one source of truth).
+- :data:`PEAK_FLOPS` — the tree's ONE per-device-kind peak (bf16)
+  FLOP/s table: the live path, ``bench.py``, the offline sweep and the
+  auto-planner all read it here.
 - :func:`analytic_flops_per_step` — XLA's cost analysis of the LOWERED
   fused step program (a re-trace, never an XLA compile — see the
   function docstring).
@@ -17,22 +17,33 @@ from __future__ import annotations
 import time
 from typing import Dict, Optional
 
+#: Peak dense bf16 FLOP/s of one chip, by ``device_kind`` prefix (first
+#: match wins, so the longer "TPU v5 lite" precedes "TPU v5"). Source:
+#: Google Cloud TPU documentation, the per-generation system-architecture
+#: pages ("TPU v4", "TPU v5e", "TPU v5p", "TPU v6e").
 PEAK_FLOPS = {
-    "TPU v5 lite": 197e12,
+    "TPU v5 lite": 197e12,   # v5e, as jax reports it
     "TPU v5e": 197e12,
-    "TPU v5": 459e12,
+    "TPU v5": 459e12,        # v5p
     "TPU v4": 275e12,
-    "TPU v6": 918e12,
+    "TPU v6": 918e12,        # v6e (Trillium)
 }
 
 
 def peak_flops(device_kind: Optional[str]) -> Optional[float]:
-    """Peak FLOP/s for a device kind, or None when unknown (CPU, new
-    TPU generations not yet tabulated)."""
-    if not device_kind:
+    """Peak FLOP/s for a device kind. None on the CPU (jax reports
+    ``device_kind == "cpu"``; utilization is "not measured" there) and
+    for no device at all; an accelerator kind that is not tabulated is
+    an error — a silent default would turn every MFU into 0.0."""
+    if not device_kind or device_kind.lower().startswith("cpu"):
         return None
-    return next((v for k, v in PEAK_FLOPS.items()
-                 if device_kind.startswith(k)), None)
+    for prefix, peak in PEAK_FLOPS.items():
+        if device_kind.startswith(prefix):
+            return peak
+    raise ValueError(
+        f"no peak FLOP/s tabulated for device kind {device_kind!r}; add "
+        "it to mercury_tpu.obs.accounting.PEAK_FLOPS with its source"
+    )
 
 
 def analytic_flops_per_step(step_fn, *args, scan_steps: int = 1
@@ -48,8 +59,6 @@ def analytic_flops_per_step(step_fn, *args, scan_steps: int = 1
     number. Unoptimized-HLO FLOPs are what the MFU estimate needs."""
     try:
         cost = step_fn.lower(*args).cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
         flops = float(cost.get("flops", 0.0))
     except Exception:
         return None
@@ -63,20 +72,17 @@ class ThroughputMeter:
 
     ``tick(step)`` returns the ``perf/*`` scalars for the interval since
     the previous tick — host floats, no device work. MFU is analytic
-    FLOPs × steps/s against the device's tabulated peak; when either is
-    unknown (e.g. CPU) it reports 0.0 and the manifest's
-    ``peak_flops: null`` marks the estimate as not meaningful."""
+    FLOPs × steps/s against the device's tabulated peak; on the CPU (no
+    peak) or before the FLOPs are known it reports 0.0 and the manifest's
+    ``peak_flops: null`` marks it as not measured."""
 
     def __init__(self, examples_per_step: float,
                  flops_per_step: Optional[float] = None,
                  device_kind: Optional[str] = None) -> None:
         if device_kind is None:
-            try:
-                import jax
+            import jax
 
-                device_kind = jax.devices()[0].device_kind
-            except Exception:
-                device_kind = None
+            device_kind = jax.devices()[0].device_kind
         self.examples_per_step = float(examples_per_step)
         self.flops_per_step = flops_per_step
         self.peak = peak_flops(device_kind)

@@ -60,23 +60,17 @@ def build_run_manifest(config, mesh=None,
         "process_index": jax.process_index(),
         "process_count": jax.process_count(),
     }
-    try:
-        dev = jax.devices()[0]
-        manifest["device_kind"] = dev.device_kind
-        manifest["platform"] = dev.platform
-        manifest["device_count"] = jax.device_count()
-    except Exception:
-        manifest["device_kind"] = None
+    dev = jax.devices()[0]
+    manifest["device_kind"] = dev.device_kind
+    manifest["platform"] = dev.platform
+    manifest["device_count"] = jax.device_count()
     if mesh is not None:
         manifest["mesh_shape"] = {str(a): int(s)
                                   for a, s in dict(mesh.shape).items()}
         manifest["mesh_axis_names"] = [str(a) for a in mesh.axis_names]
     from mercury_tpu.obs.accounting import peak_flops
 
-    manifest["peak_flops"] = (
-        peak_flops(manifest.get("device_kind")) if manifest.get("device_kind")
-        else None
-    )
+    manifest["peak_flops"] = peak_flops(dev.device_kind)
     if extra:
         manifest.update(extra)
     return manifest

@@ -163,10 +163,15 @@ def _decode_xline(buf: memoryview) -> Dict[str, Any]:
     return line
 
 
-def _decode_metadata_entry(buf: memoryview) -> Tuple[int, str]:
-    """One ``map<int64, XEventMetadata>`` entry -> ``(id, name)``."""
+def _decode_metadata_entry(buf: memoryview) -> Tuple[int, str, str]:
+    """One ``map<int64, XEventMetadata>`` entry -> ``(id, name,
+    stats_text)``: the metadata's string-valued stats, joined. On a TPU
+    capture an op's name is its HLO text and the named-scope path
+    (``jit(step)/mercury_scoring/...``) is its ``tf_op`` stat, so the
+    stats are where attribution has to look."""
     key = 0
     name = ""
+    stats: List[str] = []
     for field, _, value in _wire_fields(buf):
         if field == 1:
             key = int(value)
@@ -174,19 +179,27 @@ def _decode_metadata_entry(buf: memoryview) -> Tuple[int, str]:
             for f2, _, v2 in _wire_fields(value):
                 if f2 == 2:
                     name = bytes(v2).decode("utf-8", "replace")
-    return key, name
+                elif f2 == 5:  # XStat; field 5 of it is str_value
+                    stats.extend(
+                        bytes(v3).decode("utf-8", "replace")
+                        for f3, w3, v3 in _wire_fields(v2)
+                        if f3 == 5 and w3 == 2)
+    return key, name, " ".join(stats)
 
 
 def _decode_xplane(buf: memoryview) -> Dict[str, Any]:
-    plane: Dict[str, Any] = {"name": "", "lines": [], "event_names": {}}
+    plane: Dict[str, Any] = {"name": "", "lines": [], "event_names": {},
+                             "event_stats": {}}
     for field, _, value in _wire_fields(buf):
         if field == 2:
             plane["name"] = bytes(value).decode("utf-8", "replace")
         elif field == 3:
             plane["lines"].append(_decode_xline(value))
         elif field == 4:
-            k, name = _decode_metadata_entry(value)
+            k, name, stats = _decode_metadata_entry(value)
             plane["event_names"][k] = name
+            if stats:
+                plane["event_stats"][k] = stats
     return plane
 
 
@@ -207,7 +220,7 @@ def load_xplane_events(path: str) -> List[dict]:
             t0_us = line["timestamp_ns"] / 1e3
             for ev in line["events"]:
                 name = plane["event_names"].get(ev["metadata_id"], "")
-                events.append({
+                event = {
                     "ph": "X",
                     "name": name,
                     "ts": t0_us + ev["offset_ps"] / 1e6,
@@ -216,7 +229,11 @@ def load_xplane_events(path: str) -> List[dict]:
                     "tid": tid,
                     "_pname": plane["name"],
                     "_tname": line["name"],
-                })
+                }
+                stats = plane["event_stats"].get(ev["metadata_id"])
+                if stats:
+                    event["args"] = {"stats": stats}
+                events.append(event)
     return events
 
 
@@ -287,20 +304,52 @@ def _is_device_lane(pname: str) -> bool:
             or low.startswith("gpu"))
 
 
+def _merged(intervals: List[Tuple[float, float]]
+            ) -> List[Tuple[float, float]]:
+    """Possibly-overlapping ``(start, end)`` as disjoint sorted spans."""
+    out: List[Tuple[float, float]] = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1] = (out[-1][0], max(out[-1][1], e))
+        else:
+            out.append((s, e))
+    return out
+
+
 def _merged_busy(intervals: List[Tuple[float, float]]) -> float:
     """Total covered time of possibly-overlapping ``(start, end)``."""
-    if not intervals:
-        return 0.0
-    intervals = sorted(intervals)
-    total = 0.0
-    cur_s, cur_e = intervals[0]
-    for s, e in intervals[1:]:
-        if s > cur_e:
-            total += cur_e - cur_s
-            cur_s, cur_e = s, e
-        else:
-            cur_e = max(cur_e, e)
-    return total + (cur_e - cur_s)
+    return sum(e - s for s, e in _merged(intervals))
+
+
+def _self_times(events: List[dict]) -> List[float]:
+    """Exclusive duration of each event: its own minus that of the events
+    nested directly in it on the same lane. A real TPU op lane nests — a
+    ``while`` op's event spans the events of its body's ops (every op of
+    a ``scan_steps`` chunk, every batch of an eval epoch) — so summing
+    plain durations would count the body twice and halve every scope's
+    share."""
+    self_us = [float(e["dur"]) for e in events]
+    order = sorted(range(len(events)), key=lambda i: (
+        events[i].get("pid", 0), events[i].get("tid", 0),
+        float(events[i]["ts"]), -float(events[i]["dur"])))
+    stack: List[int] = []  # indices of the open enclosing events
+    lane = None
+    for i in order:
+        e = events[i]
+        if (e.get("pid", 0), e.get("tid", 0)) != lane:
+            lane, stack = (e.get("pid", 0), e.get("tid", 0)), []
+        start = float(e["ts"])
+        end = start + float(e["dur"])
+        while stack:
+            top = events[stack[-1]]
+            top_end = float(top["ts"]) + float(top["dur"])
+            if end <= top_end + 1e-6 and start < top_end:
+                break  # e lies inside the open event on top
+            stack.pop()
+        if stack:
+            self_us[stack[-1]] -= float(e["dur"])
+        stack.append(i)
+    return [max(us, 0.0) for us in self_us]
 
 
 def _overlap(a: List[Tuple[float, float]],
@@ -361,7 +410,9 @@ def attribute_device_time(events: List[dict],
     # attribution target; step/module container lanes would double-count
     # every nanosecond. When no lane is tagged, fall back to the busiest
     # single lane — deterministic, and honest about granularity.
-    op_lanes = [e for e in device_compute if "xla ops" in e["_tname"].lower()]
+    # An exact match: "Async XLA Ops" is another lane of the same plane
+    # (in-flight copies and collectives, overlapping the op lane).
+    op_lanes = [e for e in device_compute if e["_tname"].lower() == "xla ops"]
     if op_lanes:
         compute = op_lanes
         lane_note = "xla_ops"
@@ -380,22 +431,24 @@ def attribute_device_time(events: List[dict],
 
     bucket_us: Dict[str, float] = {s: 0.0 for s in scopes}
     bucket_us[UNATTRIBUTED] = 0.0
-    for e in compute:
+    self_us = _self_times(compute)
+    for e, us in zip(compute, self_us):
         text = _searchable_text(e)
         for scope in scopes:
             if scope in text:
-                bucket_us[scope] += float(e["dur"])
+                bucket_us[scope] += us
                 break
         else:
-            bucket_us[UNATTRIBUTED] += float(e["dur"])
+            bucket_us[UNATTRIBUTED] += us
 
-    total_us = sum(float(e["dur"]) for e in compute)
+    total_us = sum(self_us)
     attributed_us = sum(bucket_us.values())
 
-    compute_iv = [(float(e["ts"]), float(e["ts"]) + float(e["dur"]))
-                  for e in compute]
+    compute_iv = _merged([(float(e["ts"]), float(e["ts"]) + float(e["dur"]))
+                          for e in compute])
     h2d_iv = [(float(e["ts"]), float(e["ts"]) + float(e["dur"]))
               for e in h2d]
+    h2d_iv = _merged(h2d_iv)
     h2d_total = _merged_busy(h2d_iv)
     h2d_overlap = _overlap(compute_iv, h2d_iv)
 

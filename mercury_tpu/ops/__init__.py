@@ -1,7 +1,5 @@
 from mercury_tpu.ops.mercury_kernels import (  # noqa: F401
-    augment_normalize_pallas,
     on_tpu,
     per_sample_nll_pallas,
     score_and_draw_pallas,
-    table_refresh_draw_pallas,
 )

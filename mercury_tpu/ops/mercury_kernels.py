@@ -1,8 +1,7 @@
 """Pallas TPU kernels for the Mercury hot ops.
 
-Four kernels cover the importance-sampling inner loop (the math of
-``Trainer.update_samples``, ``pytorch_collab.py:101-117``) plus the
-uint8 ingest path that feeds it:
+Two kernels cover the importance-sampling inner loop (the math of
+``Trainer.update_samples``, ``pytorch_collab.py:101-117``):
 
 1. :func:`per_sample_nll_pallas` — fused per-sample cross-entropy
    (log-softmax + label gather in one VMEM pass, ≡ ``F.cross_entropy(...,
@@ -10,35 +9,23 @@ uint8 ingest path that feeds it:
    (``softmax − onehot`` per sample) so it serves both the scoring pass and
    the differentiable training loss.
 2. :func:`score_and_draw_pallas` — fused score smoothing → normalization →
-   inverse-CDF categorical draws → ``p·N`` gather (≡ ``:111-116``), one
-   VMEM-resident kernel: the cumulative distribution never round-trips to
-   HBM.
-3. :func:`table_refresh_draw_pallas` — fused scoretable step: age-decay +
-   refresh-window scatter + smoothing + inverse-CDF draw over the whole
-   persistent ``[L]`` table in one VMEM pass.
-4. :func:`augment_normalize_pallas` — fused uint8 ingest: dequant →
-   per-channel normalize → random crop(pad)/hflip in one VMEM pass per
-   image (``_data_transforms_cifar10``, ``cifar10/data_loader.py:83-96``).
-   The raw bytes enter VMEM as uint8 (4× less HBM traffic than the f32
-   HLO chain it replaces) and the crop/flip are gather-free one-hot
-   selections, bit-identical to ``normalize_images`` + ``augment_batch``.
-   Off-TPU its wrapper dispatches to an equivalent jax-native fused chain
-   instead of the interpreter (the one-hot matmuls are MXU work;
-   ``use_kernel=True`` forces the kernel for interpret-mode parity tests).
+   inverse-CDF categorical draws (≡ ``:111-116``), one VMEM-resident
+   kernel: the cumulative distribution never round-trips to HBM. It serves
+   the pool sampler (N = the candidate pool) and the scoretable sampler
+   (N = the whole shard table, after the step's jax-native decay and
+   refresh scatter).
 
 Uniform variates are passed in (from ``jax.random``) rather than drawn with
 the in-kernel TPU PRNG, so the draw is reproducible from a JAX key and the
 kernels run identically under ``interpret=True`` on CPU (how the test suite
 exercises them without a chip).
 
-Shapes here are small-to-medium (pool up to tens of thousands, classes ≤
-1024): each kernel is a single block, no grid — Mosaic pads to the (8, 128)
-f32 tile internally. The win is fusion (one HBM read of the logits,
-everything else in VMEM), not tiling. The draw kernel's CDF is computed in
-``[T, T]`` chunks (T ≤ 512) with a running scalar prefix, so its VMEM
-footprint is O(N·B + T²) rather than the O(N²) a single lower-triangular
-matmul would need — a 4096-candidate pool costs a 1 MB triangle tile, not
-a 64 MB square.
+Each kernel is a single block, no grid. The draw kernel holds its scores
+lane-dense — ``[N/128, 128]`` f32, 4 bytes per candidate — because Mosaic
+tiles an ``[N, 1]`` f32 column ``(8, 128)``, 512 bytes per candidate: a
+50,000-slot table is 0.2 MB lane-dense and 24 MB as a column, past the
+16 MB scoped-VMEM limit of a v5e. ``tests/test_tpu_aot.py`` compiles both
+kernels for the v5e target at the shapes ``Trainer`` produces.
 """
 
 from __future__ import annotations
@@ -48,6 +35,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -143,106 +131,69 @@ per_sample_nll_pallas.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 # ----------------------------------------------------------------- kernel 2
-def _pow2_divisor(n: int, cap: int = 512) -> int:
-    """Largest power-of-two divisor of ``n``, capped."""
-    t = cap
-    while t > 1 and n % t != 0:
-        t //= 2
-    return t
-
-
-def _cdf_chunk(n: int) -> int:
-    """CDF chunk size: the largest power-of-two divisor of ``n``, capped
-    at 512 — chunks tile the pool exactly and the in-kernel triangle mask
-    stays ≤ 1 MB regardless of pool size.
-
-    A pool whose largest power-of-two divisor is tiny (e.g. 625) would
-    unroll n/t near-scalar chunks into the Mosaic program; instead, such
-    pools fall back to the single [n, n] triangle when it fits VMEM
-    comfortably (n ≤ 1024 → ≤ 4 MB) — larger awkward pools are padded to
-    a 512-multiple by the wrapper before reaching the kernel."""
-    t = _pow2_divisor(n)
-    if t < 64 and n <= 1024:
-        return n
-    return t
-
-
-def _inverse_cdf_draw(probs, u, true_n: int):
-    """Chunked inverse-CDF categorical draw, in-kernel shared math.
-
-    ``probs``: [N, 1] normalized; ``u``: [1, B] iid U(0,1). Returns the
-    drawn indices [1, B] int32, clamped to the REAL pool (< ``true_n``).
-
-    Mosaic notes: ``cumsum`` has no TC lowering, so each chunk's local CDF
-    is a lower-triangular matmul (MXU) over a ``[T, T]`` tile, offset by
-    the running scalar prefix of the chunks before it. The inverse-CDF
-    count ``idx_b = #{j: cdf_j <= u_b}`` decomposes exactly over chunks
-    (each chunk contributes its own count), so chunking changes the VMEM
-    footprint — O(T²) instead of O(N²) — and nothing else. The loop over
-    N/T chunks is a static Python unroll (straight-line Mosaic program).
-    """
-    n = probs.shape[0]
-    b = u.shape[1]
-    t = _cdf_chunk(n)
-    row = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-    lower = (col <= row).astype(jnp.float32)              # [T, T]
-
-    # Inverse-CDF sampling ≡ multinomial-with-replacement (:114):
-    # idx_b = #{ j : cdf_j <= u_b }, accumulated chunk by chunk with the
-    # global prefix carried as a scalar.
-    counts = jnp.zeros((1, b), jnp.int32)
-    prefix = jnp.zeros((), jnp.float32)
-    for c in range(n // t):
-        pc = probs[c * t:(c + 1) * t, :]                  # [T, 1]
-        cdf_c = prefix + jnp.dot(
-            lower, pc, preferred_element_type=jnp.float32
-        )                                                 # [T, 1]
-        counts = counts + jnp.sum(
-            (cdf_c <= u).astype(jnp.int32), axis=0, keepdims=True
-        )
-        prefix = prefix + jnp.sum(pc)
-    # Clamp to the REAL pool: padded rows (wrapper-added, score 1e-12)
-    # carry ~zero probability, and the clamp guarantees a draw can never
-    # land on one even at u → 1.
-    return jnp.minimum(counts, true_n - 1)                # [1, B]
-
-
-def _scaled_probs_gather(probs, idx, true_n: int):
-    """``scaled_b = p[idx_b]·N`` via one-hot mask-and-reduce (gather-free;
-    [N, B] is O(N·B) — pool·batch, not pool², so it stays unchunked).
-    N is the REAL pool size: the p·N reweight contract (:116) is about
-    the candidate count the caller drew from, not the padded tile."""
-    n = probs.shape[0]
-    b = idx.shape[1]
-    onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, (n, b), 0) == idx
-    ).astype(jnp.float32)                                 # [N, B]
-    return jnp.sum(onehot * (probs * true_n), axis=0, keepdims=True)
+_LANES = 128
+#: Rows per CDF chunk: the row-prefix triangle is ``[T, T]`` f32, 1 MB at
+#: 512, and one chunk covers 65,536 candidates.
+_ROW_CHUNK = 512
 
 
 def _score_draw_kernel(
-    losses_ref, ema_ref, uniforms_ref,
-    probs_ref, selected_ref, scaled_ref,
+    scores_ref, ema_ref, uniforms_ref, probs_ref, selected_ref, cdf_ref,
     *, alpha: float, true_n: int,
 ):
-    """score → normalize → chunked inverse-CDF draw → p·N gather, all in
-    VMEM.
+    """score → normalize → inverse-CDF draw, all in VMEM.
 
-    ``losses_ref``: [N, 1]; ``ema_ref``: [1, 1] (SMEM); ``uniforms_ref``:
-    [1, B] iid U(0,1). Outputs: normalized probs [N, 1], selected pool
-    positions [1, B] int32, scaled probs p·N [1, B].
+    ``scores_ref``: [M, 128] raw per-candidate scores, row-major, padded
+    past ``true_n``; ``ema_ref``: [1] (SMEM); ``uniforms_ref``: [B] iid
+    U(0,1) (SMEM). Outputs: normalized probs [M, 128] (exactly 0 on the
+    padding), drawn candidate positions [B] int32 (SMEM). ``cdf_ref`` is
+    an [M, 128] VMEM scratch.
+
+    Mosaic notes: ``cumsum`` has no TC lowering, so the CDF is two
+    triangular matmuls (MXU) — within each 128-lane row, then an exclusive
+    prefix over the row totals, in chunks of ``_ROW_CHUNK`` rows with the
+    running total carried as a scalar (a static Python unroll). The
+    operands are 0/1 masks, so at HIGHEST precision every product is exact
+    and the CDF differs from a sequential f32 cumsum only by summation
+    order. Inverse-CDF sampling ≡ multinomial-with-replacement (:114):
+    ``idx_b = #{j : cdf_j <= u_b}``, one masked count over the table per
+    draw.
     """
-    losses = losses_ref[:]                                # [N, 1]
-    scores = jnp.maximum(losses + alpha * ema_ref[0, 0], 1e-12)  # :111
-    total = jnp.sum(scores)
-    probs = scores / total                                # :112
-    probs_ref[:] = probs
+    m = scores_ref.shape[0]
+    slot = (lax.broadcasted_iota(jnp.int32, (m, _LANES), 0) * _LANES
+            + lax.broadcasted_iota(jnp.int32, (m, _LANES), 1))
+    scores = jnp.where(
+        slot < true_n,
+        jnp.maximum(scores_ref[:] + alpha * ema_ref[0], 1e-12),   # :111
+        0.0,
+    )
+    probs_ref[:] = scores / jnp.sum(scores)                      # :112
 
-    u = uniforms_ref[:]                                   # [1, B]
-    idx = _inverse_cdf_draw(probs, u, true_n)
-    selected_ref[:] = idx
-    scaled_ref[:] = _scaled_probs_gather(probs, idx, true_n)  # p·N (:116)
+    dot = functools.partial(jnp.dot, precision=lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    upper = (lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+             <= lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+             ).astype(jnp.float32)
+    t = min(m, _ROW_CHUNK)
+    strict_lower = (lax.broadcasted_iota(jnp.int32, (t, t), 1)
+                    < lax.broadcasted_iota(jnp.int32, (t, t), 0)
+                    ).astype(jnp.float32)
+    prefix = jnp.zeros((), jnp.float32)
+    for c in range(m // t):
+        rows = slice(c * t, (c + 1) * t)
+        row_cdf = dot(probs_ref[rows, :], upper)                  # [T, 128]
+        row_total = row_cdf[:, _LANES - 1:]                       # [T, 1]
+        cdf_ref[rows, :] = row_cdf + (prefix + dot(strict_lower, row_total))
+        prefix = prefix + jnp.sum(row_total)
+
+    def draw(b, carry):
+        below = (cdf_ref[:] <= uniforms_ref[b]).astype(jnp.int32)
+        # Clamp to the REAL pool: the padding's CDF is flat at ~1.0, so a
+        # u within rounding of 1 could otherwise count past the last slot.
+        selected_ref[b] = jnp.minimum(jnp.sum(below), true_n - 1)
+        return carry
+
+    lax.fori_loop(0, uniforms_ref.shape[0], draw, 0)
 
 
 def score_and_draw_pallas(
@@ -257,295 +208,43 @@ def score_and_draw_pallas(
 
     Returns ``(probs [N], selected [B] int32, scaled_probs [B])`` matching
     the jax-native ``importance_probs`` + ``draw_with_replacement`` +
-    ``p·N`` pipeline (``mercury_tpu.sampling.importance``).
+    ``p·N`` pipeline (``mercury_tpu.sampling.importance``). ``p·N`` is a
+    ``B``-element gather of the kernel's probs, left to XLA; N is the REAL
+    candidate count — the reweight contract (:116) is about the pool the
+    caller drew from, not the padded tile.
     """
     n = losses.shape[0]
-    n_pad = n
-    if _pow2_divisor(n) < 64 and n > 1024:
-        # Awkward large pool (tiny power-of-two divisor): pad to the next
-        # 512-multiple so the chunked CDF tiles exactly. Pad losses of
-        # -1e30 clamp to score 1e-12 (≈ zero probability); the kernel's
-        # idx clamp and p·N scale both use the true n.
-        n_pad = -(-n // 512) * 512
-        losses = jnp.concatenate([
-            losses.astype(jnp.float32),
-            jnp.full((n_pad - n,), -1e30, jnp.float32),
-        ])
-    uniforms = jax.random.uniform(key, (1, batch_size), jnp.float32)
+    # Whole (8, 128) f32 tiles; past one CDF chunk, whole chunks.
+    block = 8 * _LANES if n <= _ROW_CHUNK * _LANES else _ROW_CHUNK * _LANES
+    n_pad = -(-n // block) * block
+    m = n_pad // _LANES
+    scores = jnp.pad(losses.astype(jnp.float32), (0, n_pad - n))
+    uniforms = jax.random.uniform(key, (batch_size,), jnp.float32)
     kernel = functools.partial(_score_draw_kernel, alpha=alpha, true_n=n)
     # Auditor anchor (see per_sample_nll_pallas): the fused selection
     # kernel is one named region in the traced program.
     with jax.named_scope("mercury_score_draw_kernel"):
-        probs, selected, scaled = pl.pallas_call(
+        probs, selected = pl.pallas_call(
             kernel,
             out_shape=(
-                jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-                jax.ShapeDtypeStruct((1, batch_size), jnp.int32),
-                jax.ShapeDtypeStruct((1, batch_size), jnp.float32),
+                jax.ShapeDtypeStruct((m, _LANES), jnp.float32),
+                jax.ShapeDtypeStruct((batch_size,), jnp.int32),
             ),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1), memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=(
                 pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ),
+            scratch_shapes=[pltpu.VMEM((m, _LANES), jnp.float32)],
             interpret=_interpret(),
         )(
-            losses.reshape(-1, 1).astype(jnp.float32),
-            ema_value.reshape(1, 1).astype(jnp.float32),
+            scores.reshape(m, _LANES),
+            ema_value.reshape(1).astype(jnp.float32),
             uniforms,
         )
-    return probs[:n, 0], selected[0, :], scaled[0, :]
-
-
-# ----------------------------------------------------------------- kernel 3
-def _table_refresh_draw_kernel(
-    table_ref, slots_ref, rscores_ref, ema_ref, uniforms_ref,
-    table_out_ref, probs_ref, selected_ref, scaled_ref,
-    *, alpha: float, decay: float, true_n: int,
-):
-    """Fused score-table step (``sampler="scoretable"``): age-decay the
-    whole table toward the EMA mean, scatter the freshly scored refresh
-    window in, smooth/normalize over ALL slots, and draw the train batch —
-    one VMEM pass over the persistent ``[L]`` table, no HBM round trip
-    between the decay, the scatter, and the CDF.
-
-    ``table_ref``: [N, 1] persistent scores; ``slots_ref``/``rscores_ref``:
-    [1, R] refresh window (slot ids < true_n, fresh scores);
-    ``ema_ref``: [1, 1] (SMEM); ``uniforms_ref``: [1, B]. Outputs: the
-    refreshed table [N, 1], normalized probs [N, 1], selected slots
-    [1, B] int32, scaled probs p·L [1, B].
-
-    The scatter is a one-hot mask-and-reduce over [N, R] (R ≪ N — the
-    whole point of the refresh window), with duplicate slots averaged —
-    exactly ``sampling.scoretable.scatter_mean``. Padded rows (wrapper-
-    added past ``true_n``) are re-floored to -1e30 every call so the decay
-    can never resurrect them into the distribution.
-    """
-    mu = ema_ref[0, 0]
-    table = table_ref[:]                                  # [N, 1]
-    n = table.shape[0]
-    # Staleness decay: entries refreshed a steps ago sit γ^a of the way
-    # back to the EMA mean — stale extremes fade, nothing starves.
-    decayed = mu + (table - mu) * decay
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
-    decayed = jnp.where(rows < true_n, decayed, -1e30)
-
-    slots = slots_ref[:]                                  # [1, R]
-    rscores = rscores_ref[:]                              # [1, R]
-    hit = (
-        jax.lax.broadcasted_iota(jnp.int32, (n, slots.shape[1]), 0) == slots
-    ).astype(jnp.float32)                                 # [N, R]
-    sums = jnp.sum(hit * rscores, axis=1, keepdims=True)  # [N, 1]
-    counts = jnp.sum(hit, axis=1, keepdims=True)          # [N, 1]
-    refreshed = jnp.where(
-        counts > 0, sums / jnp.maximum(counts, 1.0), decayed
-    )
-    table_out_ref[:] = refreshed
-
-    scores = jnp.maximum(refreshed + alpha * mu, 1e-12)
-    probs = scores / jnp.sum(scores)
-    probs_ref[:] = probs
-
-    idx = _inverse_cdf_draw(probs, uniforms_ref[:], true_n)
-    selected_ref[:] = idx
-    scaled_ref[:] = _scaled_probs_gather(probs, idx, true_n)  # p·L
-
-
-def table_refresh_draw_pallas(
-    key: jax.Array,
-    scores: jax.Array,
-    refresh_slots: jax.Array,
-    refresh_scores: jax.Array,
-    ema_value: jax.Array,
-    batch_size: int,
-    alpha: float = 0.5,
-    decay: float = 0.98,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Fused scoretable decay + scatter-refresh + full-table draw.
-
-    Returns ``(new_scores [L], probs [L], selected [B] int32,
-    scaled_probs [B])``, matching the jax-native
-    ``sampling.scoretable.table_refresh_draw`` (same decay/scatter/probs
-    bit math; draws use the same inverse-CDF machinery as
-    :func:`score_and_draw_pallas`, reproducible from the JAX key).
-    """
-    n = scores.shape[0]
-    n_pad = n
-    scores = scores.astype(jnp.float32)
-    if _pow2_divisor(n) < 64 and n > 1024:
-        # Same awkward-size rule as score_and_draw_pallas: pad to a
-        # 512-multiple; pad rows carry -1e30 (score floor, never drawn)
-        # and are re-floored in-kernel each call, then sliced off here —
-        # the persistent table the caller carries stays [L].
-        n_pad = -(-n // 512) * 512
-        scores = jnp.concatenate([
-            scores, jnp.full((n_pad - n,), -1e30, jnp.float32)
-        ])
-    uniforms = jax.random.uniform(key, (1, batch_size), jnp.float32)
-    kernel = functools.partial(
-        _table_refresh_draw_kernel, alpha=alpha, decay=decay, true_n=n
-    )
-    r = refresh_slots.shape[0]
-    new_table, probs, selected, scaled = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, batch_size), jnp.int32),
-            jax.ShapeDtypeStruct((1, batch_size), jnp.float32),
-        ),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ),
-        interpret=_interpret(),
-    )(
-        scores.reshape(-1, 1),
-        refresh_slots.reshape(1, r).astype(jnp.int32),
-        refresh_scores.reshape(1, r).astype(jnp.float32),
-        ema_value.reshape(1, 1).astype(jnp.float32),
-        uniforms,
-    )
-    return new_table[:n, 0], probs[:n, 0], selected[0, :], scaled[0, :]
-
-
-# ----------------------------------------------------------------- kernel 4
-def _augment_norm_kernel(
-    raw_ref, mean_ref, std_ref, oy_ref, ox_ref, flip_ref, out_ref,
-    *, pad: int, out_dtype,
-):
-    """Fused dequant → normalize → crop/flip for ONE image (grid over the
-    batch): the raw uint8 block is read once, everything else stays in
-    VMEM.
-
-    ``raw_ref``: [1, H, W, C] uint8; ``mean_ref``/``std_ref``: [1, C] f32
-    per-channel constants; ``oy_ref``/``ox_ref``/``flip_ref``: [1, 1] SMEM
-    int32 — this image's crop offsets (0..2·pad) and flip bit.
-
-    Bit-exactness contract (vs ``normalize_images`` + ``augment_batch``):
-    normalize is elementwise so it commutes exactly with the crop/flip
-    gathers, and the unfused path pads AFTER normalizing — out-of-bounds
-    pixels are literal 0.0 in normalized space, which the one-hot
-    selection reproduces for free (no source row/col matches → the
-    mask-and-reduce sums to zero). The crop and the flip fold into one
-    column selection: ``src_x = (W-1-x if flip else x) + ox - pad``
-    (crop-then-flip ≡ flipped-column crop). One-hot × value sums are
-    IEEE-exact — each output pixel is one picked value plus signed zeros.
-    """
-    x = raw_ref[0].astype(jnp.float32) / 255.0            # [H, W, C]
-    xn = (x - mean_ref[0][None, None, :]) / std_ref[0][None, None, :]
-    h, w, _ = xn.shape
-    oy = oy_ref[0, 0]
-    ox = ox_ref[0, 0]
-    flip = flip_ref[0, 0]
-
-    # Row select: out1[y] = padded[y + oy] = xn[y + oy - pad] (0.0 OOB).
-    src_y = jax.lax.broadcasted_iota(jnp.int32, (h, h), 1) + oy - pad
-    rsel = (jax.lax.broadcasted_iota(jnp.int32, (h, h), 0) == src_y
-            ).astype(jnp.float32)                         # [Y_src, y_out]
-    out1 = jnp.sum(rsel[:, :, None, None] * xn[:, None, :, :], axis=0)
-
-    # Column select with the flip folded in (see docstring).
-    x_out = jax.lax.broadcasted_iota(jnp.int32, (w, w), 1)
-    x_eff = jnp.where(flip != 0, w - 1 - x_out, x_out)
-    csel = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0) == x_eff + ox - pad
-            ).astype(jnp.float32)                         # [X_src, x_out]
-    out = jnp.sum(csel[None, :, :, None] * out1[:, :, None, :], axis=1)
-    # + 0.0 canonicalizes the all-(-0.0) OOB corner to the unfused path's
-    # +0.0 pad value; every other pixel is unchanged (exact for v != 0).
-    out_ref[0] = (out + 0.0).astype(out_dtype)
-
-
-def augment_normalize_pallas(
-    key: jax.Array,
-    raw: jax.Array,
-    mean,
-    std,
-    pad: int = 4,
-    out_dtype=jnp.float32,
-    use_kernel=None,
-) -> jax.Array:
-    """Fused uint8 ingest: dequant + per-channel normalize + random
-    crop(``pad``) + horizontal flip in one VMEM pass, bit-identical (at
-    f32) to ``augment_batch(key, normalize_images(raw, mean, std))``.
-
-    ``raw``: [N, H, W, C] uint8; ``mean``/``std``: per-channel constants.
-    ``out_dtype`` is applied as the LAST op on either path, so the bf16
-    scoring path (``scoring_dtype="bfloat16"`` + ``fused_input``) emits
-    bf16 activations directly — one rounding of the exact f32 value, never
-    an f32 round trip through HBM.
-
-    ``use_kernel=None`` picks the Mosaic kernel on real TPU (the one-hot
-    selections there are MXU work and the uint8 block enters VMEM once);
-    elsewhere it falls to a jax-native fused chain built from the exact
-    unfused ops (``normalize_images`` → pad → ``_take_crops`` → flip) with
-    the pre-drawn offsets, because the one-hot matmuls that are cheap on
-    the MXU are ~H× extra FLOPs for the CPU interpreter. Tests pass
-    ``use_kernel=True`` to pin the interpret-mode kernel's bit-parity.
-
-    The crop/flip draws replay ``augment_batch``'s key consumption exactly
-    (split 3 ways; ``randint`` for offsets, ``bernoulli`` for flips), so a
-    trajectory is reproducible from the same JAX key on either path. Runs
-    under the ``mercury_input_fuse`` named scope — the profile-attribution
-    bucket (``prof/scope_frac/mercury_input_fuse``) and the jaxpr auditor
-    both key on this anchor."""
-    n, h, w, c = raw.shape
-    # Mirror augment_batch's split even though cutout is unsupported here
-    # (config validation rejects fused_input + cutout): the draw STREAM
-    # must match so unfused trajectories replay bit-for-bit.
-    k_crop, k_flip, _k_cut = jax.random.split(key, 3)
-    off = jax.random.randint(k_crop, (n, 2), 0, 2 * pad + 1)
-    flip = jax.random.bernoulli(k_flip, shape=(n,))
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    if not use_kernel:
-        from mercury_tpu.data.pipeline import _take_crops, normalize_images
-
-        with jax.named_scope("mercury_input_fuse"):
-            xn = normalize_images(raw, mean, std)
-            padded = jnp.pad(xn, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-            out = _take_crops(padded, off[:, 0], off[:, 1], h, w)
-            out = jnp.where(flip[:, None, None, None],
-                            out[:, :, ::-1, :], out)
-            return out.astype(jnp.dtype(out_dtype))
-    kernel = functools.partial(
-        _augment_norm_kernel, pad=pad, out_dtype=jnp.dtype(out_dtype),
-    )
-    smem = pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM)
-    chan = pl.BlockSpec((1, c), lambda i: (0, 0), memory_space=pltpu.VMEM)
-    with jax.named_scope("mercury_input_fuse"):
-        return pl.pallas_call(
-            kernel,
-            grid=(n,),
-            out_shape=jax.ShapeDtypeStruct((n, h, w, c), jnp.dtype(out_dtype)),
-            in_specs=[
-                pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0),
-                             memory_space=pltpu.VMEM),
-                chan, chan,
-                smem, smem, smem,
-            ],
-            out_specs=pl.BlockSpec((1, h, w, c), lambda i: (i, 0, 0, 0),
-                                   memory_space=pltpu.VMEM),
-            interpret=_interpret(),
-        )(
-            raw,
-            jnp.asarray(mean, jnp.float32).reshape(1, c),
-            jnp.asarray(std, jnp.float32).reshape(1, c),
-            off[:, 0:1].astype(jnp.int32),
-            off[:, 1:2].astype(jnp.int32),
-            flip[:, None].astype(jnp.int32),
-        )
+    probs = probs.reshape(n_pad)[:n]
+    return probs, selected, probs[selected] * n

@@ -223,10 +223,8 @@ def worker_shard_global_arrays(
             )
             return block[(slice(None),) + tuple(idx[1:])]
 
-        # No dtype kwarg (absent on older jax): the astype above already
-        # pins every callback block to the declared dtype.
         return jax.make_array_from_callback(
-            (W, L) + shape_tail, sharding, cb
+            (W, L) + shape_tail, sharding, cb, dtype=dtype
         )
 
     return (build(xs, xs.shape[1:], xs.dtype),

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from mercury_tpu.compat import donate_argnums
 
 #: SHARDING CONTRACT (enforced by graftlint Layer 3, lint/sharding.py):
 #: params/opt-state leaves carry fsdp_shardings (largest divisible dim
@@ -145,7 +144,7 @@ def make_fsdp_train_step(
                 step,
                 out_shardings=(param_shardings, shardings_of(opt_state),
                                replicated),
-                donate_argnums=donate_argnums(0, 1),
+                donate_argnums=(0, 1),
             )
         x = jax.device_put(x, batch_sharding)
         y = jax.device_put(y, batch_sharding)

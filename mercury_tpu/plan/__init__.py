@@ -9,8 +9,7 @@ at trainer construction, and ``restore_elastic`` re-plans when the
 (W, L) mesh changes.
 
 Everything here is stdlib-only (no jax import): the planner scores from
-committed goldens, so CI's jax-free leg and the ``bench.py
---stale-check-only`` path can both run it.
+committed goldens, so CI's jax-free leg can run it.
 """
 
 from mercury_tpu.plan.auto import (  # noqa: F401

@@ -207,14 +207,15 @@ def _scaled_peak_bytes(name: str, memory: Dict[str, Any],
 
 
 def _compute_rate(device_kind: str, peak_flops: Optional[float]) -> float:
+    """FLOP/s the score divides by: an explicit ``peak_flops``, else the
+    tabulated peak; the CPU stand-in only for the CPU. An accelerator
+    kind that is not tabulated raises (``obs.accounting.peak_flops``)."""
     if peak_flops:
         return float(peak_flops)
-    try:  # obs.accounting is stdlib-only; lazy to keep import cost down
-        from mercury_tpu.obs.accounting import peak_flops as _peak
-        tabulated = _peak(device_kind)
-    except Exception:
-        tabulated = None
-    return float(tabulated) if tabulated else _CPU_FLOPS_PER_S
+    # obs.accounting is stdlib-only; lazy to keep import cost down
+    from mercury_tpu.obs.accounting import peak_flops as _peak
+
+    return float(_peak(device_kind) or _CPU_FLOPS_PER_S)
 
 
 def _collective_overhead(device_kind: str) -> float:

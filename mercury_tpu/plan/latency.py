@@ -19,6 +19,7 @@ longest matching prefix of ``jax.devices()[0].device_kind.lower()`` wins.
 TPU numbers are the published ICI per-link figures; the ``cpu`` entry is a
 deliberately modest shared-memory figure so CPU-mesh plan rankings still
 penalize collective-heavy plans instead of treating communication as free.
+A device kind that matches no prefix is an error, not the ``cpu`` figure.
 """
 
 from __future__ import annotations
@@ -39,18 +40,20 @@ LINK_BANDWIDTH_BYTES_PER_S: Dict[str, float] = {
     "cpu": 10e9,       # shared-memory "link" stand-in for the host mesh
 }
 
-_DEFAULT_BANDWIDTH = LINK_BANDWIDTH_BYTES_PER_S["cpu"]
-
 
 def link_bandwidth(device_kind: str) -> float:
-    """Per-link bandwidth (bytes/s) for a device kind, longest-prefix match;
-    unknown kinds fall back to the conservative ``cpu`` figure."""
-    kind = (device_kind or "").lower()
-    best, best_len = _DEFAULT_BANDWIDTH, -1
-    for prefix, bw in LINK_BANDWIDTH_BYTES_PER_S.items():
-        if kind.startswith(prefix) and len(prefix) > best_len:
-            best, best_len = bw, len(prefix)
-    return best
+    """Per-link bandwidth (bytes/s) for a device kind, longest-prefix
+    match. No kind at all means the host mesh (``cpu``); a kind that is
+    not tabulated raises — pricing an unknown accelerator's collectives
+    at the CPU figure would rank its plans on a made-up number."""
+    kind = (device_kind or "cpu").lower()
+    matches = [p for p in LINK_BANDWIDTH_BYTES_PER_S if kind.startswith(p)]
+    if not matches:
+        raise ValueError(
+            f"no link bandwidth tabulated for device kind {device_kind!r}; "
+            "add it to mercury_tpu.plan.latency.LINK_BANDWIDTH_BYTES_PER_S"
+        )
+    return LINK_BANDWIDTH_BYTES_PER_S[max(matches, key=len)]
 
 
 def ring_allreduce_cost_s(payload_bytes: float, axis_size: int,

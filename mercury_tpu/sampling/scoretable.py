@@ -33,10 +33,10 @@ was ACTUALLY drawn with, so ``E[loss_i/(L·p_i)] = mean_L(loss)`` exactly,
 for any table contents — staleness shifts variance, never the mean
 (verified in ``tests/test_scoretable.py``).
 
-Everything here is the pure jax-native formulation; the fused Pallas
-kernel (``ops.mercury_kernels.table_refresh_draw_pallas``) implements
-steps 2-3 in one VMEM pass and is tested equivalent under
-``interpret=True``.
+Everything here is the pure jax-native formulation; on the Pallas path
+(``use_pallas``) step 3's normalize → CDF → draw over the whole table is
+``ops.mercury_kernels.score_and_draw_pallas``, fed the table steps 1-2
+produce here.
 
 Observability: under ``telemetry=True`` the step emits the post-refresh
 table's log-binned histogram (``sampler_dist/score_hist/*``) and
@@ -191,9 +191,9 @@ def table_refresh_draw(
     smooth/normalize → draw ``batch_size`` with replacement → ``p·L``.
 
     Returns ``(new_scores [L], probs [L], selected [B] int32,
-    scaled_probs [B])``. The Pallas kernel
-    (``table_refresh_draw_pallas``) computes exactly this in one VMEM
-    pass; ``tests/test_scoretable.py`` pins the two together."""
+    scaled_probs [B])``. The Pallas path keeps this decay and scatter
+    and hands normalize → draw to ``score_and_draw_pallas``;
+    ``tests/test_scoretable.py`` pins the two together."""
     decayed = decay_scores(scores.astype(jnp.float32), ema_value, decay)
     refreshed = scatter_mean(decayed, refresh_slots, refresh_scores)
     probs = table_probs(refreshed, ema_value, alpha)

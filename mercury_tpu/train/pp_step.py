@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh
 
-from mercury_tpu.compat import donate_argnums
 from mercury_tpu.config import TrainConfig
 from mercury_tpu.data.pipeline import (
     ShardStream,
@@ -214,4 +213,4 @@ def make_pp_mercury_step(
             metrics["train/grad_norm"] = global_grad_norm(grads)
         return new_state, metrics
 
-    return jax.jit(step, donate_argnums=donate_argnums(0))
+    return jax.jit(step, donate_argnums=(0,))

@@ -28,7 +28,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from mercury_tpu.compat import axis_size, donate_argnums, shard_map
+from mercury_tpu.compat import axis_size, shard_map
 
 from mercury_tpu.config import TrainConfig
 from mercury_tpu.data.pipeline import (
@@ -99,7 +99,7 @@ def make_dp_sp_train_step(
         out_specs=(P(), P(), P()),
     )
     if not zigzag:
-        return jax.jit(sharded, donate_argnums=donate_argnums(0, 1))
+        return jax.jit(sharded, donate_argnums=(0, 1))
 
     from mercury_tpu.parallel.sequence import zigzag_order
 
@@ -109,7 +109,7 @@ def make_dp_sp_train_step(
         perm = jnp.asarray(zigzag_order(x.shape[1], w_seq))
         return sharded(params, opt_state, x[:, perm], y)
 
-    return jax.jit(step, donate_argnums=donate_argnums(0, 1))
+    return jax.jit(step, donate_argnums=(0, 1))
 
 
 class SpMercuryState(NamedTuple):
@@ -357,7 +357,7 @@ def make_dp_sp_mercury_step(
             return constrained_inner(state, x_train, y_train)
 
     if not zigzag:
-        return jax.jit(sharded, donate_argnums=donate_argnums(0))
+        return jax.jit(sharded, donate_argnums=(0,))
 
     from mercury_tpu.parallel.sequence import zigzag_order
 
@@ -365,4 +365,4 @@ def make_dp_sp_mercury_step(
         perm = jnp.asarray(zigzag_order(x_train.shape[1], w_seq))
         return sharded(state, x_train[:, perm], y_train)
 
-    return jax.jit(step, donate_argnums=donate_argnums(0))
+    return jax.jit(step, donate_argnums=(0,))

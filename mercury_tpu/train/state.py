@@ -59,8 +59,8 @@ class PendingSelection(NamedTuple):
     lookahead makes the draws key-for-key identical to the device-resident
     path: ``rng`` is the worker RNG advanced ``depth`` steps ahead, so the
     slot draw for step t+d uses exactly the key the replicated step would
-    split at t+d. Carried as raw uint32 key data (not a typed key array)
-    so the leaf shards like any other array under legacy jax."""
+    split at t+d. Carried as raw uint32 key data rather than a typed key
+    array (the checkpointed schema; ``jax.random.wrap_key_data`` to use)."""
 
     slots: jax.Array         # [depth, S] int32 — shard-local slot ids per step
     scaled_probs: jax.Array  # [depth, B] float32 — p_i·L at draw time

@@ -30,7 +30,13 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mercury_tpu.config import TrainConfig
-from mercury_tpu.data.pipeline import ShardStream, augment_batch, next_pool, normalize_images
+from mercury_tpu.data.pipeline import (
+    ShardStream,
+    augment_batch,
+    augment_normalize,
+    next_pool,
+    normalize_images,
+)
 from mercury_tpu.obs.diagnostics import (
     clip_fraction,
     ema_drift,
@@ -75,8 +81,7 @@ from mercury_tpu.train.state import (
     PendingSelection,
 )
 
-from mercury_tpu.compat import (MODERN_JAX, axis_size, donate_argnums,
-                                shard_map)
+from mercury_tpu.compat import axis_size, shard_map
 
 
 def _state_specs(
@@ -129,18 +134,11 @@ def mercury_state_out_shardings(
         opt_state=opt_sh,
         ema=EMAState(value=n(P(axis)), count=n(P(axis))),
         stream=ShardStream(perm=n(P(axis)), cursor=n(P(axis))),
-        # Legacy jax rejects a tiled out_sharding on a PRNG key array
-        # under a partial-manual mesh (the hidden [..., 2] payload dim is
-        # missing from the tile assignment at validation). Replicating the
-        # tiny [W]-key leaf sidesteps the bug; shard_map re-slices it per
-        # worker on the next step's entry either way.
-        rng=n(P(axis)) if MODERN_JAX else n(P()),
+        rng=n(P(axis)),
         groupwise=n(P(axis)) if has_groupwise else None,
         pending=n(P(axis)) if has_pending else None,
         cached_pool=n(P(axis)) if has_cached_pool else None,
         scoretable=n(P(axis)) if has_scoretable else None,
-        # Raw uint32 key data (train/state.py PendingSelection) — no PRNG
-        # key leaf, so the tiled sharding is safe on legacy jax too.
         pending_sel=n(P(axis)) if has_pending_sel else None,
         sel_counts=n(P(axis)) if has_sel_counts else None,
     )
@@ -427,8 +425,8 @@ def make_train_step(
         if config.augmentation != "noniid":
             raise ValueError(
                 "fused_input fuses the noniid crop/flip augmentation into "
-                "the ingest kernel (ops.augment_normalize_pallas); set "
-                f"augmentation='noniid' (got {config.augmentation!r})"
+                "the uint8 ingest chain (data.pipeline.augment_normalize); "
+                f"set augmentation='noniid' (got {config.augmentation!r})"
             )
         if config.cutout:
             raise ValueError(
@@ -522,21 +520,20 @@ def make_train_step(
         """Raw rows → augmented normalized images: THE ingest boundary —
         every sampler path funnels its pixel rows through here. Unfused,
         it is the ``normalize_images`` + ``_augment`` HLO chain; with
-        ``config.fused_input`` it is one Pallas VMEM pass
-        (``ops.augment_normalize_pallas``, ``mercury_input_fuse`` scope)
-        that consumes ``key`` identically, so trajectories are
-        bit-identical at f32 (test-enforced, tests/test_ops.py).
+        ``config.fused_input`` it is one chain on the raw uint8 rows with
+        pre-drawn offsets (``data.pipeline.augment_normalize``,
+        ``mercury_input_fuse`` scope) that consumes ``key`` identically,
+        so trajectories are bit-identical at f32 (test-enforced,
+        tests/test_ops.py).
         ``out_dtype`` (the bf16 scoring ingest) is applied as the LAST op
         on both paths, so the fused/unfused agreement survives the cast."""
         if fused_input:
             if raw.dtype != jnp.uint8:
                 raise ValueError(
-                    "fused_input ingests raw uint8 rows (the kernel owns "
+                    "fused_input ingests raw uint8 rows (the chain owns "
                     f"the /255 dequant); got {raw.dtype}"
                 )
-            from mercury_tpu.ops import augment_normalize_pallas
-
-            return augment_normalize_pallas(
+            return augment_normalize(
                 key, raw, mean, std,
                 out_dtype=(jnp.float32 if out_dtype is None else out_dtype),
             )
@@ -1015,31 +1012,18 @@ def make_train_step(
                 # is decay → normalize → draw only; the post-train
                 # write-back below still re-scores the trained batch for
                 # free (those logits exist either way).
+                new_scores = decay_scores(
+                    table.scores.astype(jnp.float32), ema.value,
+                    config.table_decay,
+                )
                 if use_pallas:
-                    from mercury_tpu.ops import table_refresh_draw_pallas
+                    from mercury_tpu.ops import score_and_draw_pallas
 
-                    # Dummy-slot sentinel: "refresh" slot 0 with its own
-                    # decayed value — scatter_mean writes back the number
-                    # the decay already produced, a no-op — so the SAME
-                    # fused decay→scatter→normalize→draw kernel serves the
-                    # async step with no scoring forward attached and no
-                    # second kernel to maintain.
-                    sent = (ema.value
-                            + (table.scores[0].astype(jnp.float32)
-                               - ema.value) * config.table_decay)[None]
-                    new_scores, _, selected, scaled_probs = (
-                        table_refresh_draw_pallas(
-                            k_sel, table.scores,
-                            jnp.zeros((1,), jnp.int32), sent,
-                            ema.value, batch_size,
-                            alpha=config.is_alpha, decay=config.table_decay,
-                        )
+                    _, selected, scaled_probs = score_and_draw_pallas(
+                        k_sel, new_scores, ema.value, batch_size,
+                        config.is_alpha,
                     )
                 else:
-                    new_scores = decay_scores(
-                        table.scores.astype(jnp.float32), ema.value,
-                        config.table_decay,
-                    )
                     probs = table_probs(
                         new_scores, ema.value, config.is_alpha
                     )
@@ -1063,14 +1047,19 @@ def make_train_step(
                 ema_prev = ema.value
                 ema = ema_update(ema, score_avg, config.ema_alpha)
                 if use_pallas:
-                    from mercury_tpu.ops import table_refresh_draw_pallas
+                    from mercury_tpu.ops import score_and_draw_pallas
 
-                    new_scores, _, selected, scaled_probs = (
-                        table_refresh_draw_pallas(
-                            k_sel, table.scores, refresh_slots, r_scores,
-                            ema.value, batch_size,
-                            alpha=config.is_alpha, decay=config.table_decay,
-                        )
+                    # Decay and refresh scatter are the jax-native ops of
+                    # table_refresh_draw; the kernel owns normalize → CDF
+                    # → draw over the whole table.
+                    new_scores = scatter_mean(
+                        decay_scores(table.scores.astype(jnp.float32),
+                                     ema.value, config.table_decay),
+                        refresh_slots, r_scores,
+                    )
+                    _, selected, scaled_probs = score_and_draw_pallas(
+                        k_sel, new_scores, ema.value, batch_size,
+                        config.is_alpha,
                     )
                 else:
                     new_scores, _, selected, scaled_probs = (
@@ -1597,22 +1586,6 @@ def make_train_step(
     if auto_axes:
         # Manual over the data axis only; GSPMD handles the rest.
         smap_kw["axis_names"] = frozenset({axis})
-    raw_rng = bool(auto_axes) and not MODERN_JAX
-    if raw_rng:
-        # Legacy partial-manual lowering rejects PRNG key leaves in the
-        # body's out_specs (the hidden [..., 2] payload dim is missing
-        # from the tile assignment — see compat.MODERN_JAX). Carry the
-        # rng across the shard_map boundary as raw uint32 and rewrap it
-        # just inside/outside; P(axis) prefixes the extra dim fine.
-        inner_fn = fn
-
-        def fn(state, x_train, y_train, shard_indices):
-            state = state.replace(rng=jax.random.wrap_key_data(state.rng))
-            new_state, metrics = inner_fn(
-                state, x_train, y_train, shard_indices)
-            return new_state.replace(
-                rng=jax.random.key_data(new_state.rng)), metrics
-
     # host_stream: x is the per-worker streamed rows ([W, S, ...] — sharded
     # like the indices that drew them) while y stays the replicated label
     # table the in-graph gathers index; the third output is the next
@@ -1628,16 +1601,6 @@ def make_train_step(
         check_vma=False,
         **smap_kw,
     )
-    if raw_rng:
-        inner_sharded = sharded
-
-        def sharded(state, x_train, y_train, shard_indices):
-            state = state.replace(rng=jax.random.key_data(state.rng))
-            new_state, metrics = inner_sharded(
-                state, x_train, y_train, shard_indices)
-            return new_state.replace(
-                rng=jax.random.wrap_key_data(new_state.rng)), metrics
-
     if io_constraints:
         from jax.sharding import NamedSharding
 
@@ -1670,9 +1633,8 @@ def make_train_step(
     # output never aliases it (int32 [W, S] vs uint8 rows), so the
     # PendingSelection outputs no longer pin the buffer. Layer-3's
     # memory_analysis() ratchet + the Layer-2 donation-consistency check
-    # (lint/audit.py) pin this down per plan. donate_argnums is the
-    # compat shim: () on legacy jax (persistent-cache aliasing bug).
-    donated = donate_argnums(0, 1) if host_stream else donate_argnums(0)
+    # (lint/audit.py) pin this down per plan.
+    donated = (0, 1) if host_stream else (0,)
     return jax.jit(sharded, donate_argnums=donated, **jit_kw)
 
 
@@ -1859,8 +1821,8 @@ def make_eval_epoch(
 
     The reference's ``evaluate`` walks a DataLoader batch-by-batch from the
     host (``pytorch_collab.py:201-234``); a whole split here is a single
-    device call — this matters when dispatch latency is non-trivial (e.g. a
-    tunneled chip: ~24 host round trips become 1).
+    device call — ~24 host dispatches become 1, which matters wherever a
+    dispatch costs a visible fraction of a small batch's compute.
 
     With ``mesh``, each scanned batch's sample dimension is sharded over
     the mesh's data axis (``in_shardings`` only — GSPMD partitions the

@@ -354,10 +354,13 @@ class Trainer:
             self._tp_param_sh = param_sh
         else:
             self._state_out_shardings = None
-        # Multi-controller (multi-host) runs: the host-created state and
-        # dataset are process-local; re-place them as global arrays over the
-        # (cross-process) mesh. Single-process runs skip this — shard_map
-        # handles placement there.
+        # Every step input and the whole state are committed on the mesh
+        # HERE, before the first step: left uncommitted on device 0, a
+        # several-device run re-broadcasts the dataset from that device on
+        # every step and compiles a second time once the step's own
+        # (committed) output feeds back as its input. Multi-controller runs
+        # assemble the global arrays per process (globalize_*); a single
+        # process places them directly.
         # Step-input train arrays. "sharded": materialize each worker's
         # shard rows as [W, L, ...] arrays sharded over the data axis —
         # per-device memory is one shard row, and in multi-controller runs
@@ -382,11 +385,13 @@ class Trainer:
             # (_refill_stream_pipe) needs the full host copy, which every
             # process holds identically by seeded construction.
             self._host_shard_indices = np.asarray(self.dataset.shard_indices)
+        from mercury_tpu.parallel.distributed import (
+            globalize_dataset,
+            make_global_array,
+        )
+
         if jax.process_count() > 1:
-            from mercury_tpu.parallel.distributed import (
-                globalize_dataset,
-                globalize_state,
-            )
+            from mercury_tpu.parallel.distributed import globalize_state
 
             self.state = globalize_state(
                 self.state, self.mesh, config.mesh_axis,
@@ -412,14 +417,14 @@ class Trainer:
                     self.tx.init, out_shardings=self._tp_opt_sh
                 )(self.state.params)
                 self.state = self.state.replace(opt_state=tp_opt)
-            self.dataset = globalize_dataset(
-                self.dataset, self.mesh, config.mesh_axis,
-                # host_stream: pixels must STAY host numpy — the per-host
-                # prefetch pipelines stream selected rows; replicating
-                # x_train onto every device is the thing the placement
-                # exists to avoid.
-                include_train_arrays=not data_sharded and not host_stream,
-            )
+        self.dataset = globalize_dataset(
+            self.dataset, self.mesh, config.mesh_axis,
+            # host_stream: pixels must STAY host numpy — the per-host
+            # prefetch pipelines stream selected rows; replicating
+            # x_train onto every device is the thing the placement
+            # exists to avoid.
+            include_train_arrays=not data_sharded and not host_stream,
+        )
         if params_sharded:
             # The moment layout is DERIVED (opt_sharding_like), not
             # inferred from live leaves: the structural param-path match
@@ -434,43 +439,27 @@ class Trainer:
                     self.state.opt_state, self.state.params,
                     self._tp_param_sh, self.mesh,
                 )
-            opt_sh = self._tp_opt_sh
-            from mercury_tpu.train.step import mercury_state_out_shardings
-
-            self._state_out_shardings = mercury_state_out_shardings(
-                self.mesh, config.mesh_axis, self._tp_param_sh, opt_sh,
-                has_groupwise=(config.use_importance_sampling
-                               and config.sampler == "groupwise"),
-                has_pending=(config.use_importance_sampling
-                             and config.pipelined_scoring),
-                has_cached_pool=(config.use_importance_sampling
-                                 and config.sampler == "pool"
-                                 and config.score_refresh_every > 1),
-                has_scoretable=(config.use_importance_sampling
-                                and config.sampler == "scoretable"),
-                has_sel_counts=(config.use_importance_sampling
-                                and config.sampler == "scoretable"
-                                and bool(config.telemetry)),
-            )
-            if jax.process_count() == 1:
-                # Pre-place the whole state with the pinned shardings (a
-                # no-copy no-op for the already-committed params/opt): the
-                # first step then donates cleanly instead of warning about
-                # unusable host-resident sampler buffers and resharding on
-                # entry. device_put accepts the prefix sharding pytree, so
-                # groupwise/pending subtrees are covered too. (Multi-
-                # controller state is already fully placed by
-                # globalize_state.)
-                state_sh, _ = self._state_out_shardings
-                self.state = jax.device_put(self.state, state_sh)
+            self._state_out_shardings = self._state_sharding_tree(
+                self._tp_param_sh, self._tp_opt_sh)
+        if jax.process_count() == 1:
+            # Place the whole state in the step's own layout (a no-copy
+            # no-op for params/opt a TP/FSDP branch above already
+            # committed): the first step then donates real mesh buffers
+            # and its output layout equals its input layout. device_put
+            # accepts the prefix sharding pytree, so groupwise/pending
+            # subtrees are covered too. (Multi-controller state is already
+            # fully placed by globalize_state.)
+            self.state = jax.device_put(self.state, self._state_shardings())
         if host_stream:
             # Pixels never become a step input: _step_x is the per-step
             # streamed batch (popped from the prefetch pipeline in
             # _host_stream_step). Labels are tiny ([N] int32) and the
             # in-graph gathers index them, so they live on device.
+            from jax.sharding import PartitionSpec as P
+
             self._step_x = None
-            self._step_y = jnp.asarray(np.asarray(self.dataset.y_train),
-                                       jnp.int32)
+            self._step_y = make_global_array(
+                np.asarray(self.dataset.y_train, np.int32), self.mesh, P())
         elif not data_sharded:
             self._step_x = self.dataset.x_train
             self._step_y = self.dataset.y_train
@@ -1933,6 +1922,40 @@ class Trainer:
                                     int(self.state.step),
                                     **self._ckpt_kwargs())
 
+    def _state_sharding_tree(self, params_sh, opt_sh):
+        """``(state shardings, metrics sharding)`` for this trainer's
+        state with the given params / optimizer layouts; which optional
+        sampler fields it carries is read off the state itself."""
+        from mercury_tpu.train.step import mercury_state_out_shardings
+
+        st = self.state
+        return mercury_state_out_shardings(
+            self.mesh, self.config.mesh_axis, params_sh, opt_sh,
+            has_groupwise=st.groupwise is not None,
+            has_pending=st.pending is not None,
+            has_cached_pool=st.cached_pool is not None,
+            has_scoretable=st.scoretable is not None,
+            has_pending_sel=st.pending_sel is not None,
+            has_sel_counts=st.sel_counts is not None,
+        )
+
+    def _state_shardings(self) -> MercuryState:
+        """``NamedSharding`` prefix tree of the layout the step program
+        keeps ``self.state`` in: the pinned TP/FSDP layout when there is
+        one, else params replicated, optimizer state replicated (chunk-
+        sharded under ZeRO-1) and sampler state sharded over the data
+        axis (``train.step._state_specs``)."""
+        if self._state_out_shardings is not None:
+            return self._state_out_shardings[0]
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        cfg = self.config
+        rep = NamedSharding(self.mesh, P())
+        opt = (NamedSharding(self.mesh, P(cfg.mesh_axis))
+               if cfg.zero_sharding else rep)
+        return self._state_sharding_tree(rep, opt)[0]
+
     def _recommit_state(self, reprime_stream: bool = False) -> None:
         """Re-place a host-resident ``self.state`` for this trainer's
         topology: global arrays over the cross-process mesh
@@ -1962,36 +1985,7 @@ class Trainer:
                 zero_sharding=self.config.zero_sharding, **tp_kw,
             )
         else:
-            if self._state_out_shardings is not None:
-                state_sh, _ = self._state_out_shardings
-            else:
-                # Non-TP: params/opt replicated, sampler state sharded
-                # over the data axis — the same layout the step program
-                # produces.
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as P
-                from mercury_tpu.train.step import (
-                    mercury_state_out_shardings,
-                )
-
-                cfg = self.config
-                rep = NamedSharding(self.mesh, P())
-                state_sh, _ = mercury_state_out_shardings(
-                    self.mesh, cfg.mesh_axis, rep, rep,
-                    has_groupwise=(cfg.use_importance_sampling
-                                   and cfg.sampler == "groupwise"),
-                    has_pending=(cfg.use_importance_sampling
-                                 and cfg.pipelined_scoring),
-                    has_cached_pool=(cfg.use_importance_sampling
-                                     and cfg.sampler == "pool"
-                                     and cfg.score_refresh_every > 1),
-                    has_scoretable=(cfg.use_importance_sampling
-                                    and cfg.sampler == "scoretable"),
-                    has_pending_sel=(cfg.data_placement == "host_stream"),
-                    has_sel_counts=(cfg.use_importance_sampling
-                                    and cfg.sampler == "scoretable"
-                                    and bool(cfg.telemetry)),
-                )
+            state_sh = self._state_shardings()
             # Identity jit, not a bare device_put: on CPU device_put may
             # zero-copy alias the checkpoint reader's host buffers, and
             # the first donated step would then hand XLA memory it

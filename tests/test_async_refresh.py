@@ -85,24 +85,6 @@ class TestAsyncApplyUnits:
         np.testing.assert_array_equal(
             np.asarray(refreshed), np.asarray(via_async))
 
-    def test_age0_matches_pallas_kernel(self):
-        """...and therefore also matches the fused Pallas kernel's
-        refreshed table (interpret mode on CPU, PR-1 tolerance)."""
-        from mercury_tpu.ops import table_refresh_draw_pallas
-        from mercury_tpu.sampling.scoretable import (
-            apply_async_chunk,
-            decay_scores,
-        )
-
-        key, scores, slots, values, ema = self._fixture()
-        p_table, _, _, _ = table_refresh_draw_pallas(
-            key, scores, slots, values, ema, 8, decay=0.98)
-        via_async = apply_async_chunk(
-            decay_scores(scores.astype(jnp.float32), ema, 0.98),
-            slots, values, ema, jnp.float32(1.0))
-        np.testing.assert_allclose(
-            np.asarray(p_table), np.asarray(via_async), atol=1e-5)
-
     def test_aged_apply_equals_fresh_apply_then_decay(self):
         """With a constant EMA mean, applying a chunk at age ``a`` with
         weight γ^a equals applying it fresh and decaying the table ``a``

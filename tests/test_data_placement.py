@@ -276,7 +276,7 @@ class TestHostStream:
 
 
 class TestFusedInput:
-    """fused_input=True: the ``ops.augment_normalize_pallas`` ingest must
+    """fused_input=True: the ``data.pipeline.augment_normalize`` ingest must
     replay the unfused normalize→augment trajectory BIT-identically — the
     kernel replays ``augment_batch``'s exact RNG consumption, so fusing is
     a pure lowering change, never a numerics change. Tier-1 pins the
@@ -381,3 +381,66 @@ class TestHostStreamMatrix:
             np.testing.assert_array_equal(steps(rep, 6), stream_steps(hs, 6))
         finally:
             hs.close()
+
+
+class TestPlacedAtConstruction:
+    """``Trainer`` commits every step input and the whole state on the
+    mesh BEFORE the first step. Left uncommitted on device 0 (how a
+    single-process run used to start), a several-device run re-broadcasts
+    the dataset from that device every step and compiles twice — once for
+    the uncommitted inputs, once more when its own committed output feeds
+    back ("placement settle"). Neither cost shows on the virtual CPU mesh,
+    so the layout and the compile count are asserted directly."""
+
+    @pytest.mark.parametrize("kw", [
+        {},
+        dict(zero_sharding=True, sampler="scoretable", refresh_size=8,
+             telemetry=True),
+        dict(data_placement="sharded"),
+        dict(data_placement="host_stream"),
+    ], ids=["pool", "zero-scoretable-ledger", "sharded", "host_stream"])
+    def test_committed_before_step_one_and_one_compile(self, mesh, kw):
+        from jax.sharding import NamedSharding
+
+        from mercury_tpu.lint.tracecheck import CompileMonitor
+
+        with Trainer(cfg(**kw), mesh=mesh) as tr:
+            inputs = {"state": tr.state, "y": tr._step_y,
+                      "shard_indices": tr.dataset.shard_indices}
+            if tr._step_x is not None:  # host_stream pixels stay host-side
+                inputs["x"] = tr._step_x
+            for path, leaf in jax.tree_util.tree_flatten_with_path(inputs)[0]:
+                where = jax.tree_util.keystr(path)
+                assert isinstance(leaf.sharding, NamedSharding), where
+                assert leaf.sharding.mesh.devices.size == 4, where
+                assert len(leaf.sharding.device_set) == 4, where
+                assert leaf.committed, where
+            per_call = []
+            with CompileMonitor() as monitor:
+                for _ in range(4):
+                    before = monitor.snapshot()[1]
+                    if tr._step_x is None:
+                        tr._host_stream_step()
+                    else:
+                        steps(tr, 1)
+                    per_call.append(monitor.snapshot()[1] - before)
+            assert per_call[0] >= 1          # the one compile
+            assert per_call[1:] == [0, 0, 0]  # no "placement settle"
+
+    def test_restore_recommits_zero_opt_state_chunked(self, mesh, tmp_path):
+        """restore() re-places the state in the step's own layout —
+        under ZeRO-1 the optimizer state is chunk-sharded over the data
+        axis, not replicated (which the next step would have to reshard
+        and recompile for)."""
+        from mercury_tpu.lint.tracecheck import CompileMonitor
+
+        with Trainer(cfg(zero_sharding=True), mesh=mesh) as tr:
+            steps(tr, 2)
+            tr.save(str(tmp_path))
+            tr.restore(str(tmp_path))
+            specs = {str(leaf.sharding.spec) for leaf in
+                     jax.tree_util.tree_leaves(tr.state.opt_state)}
+            assert specs == {"PartitionSpec('data',)"}
+            with CompileMonitor() as monitor:
+                steps(tr, 1)
+            assert monitor.compiles == 0

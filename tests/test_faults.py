@@ -208,7 +208,11 @@ class TestTrainerHooks:
     def test_scorer_nan_chunks_rejected_not_applied(self, mesh):
         from mercury_tpu.train.trainer import Trainer
 
-        tr = Trainer(self._cfg(fault_spec="scorer_nan@step=1,every=1"),
+        # Enough steps that the scorer thread delivers a chunk before
+        # fit() ends even on a loaded host (the step compiles once now,
+        # so six steps can be over before the first chunk is scored).
+        tr = Trainer(self._cfg(fault_spec="scorer_nan@step=1,every=1",
+                               steps_per_epoch=40),
                      mesh=mesh)
         try:
             tr.fit()

@@ -1,21 +1,14 @@
-"""graftlint Layer M (metric-key registry auditor) + the bench SLO gate.
+"""graftlint Layer M (metric-key registry auditor).
 
 Layer M is exercised on synthetic package/registry/docs trees so every
 finding class (GLM01/02/03) and every parsing subtlety (f-string skip,
 brace families, fenced code blocks, the registry's own literals) is
 pinned, then once against the real repo — which must be clean, since the
 same check gates CI.
-
-The bench half unit-tests ``bench.slo_violations``: a pure function of
-the record, so every staleness/degradation/MFU path is a table entry.
 """
-
-import calendar
-import time
 
 import pytest
 
-import bench
 from mercury_tpu.lint.metrics import (
     documented_keys,
     emitted_keys,
@@ -296,80 +289,3 @@ class TestGLM04EventKinds:
                      "supervisor/exhausted", "fault/fired",
                      "anomaly/triggered", "checkpoint/written"):
             assert kind in kinds and kind in emitted, kind
-
-
-def rec(age_h=1.0, platform="tpu", mfu=0.3, **extra):
-    """A bench record ``age_h`` hours old at the fixed judgment time."""
-    now = calendar.timegm(time.strptime("2026-08-06T12:00:00Z",
-                                        "%Y-%m-%dT%H:%M:%SZ"))
-    ts = time.strftime("%Y-%m-%dT%H:%M:%SZ",
-                       time.gmtime(now - age_h * 3600))
-    r = {"timestamp": ts, "platform": platform, "mfu": mfu}
-    r.update(extra)
-    return r, now
-
-
-class TestBenchSLOGate:
-    def test_fresh_healthy_record_passes(self):
-        r, now = rec()
-        assert bench.slo_violations(r, now=now) == []
-
-    def test_missing_record_is_violation(self):
-        assert bench.slo_violations(None) != []
-        assert bench.slo_violations({}) != []
-
-    def test_failed_degraded_stale_flags(self):
-        for flag in ("failed", "degraded", "stale"):
-            r, now = rec(**{flag: True})
-            v = bench.slo_violations(r, now=now)
-            assert len(v) == 1, (flag, v)
-
-    def test_stale_reason_is_surfaced(self):
-        r, now = rec(stale=True, stale_reason="backend unreachable")
-        (v,) = bench.slo_violations(r, now=now)
-        assert "backend unreachable" in v
-
-    def test_age_beyond_max_is_violation(self):
-        r, now = rec(age_h=73.0)
-        (v,) = bench.slo_violations(r, now=now)
-        assert "73.0h" in v
-        r, now = rec(age_h=71.0)
-        assert bench.slo_violations(r, now=now) == []
-        # max_age_h=0 disables the age check entirely.
-        r, now = rec(age_h=10_000.0)
-        assert bench.slo_violations(r, max_age_h=0, now=now) == []
-
-    def test_missing_or_garbage_timestamp(self):
-        r, now = rec()
-        del r["timestamp"]
-        (v,) = bench.slo_violations(r, now=now)
-        assert "timestamp" in v
-        r, now = rec()
-        r["timestamp"] = "yesterday-ish"
-        (v,) = bench.slo_violations(r, now=now)
-        assert "unparseable" in v
-
-    def test_mfu_floor_judges_real_chips_only(self):
-        r, now = rec(mfu=0.005)
-        (v,) = bench.slo_violations(r, now=now)
-        assert "mfu" in v and "0.005" in v
-        # CPU-degraded records carry platform=cpu — the floor never
-        # applies (their mfu is meaningless), only the degraded flag does.
-        r, now = rec(platform="cpu", mfu=0.0001)
-        assert bench.slo_violations(r, now=now) == []
-        r, now = rec(mfu=None)
-        assert bench.slo_violations(r, now=now) == []
-
-    def test_violations_accumulate(self):
-        r, now = rec(age_h=100.0, mfu=0.001, stale=True, degraded=True)
-        v = bench.slo_violations(r, now=now)
-        assert len(v) == 4
-
-    def test_committed_cache_judged_without_jax(self):
-        # The bench-slo CI job's exact code path: the committed record is
-        # loadable and judgeable with stdlib only (jax stays unimported —
-        # enforced by bench's module imports, exercised here).
-        record = bench._load_last_good()
-        assert record is not None
-        v = bench.slo_violations(record, now=time.time())
-        assert isinstance(v, list)

@@ -13,6 +13,7 @@ import os
 import threading
 
 import numpy as np
+import pytest
 
 import jax.numpy as jnp
 
@@ -433,8 +434,16 @@ class TestPeakFlops:
     def test_known_and_unknown_kinds(self):
         assert peak_flops("TPU v4") == 275e12
         assert peak_flops("TPU v5 lite") == 197e12
-        assert peak_flops("Intel Xeon") is None
+        assert peak_flops("cpu") is None
         assert peak_flops(None) is None
+
+    def test_untabulated_accelerator_raises(self):
+        """An accelerator the table does not know is an error, not a
+        silent 0.0 MFU."""
+        with pytest.raises(ValueError, match="TPU v9"):
+            peak_flops("TPU v9 hyper")
+        with pytest.raises(ValueError, match="PEAK_FLOPS"):
+            ThroughputMeter(examples_per_step=8, device_kind="NVIDIA H100")
 
 
 class TestAnalyticFlops:

@@ -62,9 +62,10 @@ class TestLatencyModel:
         # "tpu v5p" must win over the shorter "tpu v5..." family entries.
         assert link_bandwidth("TPU v5p chip") == \
             LINK_BANDWIDTH_BYTES_PER_S["tpu v5p"]
-        # Unknown kinds degrade to the cpu floor, never raise.
-        assert link_bandwidth("quantum abacus") == \
-            LINK_BANDWIDTH_BYTES_PER_S["cpu"]
+        assert link_bandwidth("") == LINK_BANDWIDTH_BYTES_PER_S["cpu"]
+        # An untabulated accelerator is an error, never the cpu figure.
+        with pytest.raises(ValueError, match="quantum abacus"):
+            link_bandwidth("quantum abacus")
 
     def test_collective_dispatch_by_hlo_kind(self):
         ar = collective_cost_s("all-reduce", 1000.0, 4, "cpu")
@@ -88,6 +89,13 @@ class TestSelectPlan:
         cm = load_cost_model()
         assert set(PLAN_NAMES) <= set(cm["perf"]["plans"])
         assert set(PLAN_NAMES) <= set(cm["shard"]["plans"])
+
+    def test_untabulated_accelerator_raises(self):
+        """The planner prices compute at the tabulated peak; a TPU it
+        does not know is an error, not the CPU stand-in rate."""
+        with pytest.raises(ValueError, match="TPU v9"):
+            select_plan(model="smallcnn", world_size=8,
+                        device_kind="TPU v9 hyper")
 
     def test_unbounded_ranking_is_deterministic(self):
         d1 = select_plan(model="smallcnn", world_size=8, device_kind="cpu")

@@ -108,6 +108,39 @@ class TestAttributionMath:
         bd = attribute_device_time(events)
         assert bd["scopes"]["mercury_grad_sync"]["time_us"] == 10.0
 
+    def test_nested_ops_count_once(self):
+        """What a real v5e op lane looks like (chip capture, PR 21): a
+        ``while`` op's event spans the events of its body — a scan chunk,
+        an eval epoch. Time is attributed exclusively: the body's ops keep
+        their scopes, the loop keeps only its own overhead, and the total
+        equals the lane's busy time."""
+        events = meta_events() + [
+            op("while.2", 0, 100),
+            {"ph": "X", "name": "fusion.7", "ts": 0, "dur": 60, "pid": 1,
+             "tid": 3, "args": {"tf_op": "jit(f)/mercury_scoring/conv"}},
+            op("fusion.8", 60, 30),
+            op("copy.1", 100, 10),        # after the loop, not inside it
+        ]
+        bd = attribute_device_time(events)
+        assert bd["total_device_time_us"] == pytest.approx(110.0)
+        assert bd["total_device_time_us"] == pytest.approx(
+            bd["idle"]["busy_us"])
+        assert bd["scopes"]["mercury_scoring"]["time_us"] == \
+            pytest.approx(60.0)
+        assert bd["scopes"][UNATTRIBUTED]["time_us"] == pytest.approx(50.0)
+
+    def test_async_op_lane_is_not_the_op_lane(self):
+        # A TPU plane also carries "Async XLA Ops" (in-flight copies,
+        # overlapping the op lane); only "XLA Ops" is attributed.
+        events = meta_events(lanes=((3, "XLA Ops"),
+                                    (4, "Async XLA Ops"))) + [
+            op("mercury_scoring/x", 0, 10),
+            op("copy-start.1", 0, 500, tid=4),
+        ]
+        bd = attribute_device_time(events)
+        assert bd["counts"]["device_events"] == 1
+        assert bd["total_device_time_us"] == pytest.approx(10.0)
+
     def test_host_lanes_ignored(self):
         events = meta_events() + [
             {"ph": "M", "name": "process_name", "pid": 9,
@@ -223,6 +256,22 @@ class TestXplaneWireReader:
             100 / 150)
         assert bd["scopes"][UNATTRIBUTED]["frac"] == pytest.approx(50 / 150)
         assert bd["attributed_frac"] == pytest.approx(1.0)
+
+    def test_scope_is_read_from_the_tf_op_stat(self, tmp_path):
+        """On a TPU capture the event name is the HLO text and the
+        named-scope path is the metadata's ``tf_op`` string stat."""
+        ev = field(1, 1) + field(2, 0) + field(3, 100_000_000)
+        line = field(2, b"XLA Ops") + field(3, 0) + field(4, ev)
+        tf_op = field(1, 7) + field(5, b"jit(f)/mercury_scoring/conv:")
+        md = field(1, 1) + field(2, field(1, 1)
+                                   + field(2, b"%fusion.3 = bf16[8] fusion()")
+                                   + field(5, tf_op))
+        plane = field(2, b"/device:TPU:0") + field(3, line) + field(4, md)
+        path = str(tmp_path / "tpu.xplane.pb")
+        with open(path, "wb") as f:
+            f.write(field(1, plane))
+        bd = attribute_device_time(load_xplane_events(path))
+        assert bd["scopes"]["mercury_scoring"]["frac"] == pytest.approx(1.0)
 
     def test_display_name_fallback(self, tmp_path):
         line = field(11, b"XLA Ops") + field(3, 0)  # display_name only

@@ -1,0 +1,170 @@
+"""The v5e compiler's verdict without a chip.
+
+The installed libtpu can compile for the real target with no TPU attached:
+``jax.experimental.topologies.get_topology_desc("tpu", "v5e:2x2")`` describes
+four ``TPU v5 lite`` devices, and ``jit(f).lower(<ShapeDtypeStructs sharded
+on them>).compile()`` runs the real XLA:TPU and Mosaic compilers. The Pallas
+kernels only ever *execute* here under ``interpret=True``, which accepts
+programs Mosaic refuses (an ``[N, 1]`` f32 column costs 512 bytes per row of
+VMEM on the chip and nothing in the interpreter) — so every kernel
+``TrainConfig`` can reach is compiled for the v5e at the shapes ``Trainer``
+produces, with ``interpret=False``. Execution parity on the chip is
+``chip_smoke.py``'s half.
+
+The standalone kernels take seconds and stay in tier-1; the fused-step
+compiles (about a minute each) are marked ``slow``.
+
+On a machine that HOLDS a chip the module skips itself: building the
+topology loads libtpu, which takes the machine's ``/tmp/libtpu_lockfile``
+even under ``JAX_PLATFORMS=cpu`` — measured on the v5e host (PR 21): while
+this process lived, a second process could not open the TPU ("Internal
+error when accessing libtpu multi-process lockfile"). There the check
+belongs to ``chip_smoke.py``, which compiles and runs the same kernels on
+the chip itself.
+"""
+
+import glob
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from mercury_tpu.ops import mercury_kernels
+
+
+def _chip_attached() -> bool:
+    """A TPU is attached to this machine — asked of the device nodes and
+    the PCI bus (as jax's own detection does), never of libtpu."""
+    if glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"):
+        return True
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        with open(vendor) as f:
+            if f.read().strip() == "0x1ae0":  # Google
+                return True
+    return False
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    if _chip_attached():
+        pytest.skip("a TPU is attached: a compile-only libtpu client would "
+                    "take its process lock; chip_smoke.py checks the "
+                    "kernels on the chip")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu, or one that cannot describe v5e
+        pytest.skip(f"cannot build a v5e:2x2 topology here: "
+                    f"{type(exc).__name__}: {exc}")
+    assert [d.device_kind for d in topo.devices] == ["TPU v5 lite"] * 4
+    return topo.devices
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Make the kernel wrappers emit real Mosaic calls (the on-chip
+    value of ``interpret``) although this process runs on the CPU."""
+    monkeypatch.setattr(mercury_kernels, "on_tpu", lambda: True)
+    assert mercury_kernels._interpret() is False
+
+
+def _compile(fn, devices, *shapes):
+    """Compile ``fn`` for one v5e device; returns the executable's text."""
+    sh = NamedSharding(Mesh(np.array(devices[:1]), ("data",)), P())
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+class TestKernelsCompileForV5e:
+    @pytest.mark.parametrize("n,c,dtype", [
+        (320, 10, jnp.float32), (32, 10, jnp.float32),
+        (320, 100, jnp.float32), (320, 10, jnp.bfloat16),
+    ])
+    def test_per_sample_nll_fwd_and_vjp(self, v5e_devices, mosaic,
+                                        n, c, dtype):
+        def fwd_bwd(logits, labels):
+            return jax.value_and_grad(
+                lambda z: jnp.sum(
+                    mercury_kernels.per_sample_nll_pallas(z, labels))
+            )(logits)
+
+        text = _compile(fwd_bwd, v5e_devices,
+                        ((n, c), dtype), ((n,), jnp.int32))
+        assert text.count("tpu_custom_call") >= 2  # forward + backward
+
+    @pytest.mark.parametrize("n,b", [
+        (320, 32),      # the reference pool (batch 32 x 10)
+        (2560, 256),    # batch 256 x 10
+        (5000, 32),     # scoretable: the synthetic shard at W=1
+        (12500, 32),    # scoretable: CIFAR-10 at W=4
+        (50000, 32),    # scoretable: CIFAR-10 at W=1
+    ])
+    def test_score_and_draw(self, v5e_devices, mosaic, n, b):
+        """An ``[N, 1]`` column layout is refused from N=12,500 up (18.5 MB
+        of scoped VMEM against the 16 MB limit); lane-dense it fits."""
+        def draw(key_data, losses, ema):
+            return mercury_kernels.score_and_draw_pallas(
+                jax.random.wrap_key_data(key_data), losses, ema, b)
+
+        text = _compile(draw, v5e_devices, ((2,), jnp.uint32),
+                        ((n,), jnp.float32), ((), jnp.float32))
+        assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------- fused step
+def _compile_trainer_step(devices, world, **kw):
+    """Compile the step ``Trainer`` would build — same model, optimizer,
+    config and state layout — for ``world`` v5e devices. The trainer
+    itself lives on the CPU mesh; only shapes and specs cross over."""
+    from mercury_tpu import TrainConfig
+    from mercury_tpu.train import Trainer
+    from mercury_tpu.train.step import make_train_step
+
+    config = TrainConfig(
+        model="resnet18", dataset="synthetic", world_size=world,
+        batch_size=32, presample_batches=10, use_pallas=True,
+        log_every=0, eval_every=0, heartbeat_every=0, **kw)
+    mesh = Mesh(np.array(devices[:world]), (config.mesh_axis,))
+    with Trainer(config) as t:
+        scan = t.scan_steps
+        step = make_train_step(t.model, t.tx, config, mesh, t.dataset.mean,
+                               t.dataset.std, scan_steps=scan,
+                               scoring_model=t.scoring_model)
+        ds = t.dataset
+        args = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=NamedSharding(mesh, x.sharding.spec)),
+            (t.state, ds.x_train, ds.y_train, ds.shard_indices))
+    return step.lower(*args).compile()
+
+
+@pytest.mark.slow
+class TestFusedStepCompilesForV5e:
+    def test_is_step_one_chip(self, v5e_devices, mosaic):
+        compiled = _compile_trainer_step(v5e_devices, 1)
+        # kernels 1 + 2 are inside the step
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.cost_analysis()["flops"] > 1e11
+
+    def test_uniform_step_one_chip(self, v5e_devices, mosaic):
+        _compile_trainer_step(v5e_devices, 1,
+                              use_importance_sampling=False)
+
+    def test_scan_chunk_one_chip(self, v5e_devices, mosaic):
+        _compile_trainer_step(v5e_devices, 1, scan_steps=25,
+                              checkpoint_every=0)
+
+    def test_is_step_four_chips(self, v5e_devices, mosaic):
+        _compile_trainer_step(v5e_devices, 4)
+
+    def test_scoretable_step_one_chip(self, v5e_devices, mosaic):
+        """L=5,000 / R=320: refused (17.16 MB of scoped VMEM) while the
+        table was an ``[L, 1]`` column inside the fused step."""
+        compiled = _compile_trainer_step(
+            v5e_devices, 1, sampler="scoretable", refresh_size=320)
+        assert "tpu_custom_call" in compiled.as_text()
