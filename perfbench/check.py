@@ -1,0 +1,140 @@
+"""What decides ``correct``: every number here is printed beside its limit
+in every run, and one over its limit makes the run ``correct: false``.
+
+**The train step, replayed** (``perfbench/replay.py``): the first steps of
+the very trainer the window then drives, through ``fit()``, against the
+plain reference on the recorded batches: ``loss_gap``, ``grad_norm_gap``,
+``update_norm_gap`` and, from the rebuilt pool, ``weight_gap``. This is the
+timed program at the timed batch and pool: scoring forward, normalization,
+reweighted loss, backward pass, Adam and its schedule.
+
+**The inference and evaluate paths.** After warm-up ``trainer.predict``
+gives the logits of 256 test images drawn from the seed, in the precision
+the configuration states; the reference computes them in float32 from a
+host copy of the same weights:
+
+    logit_gap = rms(system - reference) / rms(reference)
+
+over all 256 x classes logits (the root mean square reads the same from
+seed to seed; the widest single logit swings by its nature).
+
+The window's last ``fit()`` call closes with ``evaluate()``, which returns
+``test/eval_loss``; the reference computes the same mean cross-entropy over
+the whole test split from the final weights:
+
+    eval_loss_gap = |system - reference| / reference
+
+**The window itself.** Every ``train/loss`` logged in it is finite;
+``state.step`` advanced by exactly the steps the harness counted; nothing
+compiled; and the parameters moved: ``window_update_rms``, the root mean
+square over all parameters of their change across the window, per step,
+lies above a floor (a step that stops updating mid-run reads 0).
+
+No level of the loss is among them. On the 5,000-image stand-in ResNet-50
+under Adam memorises the data within a hundred steps and is chaotic from
+then on: of the 33 closing evaluations of eleven windows, nine read
+0.85-226 and the others 0.0002-0.03 (``train/eval_loss``; my chip runs,
+PR 24), so with three evaluations to a window no level separates a sound
+run from a fault without failing a sound run in fifty. The replay holds the
+loss of each of its steps to the reference instead.
+
+**How the limits are set** (readings beside each limit in the
+configuration's ``check`` block and in PERF.md): the largest value sound
+runs give over a dozen seeds on the chip, and the smallest the control
+gives: the reference itself with every convolution's and the head's inputs
+and weights rounded to fp8 (e4m3), the nearest precision below the bfloat16
+the configurations state, put in the program's place. The limit stands
+between the two. A number the control hardly moves is held against the
+fault it is there to catch, at about three times the sound runs' largest.
+
+**Not covered.** Which rows the draw picks (replay.py says why); the step
+that primes the pipeline (its batch is in no state); a cell without
+``pipelined_scoring``, whose drawn batch no state shows.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, List, Optional, Sequence
+
+import numpy as np
+
+#: Test images whose logits the inference check compares.
+SAMPLE = 256
+
+
+class Number:
+    """One number compared, beside its limit."""
+
+    def __init__(self, name: str, value: float, limit: float,
+                 rule: str = "<=") -> None:
+        self.name, self.value, self.limit, self.rule = name, value, limit, rule
+        if rule == "<=":
+            self.ok = bool(np.isfinite(value) and value <= limit)
+        elif rule == ">=":
+            self.ok = bool(np.isfinite(value) and value >= limit)
+        elif rule == "==":
+            self.ok = bool(value == limit)
+        else:
+            raise ValueError(rule)
+
+    def line(self) -> str:
+        return (f"[perfbench] check {self.name}: {self.value!r} "
+                f"{self.rule} {self.limit!r} -> {'ok' if self.ok else 'FAIL'}")
+
+
+def sample_indices(seed: int, n_test: int, k: int = SAMPLE) -> np.ndarray:
+    rng = np.random.default_rng([int(seed), 0xA])
+    return np.sort(rng.choice(n_test, size=min(k, n_test), replace=False))
+
+
+def logit_gap(system: np.ndarray, ref: np.ndarray) -> float:
+    system, ref = np.asarray(system, np.float64), np.asarray(ref, np.float64)
+    return float(np.sqrt(np.mean(np.square(system - ref))
+                         / np.mean(np.square(ref))))
+
+
+def eval_loss_gap(system: float, ref: float) -> float:
+    return abs(system - ref) / ref
+
+
+def update_rms(before, after, steps: int) -> float:
+    """Root mean square, over every parameter, of its change from
+    ``before`` to ``after``, per step."""
+    import jax
+
+    a, b = jax.tree.leaves(before), jax.tree.leaves(after)
+    total = sum(float(np.sum(np.square(np.asarray(y, np.float64) - x)))
+                for x, y in zip(a, b))
+    return (total / sum(x.size for x in a)) ** 0.5 / max(steps, 1)
+
+
+def failed_steps(losses: Sequence[float], log_every: int) -> int:
+    """Steps that lie in a log interval whose record was not finite."""
+    return log_every * sum(1 for v in losses if not math.isfinite(v))
+
+
+def numbers(limits: Dict[str, Any], *, system_logits, ref_logits,
+            eval_loss: Optional[float], ref_eval_loss: Optional[float],
+            replay: Dict[str, float], window_update_rms: float,
+            window_losses: List[float], steps_counted: int,
+            steps_advanced: int, compiles: int) -> List[Number]:
+    """Every number compared in a run, each beside its limit (``limits``:
+    the configuration's ``check`` block)."""
+    out = [Number(name, value, float(limits[f"{name}_limit"]))
+           for name, value in replay.items()]
+    out.append(Number("logit_gap", logit_gap(system_logits, ref_logits),
+                      float(limits["logit_gap_limit"])))
+    if eval_loss is not None and ref_eval_loss is not None:
+        out.append(Number("eval_loss_gap",
+                          eval_loss_gap(eval_loss, ref_eval_loss),
+                          float(limits["eval_loss_gap_limit"])))
+    finite = [v for v in window_losses if math.isfinite(v)]
+    out += [
+        Number("window_update_rms", window_update_rms,
+               float(limits["window_update_rms_floor"]), ">="),
+        Number("nonfinite_losses", len(window_losses) - len(finite), 0, "=="),
+        Number("steps_advanced", steps_advanced, steps_counted, "=="),
+        Number("compiles_in_window", compiles, 0, "=="),
+    ]
+    return out

@@ -1,0 +1,278 @@
+"""The train step against the plain reference: a replay of the first steps.
+
+Set-up drives the trainer's first ``STEPS + 1`` steps through ``fit()``
+itself with a recorder around ``trainer.train_step`` — the compiled step
+the window then drives, fed by ``fit()``'s own call — and keeps, after each
+step, what the state shows of it: the drawn batch of the NEXT step (the
+``pending`` batch of pipelined scoring: images as augmented, labels, and
+``scaled_probs = N p_i``), Adam's first moment, the step's scalars, and at
+both ends the parameters. Step 1 primes the pipeline and trains on a batch
+no state ever shows, so the replay starts from the state after it.
+
+Once the window has closed and the program's state is freed, the reference
+(``reference.py``, float32 at ``highest``) follows steps 2..STEPS+1 on its
+own trajectory from that state: the reweighted loss and its gradient on
+each recorded batch, Adam under the cosine schedule. Compared:
+
+- ``loss_gap``: each step's ``train/loss`` against the reference's, the
+  widest relative gap;
+- ``grad_norm_gap``: the gradient of the first replayed step as the
+  optimizer got it (from Adam's first moment before and after):
+  |program's norm - reference's norm| over the reference's, over all
+  parameters together. Norms, not the norm of the difference: at seeded
+  weights the batch's gradient is what is left of 256 per-example
+  gradients that all but cancel, and any rounding turns its direction
+  (bfloat16 program against float32 reference: norm of the difference
+  0.95-1.1 of the norm, my chip runs, PR 24). Not by the worst leaf either:
+  that reads 0.14-0.51 in sound runs, no steadier than the control;
+- ``update_norm_gap``: the parameters' change over the replayed steps, by
+  the worst leaf: |program's norm - reference's norm| over the larger of
+  the reference's norm of that leaf and of the median leaf;
+- ``weight_gap`` (one worker): the pool of the first replayed step rebuilt
+  from the state before it (stream, key, EMA) and scored by the reference
+  with the program's parameters; each drawn row is found in the rebuilt
+  pool and its ``N p_i`` compared: the root mean square of the relative
+  gaps. This is the scoring forward and the normalization at the timed
+  pool size.
+
+The draw itself (which rows the uniforms pick) is not replayed: a row
+whose CDF edge moves by a rounding is drawn differently, and rightly so.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Optional
+
+import numpy as np
+
+from perfbench import reference
+
+#: Steps the reference follows (after the priming step).
+STEPS = 3
+
+
+def _host(tree):
+    import jax
+
+    def leaf(a):
+        if jax.numpy.issubdtype(a.dtype, jax.dtypes.prng_key):
+            a = jax.random.key_data(a)
+        return np.asarray(a)
+
+    return jax.tree.map(leaf, tree)
+
+
+def _adam_state(opt_state):
+    """The ``(count, mu, nu)`` node of an optax Adam state."""
+    import jax
+
+    nodes = [n for n in jax.tree.leaves(
+        opt_state, is_leaf=lambda n: hasattr(n, "mu") and hasattr(n, "nu"))
+        if hasattr(n, "mu")]
+    if len(nodes) != 1:
+        raise NotImplementedError(
+            "the replay follows Adam; this optimizer state holds "
+            f"{len(nodes)} Adam node(s)")
+    return nodes[0]
+
+
+class Recorder:
+    """Stands around ``trainer.train_step`` for the first steps and keeps a
+    host copy of what the replay needs of each new state."""
+
+    def __init__(self, trainer) -> None:
+        self.trainer, self.real = trainer, trainer.train_step
+        self.steps: List[Dict[str, Any]] = []
+
+    def __enter__(self) -> "Recorder":
+        self.trainer.train_step = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.trainer.train_step = self.real
+
+    def __call__(self, *args):
+        state, metrics = self.real(*args)
+        adam = _adam_state(state.opt_state)
+        first, last = not self.steps, len(self.steps) == STEPS
+        kept = dict(
+            metrics={k: float(v) for k, v in _host(metrics).items()
+                     if np.ndim(v) == 0},
+            mu=_host(adam.mu), pending=_host(state.pending))
+        if first:
+            kept.update(nu=_host(adam.nu), count=int(adam.count),
+                        stream=_host(state.stream), rng=_host(state.rng),
+                        ema=_host(state.ema))
+        if first or last:
+            kept.update(params=_host(state.params))
+        self.steps.append(kept)
+        return state, metrics
+
+
+def _leaf_norms(tree) -> np.ndarray:
+    import jax
+
+    return np.array([float(np.linalg.norm(np.asarray(a, np.float64)))
+                     for a in jax.tree.leaves(tree)])
+
+
+def worst_leaf_gap(program, ref) -> float:
+    """|program's norm - reference's norm| of the worst leaf, over the
+    reference's norm of that leaf or of the median leaf, whichever is
+    larger (some gradients are all but zero)."""
+    p, r = _leaf_norms(program), _leaf_norms(ref)
+    over = np.maximum(r, np.median(r))
+    if not over.all():      # a reference that does not move at all
+        return 0.0 if (p == r).all() else float("inf")
+    return float(np.max(np.abs(p - r) / over))
+
+
+def norm_gap(program, ref) -> float:
+    """|program's norm - reference's norm| over the reference's norm, all
+    leaves together."""
+    p = float(np.sqrt(np.sum(np.square(_leaf_norms(program)))))
+    r = float(np.sqrt(np.sum(np.square(_leaf_norms(ref)))))
+    return abs(p - r) / r if r else (0.0 if p == r else float("inf"))
+
+
+def _diff(a, b):
+    import jax
+
+    return jax.tree.map(lambda x, y: np.asarray(x, np.float64) - y, a, b)
+
+
+def _match_rows(rows: np.ndarray, pool: np.ndarray) -> np.ndarray:
+    """For each of ``rows`` the index of the nearest row of ``pool``, and
+    -1 where none lies within a rounding of it."""
+    a = rows.reshape(rows.shape[0], -1).astype(np.float64)
+    b = pool.reshape(pool.shape[0], -1).astype(np.float64)
+    d2 = ((a * a).sum(1)[:, None] + (b * b).sum(1)[None, :]
+          - 2.0 * a @ b.T)
+    nearest = d2.argmin(axis=1)
+    ok = d2[np.arange(len(a)), nearest] <= 1e-6 * a.shape[1]
+    return np.where(ok, nearest, -1)
+
+
+# ------------------------------------------------- the two sides of a step
+def system_steps(steps, arch) -> Dict[str, Any]:
+    """What the program made of the replayed steps: each step's loss, the
+    first gradient as the optimizer got it, the parameters' change."""
+    import jax
+
+    b1 = float(arch["adam"]["b1"])
+    return dict(
+        losses=[s["metrics"]["train/loss"] for s in steps[1:]],
+        grad=jax.tree.map(lambda m1, m0: (np.asarray(m1, np.float64)
+                                          - b1 * m0) / (1.0 - b1),
+                          steps[1]["mu"], steps[0]["mu"]),
+        change=_diff(steps[STEPS]["params"], steps[0]["params"]))
+
+
+def reference_steps(steps, arch, fields, quantize=None) -> Dict[str, Any]:
+    """The same of the reference, which follows the recorded batches on
+    its own trajectory from the state after the priming step."""
+    import jax
+
+    start, adam = steps[0], arch["adam"]
+    world = int(fields["world_size"])
+    if world > 1 and fields.get("batch_norm", "sync") != "sync":
+        raise NotImplementedError("replay across workers needs synced BN")
+    peak_lr = float(fields["base_lr"]) * world
+    decay = int(fields["steps_per_epoch"]) * int(fields["num_epochs"])
+    loss_and_grad = reference.make_loss_and_grad(arch, quantize)
+    flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731  [W,B]->[WB]
+    params, mu, nu, count = (start["params"], start["mu"], start["nu"],
+                             start["count"])
+    out: Dict[str, Any] = dict(losses=[])
+    for i in range(STEPS):
+        batch = steps[i]["pending"]
+        loss, grads = loss_and_grad(
+            params, flat(batch.images), flat(batch.labels),
+            flat(batch.scaled_probs))
+        grads = jax.tree.map(np.asarray, grads)
+        out["losses"].append(float(loss))
+        if i == 0:
+            out["grad"] = grads
+        params, mu, nu = reference.adam_update(
+            params, mu, nu, count, grads,
+            reference.cosine_lr(count, peak_lr, decay),
+            float(adam["b1"]), float(adam["b2"]), float(adam["eps"]))
+        count += 1
+    out["change"] = _diff(params, start["params"])
+    return out
+
+
+def step_gaps(system, ref) -> Dict[str, float]:
+    return dict(
+        loss_gap=max(abs(a - b) / b
+                     for a, b in zip(system["losses"], ref["losses"])),
+        grad_norm_gap=norm_gap(system["grad"], ref["grad"]),
+        update_norm_gap=worst_leaf_gap(system["change"], ref["change"]))
+
+
+def reference_weights(steps, dataset, arch, fields, quantize=None):
+    """``N p_i`` of the rows the program drew in the first replayed step:
+    its pool rebuilt from the state before it and scored by the reference
+    with the program's parameters. None where a drawn row is not in the
+    rebuilt pool, or carries another label there."""
+    import jax
+
+    start, drawn = steps[0], steps[1]["pending"]
+    x_train, y_train, shard_indices = dataset
+    pool_size = int(fields["batch_size"]) * int(fields["presample_batches"])
+    images, labels, _, scaled = reference.score_pool(
+        start["params"],
+        jax.random.wrap_key_data(jax.numpy.asarray(start["rng"][0])),
+        start["stream"].perm[0], int(start["stream"].cursor[0]),
+        float(start["ema"].value[0]), int(start["ema"].count[0]),
+        x_train, y_train, shard_indices[0], arch, pool_size, quantize)
+    at = _match_rows(drawn.images[0], images)
+    found = at >= 0
+    print(f"[perfbench] replay pool: {int(found.sum())} of {len(at)} drawn "
+          f"rows found in the rebuilt pool of {pool_size}", flush=True)
+    if not found.all() or (labels[at] != drawn.labels[0]).any():
+        return None
+    return scaled[at]
+
+
+def weight_gap(system, ref) -> float:
+    """Root mean square of the drawn rows' relative gaps of ``N p_i``."""
+    if system is None or ref is None:
+        return float("inf")
+    gaps = np.abs(np.asarray(system, np.float64) - ref) / ref
+    return float(np.sqrt(np.mean(np.square(gaps))))
+
+
+def compare(steps: List[Dict[str, Any]], dataset, arch: Dict[str, Any],
+            fields: Dict[str, Any], control: Optional[str] = None):
+    """The replay's numbers from a ``Recorder``'s steps: the program
+    against the reference. ``dataset`` is ``(x_train_u8, y_train,
+    shard_indices)`` on the host; ``fields`` the job's ``TrainConfig``
+    fields (learning rate, schedule length, batch and pool). With
+    ``control`` (a lower precision) returns a second dict as well: the
+    reference in that precision, put in the program's place."""
+    if len(steps) != STEPS + 1:
+        raise ValueError(f"recorded {len(steps)} steps, want {STEPS + 1}")
+    if steps[0]["pending"] is None:
+        raise NotImplementedError(
+            "the replay reads the drawn batch from the state's pending "
+            "batch: the cell needs pipelined_scoring")
+    system, ref = system_steps(steps, arch), reference_steps(steps, arch,
+                                                             fields)
+    for i, (a, b) in enumerate(zip(system["losses"], ref["losses"])):
+        print(f"[perfbench] replay step {i + 2}: train/loss {a!r} "
+              f"reference {b!r}", flush=True)
+    out = step_gaps(system, ref)
+    lower = (step_gaps(reference_steps(steps, arch, fields, control), ref)
+             if control else None)
+    if int(fields["world_size"]) == 1:
+        weights = reference_weights(steps, dataset, arch, fields)
+        out["weight_gap"] = weight_gap(steps[1]["pending"].scaled_probs[0],
+                                       weights)
+        if control:
+            lower["weight_gap"] = weight_gap(reference_weights(
+                steps, dataset, arch, fields, control), weights)
+    else:
+        print("[perfbench] replay pool: not rebuilt across workers",
+              flush=True)
+    return (out, lower) if control else out
