@@ -31,9 +31,6 @@ run python benchmarks/mfu_sweep.py --model resnet50 --batches 128,256,512
 run python benchmarks/mfu_sweep.py --model transformer \
     --dataset synthetic_seq --batches 64,256,1024
 
-# 3. Segment-timing validation against a jax.profiler trace.
-run python benchmarks/profile_validation.py
-
 # 4. PP bubble on the chip (the CPU record says: re-measure here before
 #    ruling a 1F1B schedule in or out).
 run python benchmarks/pp_bubble.py

@@ -11,7 +11,8 @@ as Chrome trace-event JSON, loadable directly in Perfetto
 
 Overhead discipline (measured by ``benchmarks/telemetry_overhead.py``):
 
-- **enabled**: one ``perf_counter_ns`` pair + a deque append per span —
+- **enabled**: one ``perf_counter_ns`` pair, a thread-local stack
+  push/pop, a profiler annotation and a deque append per span —
   single-digit microseconds, invisible next to a training step;
 - **disabled**: :data:`NULL_TRACER` returns one shared no-op context
   manager, so an instrumented call site costs an attribute lookup and
@@ -22,7 +23,20 @@ Span schema (one Chrome ``"ph": "X"`` complete event per span)::
 
     {"name": "stream/gather", "cat": "stream", "ph": "X",
      "ts": <µs since tracer epoch>, "dur": <µs>,
-     "pid": <os pid>, "tid": <thread id>, "args": {...}}
+     "pid": <os pid>, "tid": <thread id>,
+     "args": {"id": <n>, "parent": <id>, "call": <n>, "step": <n>, ...}}
+
+``id`` numbers the span; ``parent`` is the id of the span that was open
+on the same thread when this one began (the span that caused it);
+``call`` is the ordinal of the ``fit()`` call and ``step`` the train step
+it belongs to — what joins a span from the prefetch or scorer thread to
+the step it served.
+
+One timeline: every span is also a ``jax.profiler.TraceAnnotation`` of
+the same name, so whenever a ``jax.profiler`` capture is open (a
+benchmark's traced run, the anomaly engine's window) the program's
+spans lie on the capture's host lane, on the profiler's clock, next to
+the device's lanes. With no capture open an annotation is a flag test.
 
 ``docs/OBSERVABILITY.md`` documents the schema and the fixed span
 vocabulary the trainer and prefetch pipeline emit.
@@ -30,6 +44,7 @@ vocabulary the trainer and prefetch pipeline emit.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -149,6 +164,14 @@ class NullTracer:
     def span(self, name: str, cat: str = "trainer", **args) -> _NullSpan:
         return _NULL_SPAN
 
+    def call_span(self, name: str, cat: str = "trainer",
+                  **args) -> _NullSpan:
+        return _NULL_SPAN
+
+    def step_span(self, name: str, step: int, cat: str = "trainer",
+                  **args) -> _NullSpan:
+        return _NULL_SPAN
+
     def instant(self, name: str, cat: str = "trainer", **args) -> None:
         return None
 
@@ -173,29 +196,42 @@ class _Span:
     """One live span: measures ``perf_counter_ns`` across the body and
     appends a ring tuple on exit. Exceptions propagate (the span still
     records — a span that died mid-body is exactly what a post-mortem
-    wants to see)."""
+    wants to see). ``annotation`` is the profiler annotation held open
+    across the body."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_annotation",
+                 "_stack", "_id", "_parent", "_call", "_step", "_t0")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]) -> None:
+                 args: Optional[Dict[str, Any]], annotation: Any) -> None:
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._annotation = annotation
 
     def __enter__(self) -> "_Span":
+        tr = self._tracer
+        stack = self._stack = tr._stack()
+        self._id = next(tr._ids)
+        self._parent = stack[-1] if stack else None
+        self._call, self._step = tr.call, tr.step
+        stack.append(self._id)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        self._stack.pop()
         tr = self._tracer
         # deque.append is atomic under the GIL: spans land from the
         # training thread, the prefetch worker, and the metric drain
         # thread without a lock on the hot path.
         tr._ring.append((self._name, self._cat, threading.get_ident(),
-                         self._t0, t1 - self._t0, self._args))
+                         self._t0, t1 - self._t0, self._args, self._id,
+                         self._parent, self._call, self._step))
         tr._total += 1
         return False
 
@@ -218,16 +254,59 @@ class SpanTracer:
         self._epoch_ns = time.perf_counter_ns()
         self._epoch_unix = time.time()
         self._thread_names: Dict[int, str] = {}
+        # jax only here, not at import: journal_lane_events and the
+        # offline merge stay stdlib-only.
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._step_annotation = StepTraceAnnotation
+        self._ids = itertools.count(1)
+        self._local = threading.local()  # .stack: ids of the open spans
+        #: What the train thread is at, read by every span as it begins
+        #: (plain attributes: one writer, reads atomic under the GIL).
+        #: ``call``: ordinal of the ``fit()`` call (:meth:`call_span`);
+        #: ``step``: the train step last dispatched (:meth:`step_span`).
+        self.call = 0
+        self.step: Optional[int] = None
+
+    def _stack(self) -> List[int]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
     # ------------------------------------------------------------ recording
     def span(self, name: str, cat: str = "trainer", **args) -> _Span:
         """Context manager timing its body as one complete event."""
-        return _Span(self, name, cat, args or None)
+        return _Span(self, name, cat, args or None, self._annotation(name))
+
+    def call_span(self, name: str, cat: str = "trainer", **args) -> _Span:
+        """The root span of one call into the program (``fit()``): counts
+        the call, and every span that begins until the next one records
+        that ordinal as ``call``."""
+        self.call += 1
+        return self.span(name, cat, **args)
+
+    def step_span(self, name: str, step: int, cat: str = "trainer",
+                  **args) -> _Span:
+        """The span that issues train step ``step`` (the first of
+        ``steps=k`` for a scanned chunk): a ``StepTraceAnnotation`` in an
+        open capture, and every span that begins until the next one, on
+        any thread, records ``step``."""
+        self.step = int(step)
+        return _Span(self, name, cat, args or None,
+                     self._step_annotation(name, step_num=self.step))
 
     def instant(self, name: str, cat: str = "trainer", **args) -> None:
         """Zero-duration marker event (trigger points, mode switches)."""
-        self._ring.append((name, cat, threading.get_ident(),
-                           time.perf_counter_ns(), -1, args or None))
+        with self._annotation(name):
+            now = time.perf_counter_ns()
+        stack = self._stack()
+        self._ring.append((name, cat, threading.get_ident(), now, -1,
+                           args or None, next(self._ids),
+                           stack[-1] if stack else None, self.call,
+                           self.step))
         self._total += 1
 
     def register_thread(self, name: str) -> None:
@@ -245,7 +324,8 @@ class SpanTracer:
         in-time copy — safe while other threads keep recording."""
         pid = os.getpid()
         events: List[Dict[str, Any]] = []
-        for name, cat, tid, t0_ns, dur_ns, args in list(self._ring):
+        for (name, cat, tid, t0_ns, dur_ns, args, span_id, parent, call,
+             step) in list(self._ring):
             ev: Dict[str, Any] = {
                 "name": name,
                 "cat": cat,
@@ -259,8 +339,15 @@ class SpanTracer:
             else:
                 ev["ph"] = "X"
                 ev["dur"] = dur_ns / 1e3
+            ev["args"] = {"id": span_id}
+            if parent is not None:
+                ev["args"]["parent"] = parent
+            if call:
+                ev["args"]["call"] = call
+            if step is not None:
+                ev["args"]["step"] = step
             if args:
-                ev["args"] = dict(args)
+                ev["args"].update(args)  # a call site's own step= wins
             events.append(ev)
         return events
 

@@ -542,10 +542,13 @@ def make_train_step(
             imgs = imgs.astype(out_dtype)
         return imgs
 
+    @jax.named_scope("mercury_draw")
     def _select(k_sel, pool_losses, ema):
         """EMA update + score→normalize→draw, returning
         ``(selected, scaled_probs, new_ema, avg_pool_loss)`` — shared by the
-        inline and pipelined paths (Pallas or jax-native)."""
+        inline and pipelined paths (Pallas or jax-native). With
+        ``_drawn_rows`` it is the ``mercury_draw`` scope: the pool sampler's
+        draw, beside ``mercury_scoring`` and not inside it."""
         if use_pallas:
             from mercury_tpu.ops import score_and_draw_pallas
 
@@ -562,6 +565,11 @@ def make_train_step(
         )
         return sel.selected, sel.scaled_probs, sel.ema, sel.avg_pool_loss
 
+    @jax.named_scope("mercury_draw")
+    def _drawn_rows(selected, images, labels):
+        """The drawn rows of the scored pool."""
+        return images[selected], labels[selected]
+
     def score_rows(state, raw, labs, ka, reuse_images=True):
         """Augment → inference-mode scoring forward over already-gathered
         rows — the pool-scoring core shared by the device-resident
@@ -569,19 +577,24 @@ def make_train_step(
         arrive pre-gathered from the host pipeline). Callers wrap the
         call in the ``mercury_scoring`` named scope the jaxpr auditor
         anchors on (one scope per call site — nesting would rename the
-        anchor). ``reuse_images=False`` marks scorer-only sites (the
+        anchor); the three scopes opened here split it by layer, for the
+        device trace: ``mercury_pool_ingest`` (with the caller's gather),
+        ``mercury_score_forward``, ``mercury_score_loss``.
+        ``reuse_images=False`` marks scorer-only sites (the
         returned images are discarded, e.g. scoretable refresh windows):
         with ``scoring_dtype="bfloat16"`` those ingest straight to bf16 —
         uint8 → bf16 score, no f32 activation round trip. Returns
         ``(imgs, pool_logits, scores)``."""
         scorer_only = not reuse_images and scoring_bf16
-        imgs = _ingest(
-            ka, raw, out_dtype=jnp.bfloat16 if scorer_only else None
-        )
-        if scoring_model is None:
-            pool_logits, _, _ = _apply_train(
-                state.params, state.batch_stats, imgs, False
+        with jax.named_scope("mercury_pool_ingest"):
+            imgs = _ingest(
+                ka, raw, out_dtype=jnp.bfloat16 if scorer_only else None
             )
+        if scoring_model is None:
+            with jax.named_scope("mercury_score_forward"):
+                pool_logits, _, _ = _apply_train(
+                    state.params, state.batch_stats, imgs, False
+                )
         else:
             # Same params, lower-precision compute (scoring_dtype) —
             # scores only rank candidates, and the reweight divides by
@@ -590,17 +603,20 @@ def make_train_step(
             # ingest already emitted bf16) so the activations never
             # materialize at f32; the returned imgs keep the training
             # precision when the caller reuses them.
-            s_in = imgs.astype(jnp.bfloat16) if scoring_bf16 else imgs
             variables = {"params": state.params}
             mutable = ["losses"]
             if state.batch_stats:
                 variables["batch_stats"] = state.batch_stats
                 mutable = ["batch_stats", "losses"]
-            pool_logits, _ = scoring_model.apply(
-                variables, s_in, train=True, mutable=mutable
-            )
-            pool_logits = pool_logits.astype(jnp.float32)
-        return imgs, pool_logits, _score_per_sample(pool_logits, labs)
+            with jax.named_scope("mercury_score_forward"):
+                s_in = imgs.astype(jnp.bfloat16) if scoring_bf16 else imgs
+                pool_logits, _ = scoring_model.apply(
+                    variables, s_in, train=True, mutable=mutable
+                )
+                pool_logits = pool_logits.astype(jnp.float32)
+        with jax.named_scope("mercury_score_loss"):
+            scores = _score_per_sample(pool_logits, labs)
+        return imgs, pool_logits, scores
 
     def probe_var_ratio(state, sel_images, sel_labels, scaled_probs):
         """Grad-variance probe (``sampler_dist/var_ratio``, the
@@ -685,9 +701,12 @@ def make_train_step(
                 total = total + config.moe_aux_weight * aux
             return total, (logits, new_bs, aux)
 
-        (loss, (logits, new_batch_stats, moe_aux)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(state.params)
+        # One scope for both halves: jax marks the backward's ops itself
+        # (``transpose(jvp(...))`` in the op's path), which is what the
+        # device trace splits forward from backward by.
+        with jax.named_scope("mercury_train"):
+            (loss, (logits, new_batch_stats, moe_aux)), grads = (
+                jax.value_and_grad(loss_fn, has_aux=True)(state.params))
 
         # --- optional quantization: each worker stochastically quantizes
         # its local gradient (independent keys); the mean across workers
@@ -875,7 +894,8 @@ def make_train_step(
             ``reuse_images`` forwards to ``score_rows`` (False at
             scorer-only sites: bf16 ingest under scoring_dtype)."""
             with jax.named_scope("mercury_scoring"):
-                raw, labs = gather_train(slots)
+                with jax.named_scope("mercury_pool_ingest"):
+                    raw, labs = gather_train(slots)
                 imgs, pool_logits, scores = score_rows(
                     state, raw, labs, ka, reuse_images=reuse_images
                 )
@@ -893,9 +913,9 @@ def make_train_step(
                 imgs, labs, pool_logits, pool_losses = score_slots(slots, ka)
                 ema_prev = ema.value
                 selected, scaled, ema, avg = _select(ksel, pool_losses, ema)
+                sel_imgs, sel_labs = _drawn_rows(selected, imgs, labs)
                 pend = PendingBatch(
-                    images=imgs[selected], labels=labs[selected],
-                    scaled_probs=scaled,
+                    images=sel_imgs, labels=sel_labs, scaled_probs=scaled,
                 )
                 tel = ()
                 if telemetry:
@@ -1142,8 +1162,9 @@ def make_train_step(
                     avg_pool_loss = _pool_loss_metric(
                         pool_logits, labels, score_avg
                     )
-                    sel_images = images[selected]
-                    sel_labels = labels[selected]
+                    sel_images, sel_labels = _drawn_rows(
+                        selected, images, labels
+                    )
                 if telemetry:
                     clip_frac = clip_fraction(
                         pool_losses, ema.value, config.is_alpha
@@ -1389,8 +1410,7 @@ def make_train_step(
                 k_sel, pool_losses, ema
             )
             avg_pool_loss = _pool_loss_metric(pool_logits, labs, score_avg)
-            sel_images = imgs[selected]
-            sel_labels = labs[selected]
+            sel_images, sel_labels = _drawn_rows(selected, imgs, labs)
             if telemetry:
                 clip_frac = clip_fraction(
                     pool_losses, ema.value, config.is_alpha

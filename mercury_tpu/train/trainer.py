@@ -23,9 +23,10 @@ Parity map:
   (``obs/manifest.py``, ``obs/accounting.py``).
 - wall-clock segment timing (``step/ff/is/bp/sync``, ``:129-168``): a fused
   XLA step has no host-visible segment boundaries — the trainer reports
-  true ``step_time`` and throughput; per-segment attribution lives in
-  ``mercury_tpu.train.profile`` (instrumented sub-step timings comparable
-  to the reference's five named segments).
+  true ``step_time`` and throughput; the five segments are read off one
+  profiler capture of the real step, by its named scopes (``perfbench``'s
+  ``device_ms_per_step``, ``train_forward_share``, ``train_backward_share``,
+  ``scoring_share`` and the ``mercury_grad_sync`` scope's share).
 """
 
 from __future__ import annotations
@@ -1034,7 +1035,7 @@ class Trainer:
                         self.supervisor.request_restart("prefetch", step):
                     raise
                 batch = self._stream_pipe.pop()
-        with self.tracer.span("trainer/dispatch", cat="trainer"):
+        with self.tracer.step_span("trainer/dispatch", step):
             self.state, metrics, next_gidx = self.train_step(  # graftlint: disable=GL120 -- supervisor callbacks run on the trainer thread only (see pop() above); state is never touched off-thread
                 self.state, batch, self._step_y, self.dataset.shard_indices
             )
@@ -1365,6 +1366,14 @@ class Trainer:
 
         Returns the final eval metrics. Honors the step-budget break
         (``step×world_size > budget``, ``:71``)."""
+        self.tracer.register_thread("train")
+        # The root span: everything a call does lies under it (its own
+        # self time is the loop's bookkeeping), and what lies between two
+        # of them is the caller's code.
+        with self.tracer.call_span("trainer/fit", cat="trainer"):
+            return self._fit(num_epochs)
+
+    def _fit(self, num_epochs: Optional[int]) -> Dict[str, float]:
         cfg = self.config
         num_epochs = num_epochs or cfg.num_epochs
         step = int(self.state.step)
@@ -1391,7 +1400,6 @@ class Trainer:
             """Did [at-advanced, at] cross a multiple of ``every``?"""
             return bool(every) and (at // every) > ((at - advanced) // every)
 
-        self.tracer.register_thread("train")
         try:
             while step < end:
                 # Wall time of the whole training action: under async
@@ -1411,8 +1419,8 @@ class Trainer:
                     metrics = self._host_stream_step(step)
                 elif self.train_step_many is not None and step + self.scan_steps <= end:
                     k = self.scan_steps
-                    with self.tracer.span("trainer/dispatch",
-                                          cat="trainer", steps=k):
+                    with self.tracer.step_span("trainer/dispatch", step,
+                                               steps=k):
                         self.state, metrics = self.train_step_many(
                             self.state,
                             self._step_x,
@@ -1421,7 +1429,7 @@ class Trainer:
                         )
                 else:
                     k = 1
-                    with self.tracer.span("trainer/dispatch", cat="trainer"):
+                    with self.tracer.step_span("trainer/dispatch", step):
                         self.state, metrics = self.train_step(
                             self.state,
                             self._step_x,
@@ -1464,12 +1472,16 @@ class Trainer:
                         # enabling perf/mfu.
                         fn, ks = ((self.train_step_many, self.scan_steps)
                                   if k > 1 else (self.train_step, 1))
-                        self._throughput.flops_per_step = (
-                            analytic_flops_per_step(
-                                fn, self.state, self._step_x, self._step_y,
-                                self.dataset.shard_indices, scan_steps=ks,
+                        with self.tracer.span("trainer/flops_probe",
+                                              cat="trainer", step=step):
+                            self._throughput.flops_per_step = (
+                                analytic_flops_per_step(
+                                    fn, self.state, self._step_x,
+                                    self._step_y,
+                                    self.dataset.shard_indices,
+                                    scan_steps=ks,
+                                )
                             )
-                        )
                         self._flops_known = True
                     # Enqueue the ON-DEVICE metric pytree: no float(), no
                     # device sync, no filesystem write on this thread. The
@@ -1589,12 +1601,17 @@ class Trainer:
             # Drain the metric queue to the sinks so callers (and crashed
             # runs' postmortems) see every step logged up to here. The
             # writer itself stays open — fit() can be called again.
-            self.logger.flush()
+            with self.tracer.span("trainer/flush", cat="trainer", step=step):
+                self.logger.flush()
         if not final_metrics:
-            final_metrics = self.evaluate()
+            with self.tracer.span("trainer/eval", cat="trainer", step=step,
+                                  closing=True):
+                final_metrics = self.evaluate()
         if cfg.checkpoint_dir:
-            ckpt.save_checkpoint(cfg.checkpoint_dir, self.state, step,
-                                 **self._ckpt_kwargs())
+            with self.tracer.span("trainer/final_checkpoint", cat="trainer",
+                                  step=step):
+                ckpt.save_checkpoint(cfg.checkpoint_dir, self.state, step,
+                                     **self._ckpt_kwargs())
         return final_metrics
 
     def _ckpt_kwargs(self) -> Dict[str, Any]:
@@ -1820,16 +1837,21 @@ class Trainer:
         return self._eval_cache[train]
 
     def _eval_split(self, train: bool) -> Dict[str, float]:
-        images_b, labels_b, valid_b = self._eval_arrays(train)
-        loss_sum, correct, count = self.eval_epoch(
-            self.state.params, self.state.batch_stats, images_b, labels_b, valid_b
-        )
-        count = max(float(count), 1.0)
         prefix = "train" if train else "test"
-        return {
-            f"{prefix}/eval_loss": float(loss_sum) / count,
-            f"{prefix}/eval_acc": float(correct) / count,
-        }
+        with self.tracer.span("eval/dispatch", cat="eval", split=prefix):
+            images_b, labels_b, valid_b = self._eval_arrays(train)
+            loss_sum, correct, count = self.eval_epoch(
+                self.state.params, self.state.batch_stats, images_b,
+                labels_b, valid_b
+            )
+        # The host floats are the fence: this waits for the split's epoch
+        # and for every train step queued before it.
+        with self.tracer.span("eval/fetch", cat="eval", split=prefix):
+            count = max(float(count), 1.0)
+            return {
+                f"{prefix}/eval_loss": float(loss_sum) / count,
+                f"{prefix}/eval_acc": float(correct) / count,
+            }
 
     def evaluate(self, include_train: bool = True) -> Dict[str, float]:
         """Full train+test pass in inference mode

@@ -38,7 +38,7 @@ class TestSpanTracer:
         assert ev["ph"] == "X"
         assert ev["dur"] >= 1000.0  # µs — the 2 ms body, minus clock slop
         assert ev["ts"] >= 0.0  # µs since tracer epoch
-        assert ev["args"] == {"steps": 4}
+        assert ev["args"] == {"id": 1, "steps": 4}  # a root, no fit() call
         assert ev["pid"] == os.getpid()
         assert ev["tid"] == threading.get_ident()
 
@@ -49,7 +49,7 @@ class TestSpanTracer:
         assert ev["ph"] == "i"
         assert ev["s"] == "t"
         assert "dur" not in ev
-        assert ev["args"] == {"step": 7}
+        assert ev["args"] == {"id": 1, "step": 7}
 
     def test_ring_keeps_last_capacity_and_counts_dropped(self):
         tr = SpanTracer(capacity=8)
