@@ -72,7 +72,7 @@ def _step_flops(trainer) -> float:
     ds = trainer.dataset
     step_fn = trainer.train_step_many or trainer.train_step
     cost = step_fn.lower(
-        trainer.state, ds.x_train, ds.y_train, ds.shard_indices
+        trainer.state, trainer._step_x, ds.y_train, ds.shard_indices
     ).compile().cost_analysis()
     return float(cost["flops"])
 
@@ -89,13 +89,13 @@ def bench_fused(trainer, sc: dict) -> float:
     # Warmup covers the compile (Trainer commits state and inputs on the
     # mesh, so there is one) and lets the dispatch queue settle.
     for _ in range(3 if k > 1 else sc["warmup"]):
-        state, metrics = step_fn(state, ds.x_train, ds.y_train, ds.shard_indices)
+        state, metrics = step_fn(state, trainer._step_x, ds.y_train, ds.shard_indices)
         np.asarray(metrics["train/loss"])
     # Timing fence = host fetch of the final loss: the transfer cannot
     # complete before the last step that produced it has run.
     t0 = time.perf_counter()
     for _ in range(calls):
-        state, metrics = step_fn(state, ds.x_train, ds.y_train, ds.shard_indices)
+        state, metrics = step_fn(state, trainer._step_x, ds.y_train, ds.shard_indices)
     np.asarray(metrics["train/loss"])
     dt = time.perf_counter() - t0
     trainer.state = state

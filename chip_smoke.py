@@ -83,7 +83,7 @@ def _committed_on_mesh(trainer) -> List[str]:
 
     n = trainer.mesh.devices.size
     ds = trainer.dataset
-    tree = {"state": trainer.state, "x_train": ds.x_train,
+    tree = {"state": trainer.state, "x_train": trainer._step_x,
             "y_train": ds.y_train, "shard_indices": ds.shard_indices}
     bad = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
@@ -174,7 +174,7 @@ def _deep_checks(name: str, trainer, tiny: bool) -> Dict[str, Any]:
     # must carry the Mosaic custom call and nothing may run under the
     # interpreter; off it, neither.
     text = trainer.train_step.lower(
-        trainer.state, ds.x_train, ds.y_train, ds.shard_indices
+        trainer.state, trainer._step_x, ds.y_train, ds.shard_indices
     ).compile().as_text()
     facts: Dict[str, Any] = {"mosaic_in_step": "tpu_custom_call" in text}
     _require(facts["mosaic_in_step"] == on_tpu()
@@ -215,7 +215,7 @@ def _profile_three_steps(name: str, trainer, tmp: str) -> Dict[str, Any]:
     try:
         for _ in range(3):
             trainer.state, metrics = trainer.train_step(
-                trainer.state, ds.x_train, ds.y_train, ds.shard_indices)
+                trainer.state, trainer._step_x, ds.y_train, ds.shard_indices)
         jax.block_until_ready(metrics)
     finally:
         jax.profiler.stop_trace()

@@ -538,14 +538,16 @@ class TrainConfig:
     # None → auto (Pallas kernels on TPU, jax-native elsewhere);
     # True/False force. Pallas path requires label_smoothing == 0.
     use_pallas: Optional[bool] = None
-    # Fused uint8 ingest: replace the normalize_images + augment_batch HLO
-    # chain with data.pipeline.augment_normalize — dequant → per-channel
-    # normalize → crop/flip as one chain on the raw bytes (they enter
-    # device memory as uint8), under the mercury_input_fuse named scope.
-    # Bit-identical trajectories to the unfused path at f32
-    # (test-enforced); with scoring_dtype="bfloat16" the scorer-only
-    # ingest emits bf16 directly (uint8 → bf16 scoring, no f32 round
-    # trip). Requires uint8 image data, augmentation="noniid",
+    # uint8 image rows under augmentation="noniid" without cutout are
+    # ingested by data.pipeline.augment_normalize on every path — one
+    # dense pass over the raw bytes, crop/flip as exact selection, then
+    # normalize (train/step.py::ingest_path picks it from what the step
+    # sees; PERF.md section 6, PR 26) — bit-identical at f32 to the
+    # normalize_images + augment_batch chain (test-enforced). This flag
+    # no longer changes the ingest: it names the scope the pass runs
+    # under (mercury_input_fuse instead of mercury_augmentation, for the
+    # profile bucket and the lint plan that key on it) and keeps its
+    # validation: requires uint8 image data, augmentation="noniid",
     # cutout=False.
     fused_input: bool = False
 

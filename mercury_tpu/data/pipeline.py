@@ -132,6 +132,84 @@ def augment_batch(
     return out
 
 
+def crop_flip_draws(
+    key: jax.Array, n: int, pad: int = 4
+) -> Tuple[jax.Array, jax.Array]:
+    """The crop offsets ``off`` (``[n, 2]`` int32 in ``[0, 2*pad]``, row
+    then column) and flips (``[n]`` bool) that :func:`augment_batch` draws
+    from ``key`` — the same split, the same ``randint`` and ``bernoulli``,
+    so either path replays the other's trajectory from one key (the third
+    subkey is cutout's and stays unused here)."""
+    k_crop, k_flip, _k_cut = jax.random.split(key, 3)
+    off = jax.random.randint(k_crop, (n, 2), 0, 2 * pad + 1)
+    flip = jax.random.bernoulli(k_flip, shape=(n,))
+    return off, flip
+
+
+def select_crop_flip(
+    raw: jax.Array,
+    off: jax.Array,
+    flip: jax.Array,
+    mean,
+    std,
+    image_shape: Optional[Tuple[int, int, int]] = None,
+    pad: int = 4,
+    out_dtype=jnp.float32,
+) -> jax.Array:
+    """Zero-pad(``pad``) + crop at ``off`` + flip + normalize of uint8
+    image rows as ONE dense pass: ``out[i, y, x] = raw[i, y + oy - pad,
+    fx(x) + ox - pad]`` (``fx(x) = W - 1 - x`` for a flipped image), exact
+    0.0 outside the image, bit-identical (at f32) to
+    ``hflip(crop(pad(normalize_images(raw))))`` with the same draws.
+
+    ``raw`` is ``[n, H, W, C]`` uint8 or the flat rows ``[n, H*W*C]`` the
+    step keeps resident (then ``image_shape=(H, W, C)``); the result is
+    ``[n, H, W, C]`` in ``out_dtype``, cast as the last op.
+
+    The selection runs on the raw bytes, as two one-hot contractions on
+    ``[n, H, W*C]`` (rows, then columns with the flip folded in). The
+    pixels go in as ``value + 1``: 1..256 and the one-hots are exact in
+    bf16, each output element has at most one non-zero term and the
+    accumulator is f32, so what comes out is exactly ``value + 1`` inside
+    the image and exactly 0 in the padding, which no pixel can be. The
+    channels never stand alone in a minor dimension (which the TPU tiles
+    to 128 lanes — the gather/pad/select chain this replaces moved 9.7 GB
+    for a 31 MB pool, PERF.md section 6). Normalization comes last, in
+    :func:`normalize_images`' own order, and the padding is masked to the
+    exact zero the old chain padded with."""
+    if raw.dtype != jnp.uint8:
+        raise ValueError(
+            f"select_crop_flip selects on raw uint8 pixels; got {raw.dtype}")
+    if raw.ndim == 4:
+        image_shape = tuple(int(d) for d in raw.shape[1:])
+    elif raw.ndim != 2 or image_shape is None:
+        raise ValueError(
+            "select_crop_flip takes [n, H, W, C] images, or flat "
+            f"[n, H*W*C] rows with image_shape; got {raw.shape}")
+    h, w, c = image_shape
+    n = raw.shape[0]
+    pix = raw.reshape(n, h, w * c).astype(jnp.bfloat16) + 1
+    ys, xs = jnp.arange(h), jnp.arange(w)
+    src_y = ys[None, :] + (off[:, 0] - pad)[:, None]               # [n, H]
+    fx = jnp.where(flip[:, None], w - 1 - xs[None, :], xs[None, :])
+    src_x = fx + (off[:, 1] - pad)[:, None]                        # [n, W]
+    # Column q = (x, ch) of the output reads column (src_x[x], ch) of the
+    # source: one [W*C, W*C] one-hot per image. A source row or column
+    # outside the image matches nothing and selects the 0.
+    src_q = (src_x[:, :, None] * c + jnp.arange(c)).reshape(n, w * c)
+    sel_y = src_y[:, :, None] == ys                                # [n, y, k]
+    sel_q = jnp.arange(w * c)[:, None] == src_q[:, None, :]        # [n, k, q]
+    rows = jnp.einsum("nyk,nkq->nyq", sel_y.astype(jnp.bfloat16), pix,
+                      preferred_element_type=jnp.float32)
+    sel = jnp.einsum("nyk,nkq->nyq", rows.astype(jnp.bfloat16),
+                     sel_q.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)
+    sel = sel.reshape(n, h, w, c)
+    x = (sel - 1.0) / 255.0
+    x = (x - jnp.asarray(mean)) / jnp.asarray(std)
+    return jnp.where(sel > 0, x, 0.0).astype(jnp.dtype(out_dtype))
+
+
 def augment_normalize(
     key: jax.Array,
     raw: jax.Array,
@@ -139,36 +217,26 @@ def augment_normalize(
     std,
     pad: int = 4,
     out_dtype=jnp.float32,
+    image_shape: Optional[Tuple[int, int, int]] = None,
 ) -> jax.Array:
-    """Fused uint8 ingest (``fused_input=True``): dequant + per-channel
-    normalize + random crop(``pad``) + horizontal flip as one chain on the
-    raw bytes, bit-identical (at f32) to
+    """The uint8 ingest: dequant + per-channel normalize + random
+    crop(``pad``) + horizontal flip in one pass over the raw bytes
+    (:func:`select_crop_flip`), bit-identical (at f32) to
     ``augment_batch(key, normalize_images(raw, mean, std))``.
 
-    ``raw``: [N, H, W, C] uint8; ``mean``/``std``: per-channel constants.
+    ``raw``: ``[N, H, W, C]`` uint8, or flat ``[N, H*W*C]`` rows with
+    ``image_shape``; ``mean``/``std``: per-channel constants.
     ``out_dtype`` is applied as the LAST op, so the bf16 scoring path
-    (``scoring_dtype="bfloat16"`` + ``fused_input``) emits bf16 activations
-    directly — one rounding of the exact f32 value.
+    (``scoring_dtype="bfloat16"``) emits bf16 activations directly — one
+    rounding of the exact f32 value.
 
     The crop/flip draws replay ``augment_batch``'s key consumption exactly
-    (split 3 ways; ``randint`` for offsets, ``bernoulli`` for flips), so a
-    trajectory is reproducible from the same JAX key on either path. Runs
-    under the ``mercury_input_fuse`` named scope — the profile-attribution
-    bucket (``prof/scope_frac/mercury_input_fuse``) and the jaxpr auditor
-    both key on this anchor."""
-    n, h, w, _ = raw.shape
-    # Mirror augment_batch's split even though cutout is unsupported here
-    # (config validation rejects fused_input + cutout): the draw STREAM
-    # must match so unfused trajectories replay bit-for-bit.
-    k_crop, k_flip, _k_cut = jax.random.split(key, 3)
-    off = jax.random.randint(k_crop, (n, 2), 0, 2 * pad + 1)
-    flip = jax.random.bernoulli(k_flip, shape=(n,))
-    with jax.named_scope("mercury_input_fuse"):
-        xn = normalize_images(raw, mean, std)
-        padded = jnp.pad(xn, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-        out = _take_crops(padded, off[:, 0], off[:, 1], h, w)
-        out = jnp.where(flip[:, None, None, None], out[:, :, ::-1, :], out)
-        return out.astype(jnp.dtype(out_dtype))
+    (:func:`crop_flip_draws`), so a trajectory is reproducible from the
+    same JAX key on either path."""
+    off, flip = crop_flip_draws(key, raw.shape[0], pad)
+    return select_crop_flip(raw, off, flip, mean, std,
+                            image_shape=image_shape, pad=pad,
+                            out_dtype=out_dtype)
 
 
 def next_pool(

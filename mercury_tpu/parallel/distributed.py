@@ -197,7 +197,7 @@ def globalize_dataset(dataset, mesh: Mesh, axis_name: str = "data",
 
 
 def worker_shard_global_arrays(
-    dataset, mesh: Mesh, axis_name: str = "data"
+    dataset, mesh: Mesh, axis_name: str = "data", flat_rows: bool = False
 ) -> Tuple[jax.Array, jax.Array]:
     """Materialize the per-worker train data as ``[W, L, ...]`` global
     arrays sharded ``P(axis_name)`` — each host constructs and transfers
@@ -205,9 +205,13 @@ def worker_shard_global_arrays(
     and no host→device path ever carries the full dataset. This is the
     scaling-past-CIFAR data path (``data_placement="sharded"``),
     capability parity with ``load_partition_data_distributed_cifar10``
-    (``cifar10/data_loader.py:214-245``)."""
+    (``cifar10/data_loader.py:214-245``). ``flat_rows`` flattens each
+    sample (``[W, L, H*W*C]``): the layout the step's selection ingest
+    gathers from without a relayout (``train/step.py::ingest_path``)."""
     sidx = np.asarray(dataset.shard_indices)
     xs = np.asarray(dataset.x_train)
+    if flat_rows:
+        xs = xs.reshape(xs.shape[0], -1)
     ys = np.asarray(dataset.y_train)
     W, L = sidx.shape
     sharding = NamedSharding(mesh, P(axis_name))

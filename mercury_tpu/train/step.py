@@ -145,6 +145,18 @@ def mercury_state_out_shardings(
     return state_sh, n(P())
 
 
+def ingest_path(config: TrainConfig, dtype) -> str:
+    """Which ingest :func:`make_train_step` builds for rows of ``dtype``:
+    ``"select"`` — uint8 image rows under the noniid crop/flip, one dense
+    pass over the raw bytes (``data.pipeline.select_crop_flip``) — or
+    ``"chain"`` — ``normalize_images`` then the augmentation, for float
+    inputs, ``augmentation="iid"``/``"none"`` and cutout. Decided at
+    trace time from what the step sees; no config field picks it."""
+    select = (jnp.dtype(dtype) == jnp.uint8
+              and config.augmentation == "noniid" and not config.cutout)
+    return "select" if select else "chain"
+
+
 def make_train_step(
     model,
     tx: optax.GradientTransformation,
@@ -156,6 +168,7 @@ def make_train_step(
     state_out_shardings=None,
     scoring_model=None,
     io_constraints: bool = True,
+    image_shape: Optional[Tuple[int, int, int]] = None,
 ) -> Callable[..., Tuple[MercuryState, Dict[str, jax.Array]]]:
     """Build the jitted train step.
 
@@ -163,6 +176,12 @@ def make_train_step(
     (new_state, metrics)`` where ``x_train``/``y_train`` are the full
     device-resident train arrays (replicated) and ``shard_indices`` is the
     ``[W, L]`` per-worker index matrix (sharded over the data axis).
+
+    uint8 image rows may arrive flat — ``x_train`` as ``[N, H*W*C]`` (or
+    ``[W, L, H*W*C]`` sharded, ``[W, S, H*W*C]`` streamed) with
+    ``image_shape=(H, W, C)`` — which is what ``Trainer`` hands the step
+    on the selection ingest (:func:`ingest_path`): the pool's gather is
+    then a dense row gather and the resident set is never relaid out.
 
     With ``scan_steps > 1`` the returned function advances ``scan_steps``
     steps per call — the step body wrapped in ``lax.scan`` inside the same
@@ -518,25 +537,32 @@ def make_train_step(
 
     def _ingest(key, raw, out_dtype=None):
         """Raw rows → augmented normalized images: THE ingest boundary —
-        every sampler path funnels its pixel rows through here. Unfused,
-        it is the ``normalize_images`` + ``_augment`` HLO chain; with
-        ``config.fused_input`` it is one chain on the raw uint8 rows with
-        pre-drawn offsets (``data.pipeline.augment_normalize``,
-        ``mercury_input_fuse`` scope) that consumes ``key`` identically,
-        so trajectories are bit-identical at f32 (test-enforced,
-        tests/test_ops.py).
+        every sampler path funnels its pixel rows through here. Which
+        ingest runs is read off the rows (:func:`ingest_path`): uint8
+        image rows under the noniid crop/flip take one dense pass over the
+        raw bytes (``data.pipeline.augment_normalize``: crop and flip as
+        exact selection, normalize last); float inputs, ``iid`` and cutout
+        keep the ``normalize_images`` + ``_augment`` chain. Both consume
+        ``key`` identically and agree bit for bit at f32 (test-enforced,
+        tests/test_ops.py). The selection's ops sit under
+        ``mercury_augmentation``, or ``mercury_input_fuse`` with
+        ``config.fused_input`` — the same pass under the scope the
+        profile attribution and the jaxpr auditor key on.
         ``out_dtype`` (the bf16 scoring ingest) is applied as the LAST op
-        on both paths, so the fused/unfused agreement survives the cast."""
-        if fused_input:
-            if raw.dtype != jnp.uint8:
-                raise ValueError(
-                    "fused_input ingests raw uint8 rows (the chain owns "
-                    f"the /255 dequant); got {raw.dtype}"
-                )
-            return augment_normalize(
-                key, raw, mean, std,
-                out_dtype=(jnp.float32 if out_dtype is None else out_dtype),
+        on both paths."""
+        if fused_input and raw.dtype != jnp.uint8:
+            raise ValueError(
+                "fused_input ingests raw uint8 rows (the chain owns "
+                f"the /255 dequant); got {raw.dtype}"
             )
+        if ingest_path(config, raw.dtype) == "select":
+            with jax.named_scope("mercury_input_fuse" if fused_input
+                                 else "mercury_augmentation"):
+                return augment_normalize(
+                    key, raw, mean, std, image_shape=image_shape,
+                    out_dtype=(jnp.float32 if out_dtype is None
+                               else out_dtype),
+                )
         imgs = _augment(key, normalize_images(raw, mean, std))
         if out_dtype is not None:
             imgs = imgs.astype(out_dtype)
@@ -567,7 +593,14 @@ def make_train_step(
 
     @jax.named_scope("mercury_draw")
     def _drawn_rows(selected, images, labels):
-        """The drawn rows of the scored pool."""
+        """The drawn rows of the scored pool. Images are gathered as
+        ``[n, H, W*C]`` rows — the dense form the ingest's selection
+        leaves them in — so the row gather reads the pool as it was
+        written instead of a relayout with the channels minor."""
+        if images.ndim == 4:
+            n, h, w, c = images.shape
+            drawn = images.reshape(n, h, w * c)[selected]
+            return drawn.reshape(-1, h, w, c), labels[selected]
         return images[selected], labels[selected]
 
     def score_rows(state, raw, labs, ka, reuse_images=True):

@@ -371,13 +371,31 @@ class Trainer:
         # identical on every process by seeded construction).
         data_sharded = config.data_placement == "sharded"
         host_stream = config.data_placement == "host_stream"
+        # Which ingest the step is built with (step.ingest_path reads it
+        # off the rows' dtype and the augmentation). On the selection
+        # ingest the step's image rows are FLAT — [N, H*W*C] uint8, a
+        # host-side view of x_train — so the pool's gather is a dense row
+        # gather and the step never relays the resident set out (PERF.md
+        # section 6, PR 26); dataset.x_train keeps its [N, H, W, C] face
+        # for evaluate() and every other reader.
+        from mercury_tpu.train.step import ingest_path
+
+        self._ingest_path = ingest_path(config, self.dataset.x_train.dtype)
+        flat_rows = is_image and self._ingest_path == "select"
+        self._image_shape = sample_shape if flat_rows else None
+        # Read BEFORE the dataset is globalized, like the shard arrays
+        # below: the process-local host copy (a view where x_train is
+        # host-side already).
+        host_rows = (np.asarray(self.dataset.x_train).reshape(
+            self.dataset.n_train, -1) if flat_rows else None)
         if data_sharded:
             from mercury_tpu.parallel.distributed import (
                 worker_shard_global_arrays,
             )
 
             self._step_x, self._step_y = worker_shard_global_arrays(
-                self.dataset, self.mesh, config.mesh_axis
+                self.dataset, self.mesh, config.mesh_axis,
+                flat_rows=flat_rows,
             )
         if host_stream:
             # Stashed BEFORE the dataset is globalized (the [W, L] matrix
@@ -462,12 +480,16 @@ class Trainer:
             self._step_y = make_global_array(
                 np.asarray(self.dataset.y_train, np.int32), self.mesh, P())
         elif not data_sharded:
-            self._step_x = self.dataset.x_train
+            from jax.sharding import PartitionSpec as P
+
+            self._step_x = (make_global_array(host_rows, self.mesh, P())
+                            if flat_rows else self.dataset.x_train)
             self._step_y = self.dataset.y_train
         self.train_step = make_train_step(
             self.model, self.tx, config, self.mesh, self.dataset.mean,
             self.dataset.std, state_out_shardings=self._state_out_shardings,
             scoring_model=self.scoring_model,
+            image_shape=self._image_shape,
         )
         # K-step chunked variant: one dispatch per config.scan_steps steps
         # (lax.scan over the same body; jit is lazy, so this costs nothing
@@ -488,6 +510,7 @@ class Trainer:
                 self.dataset.mean, self.dataset.std, scan_steps=self.scan_steps,
                 state_out_shardings=self._state_out_shardings,
                 scoring_model=self.scoring_model,
+                image_shape=self._image_shape,
             )
             if self.scan_steps > 1
             else None
@@ -612,6 +635,11 @@ class Trainer:
         # this thread.
         self.tracer = (SpanTracer(config.trace_capacity)
                        if config.trace else NULL_TRACER)
+        # The ingest is chosen at trace time, so how often it engages is
+        # a fact of the compiled step: recorded once, here.
+        self.tracer.instant(
+            "trainer/ingest_path", cat="trainer", path=self._ingest_path,
+            rows="flat" if flat_rows else "nhwc")
         self.anomaly: Optional[AnomalyEngine] = None
         if config.anomaly_detection and pidx == 0:
             self.anomaly = AnomalyEngine(
@@ -745,8 +773,11 @@ class Trainer:
             if shard_mode == "local":
                 self._stream_local_workers = host_worker_slice(
                     self.mesh, config.mesh_axis)
+            # The selection ingest streams flat rows too: host_rows is a
+            # view, so the gather reads the same bytes either way.
             source = HostStreamSource(
-                np.asarray(self.dataset.x_train),
+                host_rows if flat_rows
+                else np.asarray(self.dataset.x_train),
                 decode_workers=config.decode_workers,
             )
             self._stream_x_sharding = NamedSharding(

@@ -13,7 +13,9 @@ import pytest
 from mercury_tpu.data.pipeline import (
     augment_batch,
     augment_normalize,
+    crop_flip_draws,
     normalize_images,
+    select_crop_flip,
 )
 from mercury_tpu.ops import per_sample_nll_pallas, score_and_draw_pallas
 from mercury_tpu.sampling.importance import importance_probs, per_sample_loss
@@ -253,3 +255,99 @@ class TestAugmentNormalize:
         a = augment_normalize(key, raw_uint8, _MEAN, _STD)
         b = augment_normalize(key, raw_uint8, _MEAN, _STD)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _chain_with_draws(raw, off, flip, out_dtype=None):
+    """The chain the selection ingest replaced, with the draws handed in:
+    normalize, zero-pad 4, crop at ``off``, flip — ``augment_batch``'s own
+    ops on ``[N, H, W, C]``, kept here as the reference."""
+    from mercury_tpu.data.pipeline import _take_crops
+
+    n, h, w, _ = raw.shape
+    padded = jnp.pad(normalize_images(raw, _MEAN, _STD),
+                     ((0, 0), (4, 4), (4, 4), (0, 0)))
+    out = _take_crops(padded, off[:, 0], off[:, 1], h, w)
+    out = jnp.where(flip[:, None, None, None], out[:, :, ::-1, :], out)
+    return out if out_dtype is None else out.astype(out_dtype)
+
+
+def _as_rows(raw, rows):
+    """``raw`` as the step hands it to the ingest: ``[N, H, W, C]`` or the
+    flat ``[N, H*W*C]`` rows with their ``image_shape``."""
+    if rows == "flat":
+        return raw.reshape(raw.shape[0], -1), tuple(raw.shape[1:])
+    return raw, None
+
+
+class TestSelectIngest:
+    """The one-pass uint8 ingest (crop and flip as exact selection on the
+    raw bytes, normalize last) against the normalize → pad → crop → flip
+    chain it replaced: bitwise, jit against jit, at f32 and through the
+    bf16 cast."""
+
+    @pytest.mark.parametrize("rows", ["nhwc", "flat"])
+    @pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("seed", [0, 1, 7, 2**31 + 11])
+    def test_seeded_keys(self, raw_uint8, seed, out_dtype, rows):
+        key = jax.random.key(seed)
+        x, shape = _as_rows(raw_uint8, rows)
+        new = jax.jit(lambda k, r: augment_normalize(
+            k, r, _MEAN, _STD, out_dtype=out_dtype, image_shape=shape)
+        )(key, x)
+        ref = jax.jit(lambda k, r: _unfused_ingest(k, r, out_dtype)
+                      )(key, raw_uint8)
+        assert new.dtype == out_dtype and new.shape == raw_uint8.shape
+        np.testing.assert_array_equal(
+            np.asarray(new, np.float32), np.asarray(ref, np.float32))
+
+    @pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("flip", [False, True])
+    @pytest.mark.parametrize("ox", [0, 8])
+    @pytest.mark.parametrize("oy", [0, 8])
+    def test_forced_corner_draws(self, raw_uint8, oy, ox, flip, out_dtype):
+        """The four corners of the padded image, flipped and not: rows and
+        columns of exact zeros on two sides, the image's own corner pixel
+        at the opposite one."""
+        n = raw_uint8.shape[0]
+        off = jnp.tile(jnp.asarray([[oy, ox]], jnp.int32), (n, 1))
+        flips = jnp.full((n,), flip)
+        new = jax.jit(lambda r, o, f: select_crop_flip(
+            r, o, f, _MEAN, _STD, out_dtype=out_dtype))(raw_uint8, off, flips)
+        ref = jax.jit(lambda r, o, f: _chain_with_draws(r, o, f, out_dtype)
+                      )(raw_uint8, off, flips)
+        np.testing.assert_array_equal(
+            np.asarray(new, np.float32), np.asarray(ref, np.float32))
+        got = np.asarray(new, np.float32)
+        pad_rows = slice(0, 4) if oy == 0 else slice(28, 32)
+        assert (got[:, pad_rows] == 0.0).all()
+        assert not (got[:, 4:28, 4:28] == 0.0).all()
+
+    def test_each_image_its_own_draw(self, raw_uint8):
+        """Every offset pair and both flips in one batch: no draw leaks
+        from one image into another."""
+        n = raw_uint8.shape[0]
+        off = jnp.asarray([[i % 9, (3 * i + 2) % 9] for i in range(n)],
+                          jnp.int32)
+        flips = jnp.arange(n) % 2 == 0
+        new = jax.jit(lambda r: select_crop_flip(
+            r.reshape(n, -1), off, flips, _MEAN, _STD,
+            image_shape=(32, 32, 3)))(raw_uint8)
+        ref = jax.jit(lambda r: _chain_with_draws(r, off, flips))(raw_uint8)
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(ref))
+
+    def test_draws_replay_augment_batch(self, raw_uint8):
+        """``crop_flip_draws`` is the seam: the same key gives the draws
+        ``augment_batch`` makes."""
+        key = jax.random.key(5)
+        off, flips = crop_flip_draws(key, raw_uint8.shape[0])
+        ref = jax.jit(_unfused_ingest)(key, raw_uint8)
+        via = jax.jit(lambda r: _chain_with_draws(r, off, flips))(raw_uint8)
+        np.testing.assert_array_equal(np.asarray(via), np.asarray(ref))
+
+    @pytest.mark.parametrize("bad", ["float", "flat_without_shape"])
+    def test_rejects_what_it_cannot_select(self, raw_uint8, bad):
+        off, flips = crop_flip_draws(jax.random.key(0), raw_uint8.shape[0])
+        x = (raw_uint8.astype(jnp.float32) if bad == "float"
+             else raw_uint8.reshape(raw_uint8.shape[0], -1))
+        with pytest.raises(ValueError, match="select_crop_flip"):
+            select_crop_flip(x, off, flips, _MEAN, _STD)

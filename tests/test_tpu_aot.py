@@ -72,11 +72,16 @@ def mosaic(monkeypatch):
     assert mercury_kernels._interpret() is False
 
 
-def _compile(fn, devices, *shapes):
-    """Compile ``fn`` for one v5e device; returns the executable's text."""
+def _compiled(fn, devices, *shapes):
+    """Compile ``fn`` for one v5e device; returns the executable."""
     sh = NamedSharding(Mesh(np.array(devices[:1]), ("data",)), P())
     args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _compile(fn, devices, *shapes):
+    """Compile ``fn`` for one v5e device; returns the executable's text."""
+    return _compiled(fn, devices, *shapes).as_text()
 
 
 class TestKernelsCompileForV5e:
@@ -133,13 +138,14 @@ def _compile_trainer_step(devices, world, **kw):
         scan = t.scan_steps
         step = make_train_step(t.model, t.tx, config, mesh, t.dataset.mean,
                                t.dataset.std, scan_steps=scan,
-                               scoring_model=t.scoring_model)
+                               scoring_model=t.scoring_model,
+                               image_shape=t._image_shape)
         ds = t.dataset
         args = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(
                 x.shape, x.dtype,
                 sharding=NamedSharding(mesh, x.sharding.spec)),
-            (t.state, ds.x_train, ds.y_train, ds.shard_indices))
+            (t.state, t._step_x, ds.y_train, ds.shard_indices))
     return step.lower(*args).compile()
 
 
@@ -168,3 +174,35 @@ class TestFusedStepCompilesForV5e:
         compiled = _compile_trainer_step(
             v5e_devices, 1, sampler="scoretable", refresh_size=320)
         assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------- pool ingest
+def test_pool_ingest_dense_for_v5e(v5e_devices):
+    """The pool's ingest at the benchmark cell's shapes (5,000-row resident
+    set, 2,560 slots, 32x32x3 uint8): gather + crop/flip/normalize as the
+    step runs them. With the channels minor the v5e compiler relaid the
+    whole set out every step and moved 9.68 GB for a 31 MB pool (PERF.md
+    section 6, PR 26); the selection pass on flat rows moves 0.75 GB."""
+    import re
+
+    from mercury_tpu.data.pipeline import augment_normalize
+
+    n, pool, shape = 5000, 2560, (32, 32, 3)
+    mean = np.asarray([0.5071, 0.4865, 0.4409], np.float32)
+    std = np.asarray([0.2673, 0.2564, 0.2762], np.float32)
+
+    def ingest(x_rows, gidx, key_data):
+        with jax.named_scope("mercury_pool_ingest"):
+            return augment_normalize(
+                jax.random.wrap_key_data(key_data), x_rows[gidx],
+                mean, std, image_shape=shape)
+
+    compiled = _compiled(
+        ingest, v5e_devices, ((n, int(np.prod(shape))), jnp.uint8),
+        ((pool,), jnp.int32), ((2,), jnp.uint32))
+    # no `copy` op whose result is the whole training set
+    set_copies = [line for line in compiled.as_text().splitlines()
+                  if re.search(rf"= u8\[{n},[^\]]*\]\S* copy\(", line)]
+    assert not set_copies, set_copies
+    accessed = compiled.cost_analysis()["bytes accessed"]
+    assert accessed < 1.5e9, accessed
