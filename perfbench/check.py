@@ -5,22 +5,26 @@ in every run, and one over its limit makes the run ``correct: false``.
 the very trainer the window then drives, through ``fit()``, against the
 plain reference on the recorded batches: ``loss_gap``, ``grad_norm_gap``,
 ``update_norm_gap`` and, from the rebuilt pool, ``weight_gap``. This is the
-timed program at the timed batch and pool: scoring forward, normalization,
-reweighted loss, backward pass, Adam and its schedule.
+timed program at the timed batch and pool: scoring forward, input
+pipeline, reweighted loss, backward pass, Adam and its schedule.
 
 **The inference and evaluate paths.** After warm-up ``trainer.predict``
-gives the logits of 256 test images drawn from the seed, in the precision
-the configuration states; the reference computes them in float32 from a
-host copy of the same weights:
+gives the outputs of ``sample_rows`` held-out rows drawn from the seed (the
+configuration's ``check.sample_rows``, 256 where it names none: as many as
+the host can hold the outputs of), in the precision the configuration
+states; the reference (the configuration's family file) computes them in
+float32 from a host copy of the same weights:
 
     logit_gap = rms(system - reference) / rms(reference)
 
-over all 256 x classes logits (the root mean square reads the same from
-seed to seed; the widest single logit swings by its nature).
+over every number of those outputs, whatever their shape (the root mean
+square reads the same from seed to seed; the widest single output swings
+by its nature).
 
 The window's last ``fit()`` call closes with ``evaluate()``, which returns
-``test/eval_loss``; the reference computes the same mean cross-entropy over
-the whole test split from the final weights:
+``test/eval_loss``; the reference computes the same mean per-example loss
+over the whole test split from the final weights, ``block_rows`` rows at a
+time (``check.block_rows``), keeping one float a row:
 
     eval_loss_gap = |system - reference| / reference
 
@@ -41,11 +45,12 @@ loss of each of its steps to the reference instead.
 **How the limits are set** (readings beside each limit in the
 configuration's ``check`` block and in PERF.md): the largest value sound
 runs give over a dozen seeds on the chip, and the smallest the control
-gives: the reference itself with every convolution's and the head's inputs
-and weights rounded to fp8 (e4m3), the nearest precision below the bfloat16
-the configurations state, put in the program's place. The limit stands
-between the two. A number the control hardly moves is held against the
-fault it is there to catch, at about three times the sound runs' largest.
+gives: the reference itself with the inputs and weights of its matrix
+products rounded to fp8 (e4m3; the family's file says which), the nearest
+precision below the bfloat16 the configurations state, put in the program's
+place. The limit stands between the two. A number the control hardly moves
+is held against the fault it is there to catch, at about three times the
+sound runs' largest.
 
 **Not covered.** Which rows the draw picks (replay.py says why); the step
 that primes the pipeline (its batch is in no state); a cell without
@@ -59,7 +64,8 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-#: Test images whose logits the inference check compares.
+#: Held-out rows whose outputs the inference check compares, where the
+#: configuration's ``check`` block names no ``sample_rows``.
 SAMPLE = 256
 
 
@@ -81,6 +87,17 @@ class Number:
     def line(self) -> str:
         return (f"[perfbench] check {self.name}: {self.value!r} "
                 f"{self.rule} {self.limit!r} -> {'ok' if self.ok else 'FAIL'}")
+
+
+def sample_rows(limits: Dict[str, Any]) -> int:
+    return int(limits.get("sample_rows", SAMPLE))
+
+
+def block_rows(limits: Dict[str, Any], n_rows: int) -> int:
+    """Rows the evaluate side's reference hands ``forward`` at a time:
+    ``check.block_rows``, else 250 where that divides the split (one shape,
+    one compile) and 64 where it does not."""
+    return int(limits.get("block_rows", 250 if n_rows % 250 == 0 else 64))
 
 
 def sample_indices(seed: int, n_test: int, k: int = SAMPLE) -> np.ndarray:
@@ -114,7 +131,7 @@ def failed_steps(losses: Sequence[float], log_every: int) -> int:
     return log_every * sum(1 for v in losses if not math.isfinite(v))
 
 
-def numbers(limits: Dict[str, Any], *, system_logits, ref_logits,
+def numbers(limits: Dict[str, Any], *, system_outputs, ref_outputs,
             eval_loss: Optional[float], ref_eval_loss: Optional[float],
             replay: Dict[str, float], window_update_rms: float,
             window_losses: List[float], steps_counted: int,
@@ -123,7 +140,7 @@ def numbers(limits: Dict[str, Any], *, system_logits, ref_logits,
     the configuration's ``check`` block)."""
     out = [Number(name, value, float(limits[f"{name}_limit"]))
            for name, value in replay.items()]
-    out.append(Number("logit_gap", logit_gap(system_logits, ref_logits),
+    out.append(Number("logit_gap", logit_gap(system_outputs, ref_outputs),
                       float(limits["logit_gap_limit"])))
     if eval_loss is not None and ref_eval_loss is not None:
         out.append(Number("eval_loss_gap",

@@ -4,15 +4,17 @@ Set-up drives the trainer's first ``STEPS + 1`` steps through ``fit()``
 itself with a recorder around ``trainer.train_step`` — the compiled step
 the window then drives, fed by ``fit()``'s own call — and keeps, after each
 step, what the state shows of it: the drawn batch of the NEXT step (the
-``pending`` batch of pipelined scoring: images as augmented, labels, and
-``scaled_probs = N p_i``), Adam's first moment, the step's scalars, and at
-both ends the parameters. Step 1 primes the pipeline and trains on a batch
-no state ever shows, so the replay starts from the state after it.
+``pending`` batch of pipelined scoring, read by position: the inputs as
+augmented, the labels, and ``scaled_probs = N p_i``, each ``[W, B, ...]``),
+Adam's first moment, the step's scalars, and at both ends the parameters.
+Step 1 primes the pipeline and trains on a batch no state ever shows, so
+the replay starts from the state after it.
 
 Once the window has closed and the program's state is freed, the reference
-(``reference.py``, float32 at ``highest``) follows steps 2..STEPS+1 on its
-own trajectory from that state: the reweighted loss and its gradient on
-each recorded batch, Adam under the cosine schedule. Compared:
+(``reference.py`` around the configuration's family file, float32 at
+``highest``) follows steps 2..STEPS+1 on its own trajectory from that
+state: the reweighted loss and its gradient on each recorded batch, Adam
+under the cosine schedule. Compared:
 
 - ``loss_gap``: each step's ``train/loss`` against the reference's, the
   widest relative gap;
@@ -31,9 +33,9 @@ each recorded batch, Adam under the cosine schedule. Compared:
 - ``weight_gap`` (one worker): the pool of the first replayed step rebuilt
   from the state before it (stream, key, EMA) and scored by the reference
   with the program's parameters; each drawn row is found in the rebuilt
-  pool and its ``N p_i`` compared: the root mean square of the relative
-  gaps. This is the scoring forward and the normalization at the timed
-  pool size.
+  pool (as a flat vector, whatever its shape; integer rows exactly) and
+  its ``N p_i`` compared: the root mean square of the relative gaps. This
+  is the scoring forward and the input pipeline at the timed pool size.
 
 The draw itself (which rows the uniforms pick) is not replayed: a row
 whose CDF edge moves by a rounding is drawn differently, and rightly so.
@@ -141,15 +143,22 @@ def _diff(a, b):
     return jax.tree.map(lambda x, y: np.asarray(x, np.float64) - y, a, b)
 
 
+#: The drawn batch in the state, by position (``PendingBatch``'s fields).
+INPUTS, LABELS, SCALED_PROBS = 0, 1, 2
+
+
 def _match_rows(rows: np.ndarray, pool: np.ndarray) -> np.ndarray:
-    """For each of ``rows`` the index of the nearest row of ``pool``, and
-    -1 where none lies within a rounding of it."""
+    """For each of ``rows`` the index of the nearest row of ``pool``, rows
+    taken as flat vectors, and -1 where none lies within a rounding of it
+    (integer rows: where none is the same)."""
+    exact = np.issubdtype(rows.dtype, np.integer)
     a = rows.reshape(rows.shape[0], -1).astype(np.float64)
     b = pool.reshape(pool.shape[0], -1).astype(np.float64)
     d2 = ((a * a).sum(1)[:, None] + (b * b).sum(1)[None, :]
           - 2.0 * a @ b.T)
     nearest = d2.argmin(axis=1)
-    ok = d2[np.arange(len(a)), nearest] <= 1e-6 * a.shape[1]
+    ok = d2[np.arange(len(a)), nearest] <= (0.0 if exact
+                                            else 1e-6 * a.shape[1])
     return np.where(ok, nearest, -1)
 
 
@@ -176,7 +185,8 @@ def reference_steps(steps, arch, fields, quantize=None) -> Dict[str, Any]:
     start, adam = steps[0], arch["adam"]
     world = int(fields["world_size"])
     if world > 1 and fields.get("batch_norm", "sync") != "sync":
-        raise NotImplementedError("replay across workers needs synced BN")
+        raise NotImplementedError("replay across workers needs synced "
+                                  "batch statistics")
     peak_lr = float(fields["base_lr"]) * world
     decay = int(fields["steps_per_epoch"]) * int(fields["num_epochs"])
     loss_and_grad = reference.make_loss_and_grad(arch, quantize)
@@ -187,8 +197,8 @@ def reference_steps(steps, arch, fields, quantize=None) -> Dict[str, Any]:
     for i in range(STEPS):
         batch = steps[i]["pending"]
         loss, grads = loss_and_grad(
-            params, flat(batch.images), flat(batch.labels),
-            flat(batch.scaled_probs))
+            params, flat(batch[INPUTS]), flat(batch[LABELS]),
+            flat(batch[SCALED_PROBS]))
         grads = jax.tree.map(np.asarray, grads)
         out["losses"].append(float(loss))
         if i == 0:
@@ -220,17 +230,17 @@ def reference_weights(steps, dataset, arch, fields, quantize=None):
     start, drawn = steps[0], steps[1]["pending"]
     x_train, y_train, shard_indices = dataset
     pool_size = int(fields["batch_size"]) * int(fields["presample_batches"])
-    images, labels, _, scaled = reference.score_pool(
+    inputs, labels, _, scaled = reference.score_pool(
         start["params"],
         jax.random.wrap_key_data(jax.numpy.asarray(start["rng"][0])),
         start["stream"].perm[0], int(start["stream"].cursor[0]),
         float(start["ema"].value[0]), int(start["ema"].count[0]),
         x_train, y_train, shard_indices[0], arch, pool_size, quantize)
-    at = _match_rows(drawn.images[0], images)
+    at = _match_rows(drawn[INPUTS][0], inputs)
     found = at >= 0
     print(f"[perfbench] replay pool: {int(found.sum())} of {len(at)} drawn "
           f"rows found in the rebuilt pool of {pool_size}", flush=True)
-    if not found.all() or (labels[at] != drawn.labels[0]).any():
+    if not found.all() or (labels[at] != drawn[LABELS][0]).any():
         return None
     return scaled[at]
 
@@ -246,9 +256,10 @@ def weight_gap(system, ref) -> float:
 def compare(steps: List[Dict[str, Any]], dataset, arch: Dict[str, Any],
             fields: Dict[str, Any], control: Optional[str] = None):
     """The replay's numbers from a ``Recorder``'s steps: the program
-    against the reference. ``dataset`` is ``(x_train_u8, y_train,
-    shard_indices)`` on the host; ``fields`` the job's ``TrainConfig``
-    fields (learning rate, schedule length, batch and pool). With
+    against the reference. ``dataset`` is ``(x_train, y_train,
+    shard_indices)`` on the host, rows as ``trainer.dataset`` holds them;
+    ``fields`` the job's ``TrainConfig`` fields (learning rate, schedule
+    length, batch and pool). With
     ``control`` (a lower precision) returns a second dict as well: the
     reference in that precision, put in the program's place."""
     if len(steps) != STEPS + 1:
@@ -267,8 +278,8 @@ def compare(steps: List[Dict[str, Any]], dataset, arch: Dict[str, Any],
              if control else None)
     if int(fields["world_size"]) == 1:
         weights = reference_weights(steps, dataset, arch, fields)
-        out["weight_gap"] = weight_gap(steps[1]["pending"].scaled_probs[0],
-                                       weights)
+        out["weight_gap"] = weight_gap(
+            steps[1]["pending"][SCALED_PROBS][0], weights)
         if control:
             lower["weight_gap"] = weight_gap(reference_weights(
                 steps, dataset, arch, fields, control), weights)

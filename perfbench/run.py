@@ -165,14 +165,18 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             f"{setup_monitor.cache_misses}; loss at the first log gates "
             f"{[round(v, 5) for v in losses[:5]]}")
 
-        # The inference check's system side: seeded test images,
+        # The inference check's system side: seeded held-out rows,
         # at the warm weights (the same for a seed whatever --seconds is).
+        # The program's ``batch_stats`` is what a family's forward takes as
+        # ``model_state``.
         ds = trainer.dataset
         x_test, y_test = np.asarray(ds.x_test), np.asarray(ds.y_test)
         train_split = (np.asarray(ds.x_train), np.asarray(ds.y_train),
                        np.asarray(ds.shard_indices))
-        idx = check.sample_indices(seed, x_test.shape[0])
-        system_logits = trainer.predict(x_test[idx])
+        limits = cell.config["check"]
+        idx = check.sample_indices(seed, x_test.shape[0],
+                                   check.sample_rows(limits))
+        system_outputs = trainer.predict(x_test[idx])
         warm_weights = _host_copy((trainer.state.params,
                                    trainer.state.batch_stats))
 
@@ -225,28 +229,26 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     # ------------------------------------------------- the reference check
     t_ref = time.perf_counter()
-    block = 250 if x_test.shape[0] % 250 == 0 else 64
+    arch = cell.config["reference"]
+    block = check.block_rows(limits, x_test.shape[0])
 
     def ref_side(quantize=None):
-        """(logits of the sample at the warm weights, loss over the whole
+        """(outputs of the sample at the warm weights, loss over the whole
         test split at the final weights) by the plain reference."""
-        arch = cell.config["reference"]
-        sample = reference.logits(*warm_weights, x_test[idx], arch, quantize)
-        whole = reference.logits(*final_weights, x_test, arch, quantize,
-                                 block_rows=block)
-        return sample, reference.nll(whole, y_test)
+        sample = reference.outputs(*warm_weights, x_test[idx], arch, quantize)
+        return sample, reference.eval_loss(*final_weights, x_test, y_test,
+                                           arch, quantize, block_rows=block)
 
-    ref_logits, ref_eval_loss = ref_side()
+    ref_outputs, ref_eval_loss = ref_side()
     say(f"reference: inference and evaluate sides took "
         f"{time.perf_counter() - t_ref:.2f} s")
-    arch = cell.config["reference"]
     replayed = replay.compare(recorder.steps, train_split, arch, fields,
                               "fp8" if control else None)
     if control:
         replayed, replayed_lower = replayed
     numbers = check.numbers(
-        cell.config["check"], system_logits=system_logits,
-        ref_logits=ref_logits, eval_loss=evals[-1].get("test/eval_loss"),
+        limits, system_outputs=system_outputs,
+        ref_outputs=ref_outputs, eval_loss=evals[-1].get("test/eval_loss"),
         ref_eval_loss=ref_eval_loss, replay=replayed,
         window_update_rms=check.update_rms(warm_weights[0],
                                            final_weights[0], steps),
@@ -259,10 +261,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         f"reference {ref_eval_loss}")
     extra: Dict[str, Any] = {}
     if control:
-        quant_logits, quant_eval_loss = ref_side("fp8")
+        quant_outputs, quant_eval_loss = ref_side("fp8")
         extra["numbers"] = {n.name: n.value for n in numbers}
         extra["control"] = dict(
-            replayed_lower, logit_gap=check.logit_gap(quant_logits, ref_logits),
+            replayed_lower,
+            logit_gap=check.logit_gap(quant_outputs, ref_outputs),
             eval_loss_gap=check.eval_loss_gap(quant_eval_loss,
                                               ref_eval_loss))
         say(f"control (fp8 reference): {extra['control']}")
@@ -295,6 +298,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         say(f"capture {source}: {len(events)} events, {len(capture.planes)} "
             f"device plane(s), {capture.step_count()} runs of "
             f"{programs['step']}")
+        note = lost_steps_note(capture, steps)
+        if note:
+            say(note)
         for m in cell.per_layer():
             spec = layer_metric(m["name"])
             value = reducer(spec["reducer"])(ctx, **spec.get("args", {}))
@@ -326,6 +332,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     }
     print(json.dumps(result), flush=True)
     return result
+
+
+def lost_steps_note(capture, steps: int) -> Optional[str]:
+    """What to say where the capture holds fewer executions of the step
+    program than the traced calls took steps (a just-compiled step's first
+    traced run: PERF.md, PR 26)."""
+    held = capture.steps_held(steps)
+    if held == steps:
+        return None
+    return (f"the capture lost {steps - held} of {steps} step programs: the "
+            f"per-step device readings divide by the {held} it holds")
 
 
 def load_capture(root: str):

@@ -16,11 +16,13 @@ import numpy as np
 import pytest
 
 from perfbench import cell as cell_mod
-from perfbench import check, flops, reference, replay, run, trace_reduce
+from perfbench import check, reference, replay, run, trace_reduce
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 FIXTURE = os.path.join(REPO, "tests", "fixtures", "profile_trace.json")
+RESNET_FILE = "perfbench/references/resnet.py"
+RESNET = reference.family({"file": RESNET_FILE})
 MANIFEST = cell_mod.manifest()
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -34,7 +36,8 @@ TINY = {
                      "batch_size": 8, "presample_batches": 2,
                      "compute_dtype": "float32", "log_every": 10},
     "steps_per_call": 10, "trace_calls": 2,
-    "reference": {"family": "smallcnn",
+    "reference": {"file": "perfbench/references/smallcnn.py",
+                  "family": "smallcnn",
                   "mean": [0.49139968, 0.48215827, 0.44653124],
                   "std": [0.24703233, 0.24348505, 0.26158768],
                   "sampling": {"is_alpha": 0.5, "ema_alpha": 0.9, "pad": 4},
@@ -45,12 +48,38 @@ TINY = {
               "update_norm_gap_limit": 1e-3, "weight_gap_limit": 1e-4,
               "window_update_rms_floor": 1e-5},
 }
+#: The same command over a second family, from what the program runs
+#: today: the pre-LN Transformer classifier on the program's seeded feature
+#: sequences ``[N, 32, 16]`` (float32 rows, no augmentation, no BatchNorm
+#: state). ``update_norm_gap``'s worst leaf is the first block's key bias:
+#: its gradient is zero in exact arithmetic (softmax takes no notice of a
+#: constant added to every score of a row), so Adam normalises rounding
+#: noise on both sides: 0.003-0.005 here, where a stopped optimizer reads 1.
+TRANSFORMER = {
+    "train_config": {"model": "transformer", "dataset": "synthetic_seq_hard",
+                     "augmentation": "none", "batch_size": 8,
+                     "presample_batches": 4, "compute_dtype": "float32",
+                     "log_every": 10},
+    "steps_per_call": 10, "trace_calls": 2,
+    "reference": {"file": "perfbench/references/transformer_classifier.py",
+                  "num_heads": 4,
+                  "sampling": {"is_alpha": 0.5, "ema_alpha": 0.9},
+                  "adam": {"b1": 0.9, "b2": 0.999, "eps": 1e-8}},
+    "check": {"sample_rows": 32, "block_rows": 100,
+              "logit_gap_limit": 1e-4, "eval_loss_gap_limit": 1e-3,
+              "loss_gap_limit": 1e-4, "grad_norm_gap_limit": 1e-3,
+              "update_norm_gap_limit": 0.05, "weight_gap_limit": 1e-4,
+              "window_update_rms_floor": 1e-5},
+}
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+ALL_CHECKS = {"loss_gap", "grad_norm_gap", "update_norm_gap", "logit_gap",
+              "eval_loss_gap", "window_update_rms", "nonfinite_losses",
+              "steps_advanced", "compiles_in_window"}
 
 
-def _tiny(**train_config):
-    out = dict(TINY)
-    out["train_config"] = dict(TINY["train_config"], **train_config)
+def _tiny(base=TINY, **train_config):
+    out = dict(base)
+    out["train_config"] = dict(base["train_config"], **train_config)
     return out
 
 
@@ -71,11 +100,7 @@ def test_whole_command_tiny(capsys, world):
     line = json.loads(out.strip().splitlines()[-1])
     assert set(line) == RESULT_KEYS and line == result
     compared = set(re.findall(r"check (\w+): .* -> ok", out))
-    assert compared == {
-        "loss_gap", "grad_norm_gap", "update_norm_gap", "logit_gap",
-        "eval_loss_gap", "window_update_rms", "nonfinite_losses",
-        "steps_advanced", "compiles_in_window"} | (
-            {"weight_gap"} if world == 1 else set())
+    assert compared == ALL_CHECKS | ({"weight_gap"} if world == 1 else set())
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["attempted"] % 10 == 0
     want = {m["name"] for m in MANIFEST["end_to_end"]}
@@ -102,6 +127,42 @@ def test_traced_run_tiny(capsys):
     assert line["metrics"]["compiles_in_window"]["value"] == 0
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     assert {"busy_s", "window_s"} <= set(line["device"])
+
+
+@pytest.mark.parametrize("world, trace", [(1, False), (4, False), (1, True)],
+                         ids=["one_worker", "four_workers", "traced"])
+def test_whole_command_transformer(capsys, world, trace):
+    """The same command body over the second family: float32 sequence
+    rows, no augmentation, an empty ``batch_stats``, a sample of 32 rows,
+    the evaluate side in blocks of 100: ``correct: true`` as stated."""
+    run.run_cell(CELLS[0], 2 ** 31 + 11, 0.3, trace,
+                 rehearsal=_tiny(TRANSFORMER, world_size=world))
+    out = capsys.readouterr().out
+    line = json.loads(out.strip().splitlines()[-1])
+    assert set(line) == RESULT_KEYS | ({"breakdown"} if trace else set())
+    compared = set(re.findall(r"check (\w+): .* -> ok", out))
+    assert compared == ALL_CHECKS | ({"weight_gap"} if world == 1 else set())
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0 and line["attempted"] % 10 == 0
+    if world == 1:
+        assert "8 of 8 drawn rows found in the rebuilt pool of 32" in out
+
+
+def test_the_fp8_control_fails_the_transformers_check(capsys):
+    """The whole command with the control's readings beside the program's
+    (``readings.py``'s call): the plain reference in fp8, put in the
+    program's place, is over the limits the program passes, on the
+    inference check's number and on the pool's weights."""
+    result = run.run_cell(CELLS[0], 13, 0.3, False,
+                          rehearsal=_tiny(TRANSFORMER), control=True)
+    capsys.readouterr()
+    assert result["correct"] is True
+    limits = TRANSFORMER["check"]
+    for name in ("logit_gap", "weight_gap"):
+        limit = limits[f"{name}_limit"]
+        assert check.Number(name, result["numbers"][name], limit).ok
+        assert not check.Number(name, result["control"][name], limit).ok
+        assert result["control"][name] > 100 * result["numbers"][name]
 
 
 def _frozen(trainer):
@@ -166,21 +227,21 @@ def _evaluates(trainer, loss_of):
     trainer.evaluate = evaluate
 
 
-def _evaluate_drops_rows(trainer):
+def _evaluate_drops_rows(trainer, arch=TINY["reference"]):
     """An evaluate that leaves out the test split's second half."""
-    _evaluates(trainer, lambda x, y: reference.nll(
-        trainer.predict(x[:len(x) // 2]), y[:len(x) // 2]))
+    fam = reference.family(arch)
+    _evaluates(trainer, lambda x, y: float(fam.eval_example_loss(
+        trainer.predict(x[:len(x) // 2]), y[:len(x) // 2]).mean()))
 
 
-def _evaluate_in_training_mode(trainer):
+def _evaluate_in_training_mode(trainer, arch=TINY["reference"]):
     """An evaluate that normalizes by the batch's own statistics."""
+    fam = reference.family(arch)
+
     def loss_of(x, y):
-        arch = TINY["reference"]
-        z = reference.forward(
-            jax.device_get(trainer.state.params), None,
-            reference.normalize(jnp.asarray(x), arch["mean"], arch["std"]),
-            arch)
-        return reference.nll(z, y)
+        z = fam.forward(jax.device_get(trainer.state.params), None,
+                        fam.prepare(jnp.asarray(x), arch), arch)
+        return float(fam.eval_example_loss(z, y).mean())
 
     _evaluates(trainer, loss_of)
 
@@ -200,18 +261,75 @@ def test_a_broken_path_is_not_correct(capsys, monkeypatch, fault, failing):
     """The timed path broken underneath drives the rest of a run, past the
     harness's look for a chip, and comes out ``correct: false`` on the
     numbers that are there to catch that fault."""
+    failed = _failed_checks_of_a_broken_run(capsys, monkeypatch, fault,
+                                            3, _tiny())
+    assert failing <= failed, (failing, failed)
+
+
+def _failed_checks_of_a_broken_run(capsys, monkeypatch, fault, seed,
+                                   rehearsal):
+    """The names of the numbers over their limit in a run of the whole
+    command whose trainer ``fault`` has broken (None: broken elsewhere);
+    ``correct`` has to be false."""
     build = run.build_trainer
 
     def build_broken(fields):
         trainer = build(fields)
-        fault(trainer)
+        if fault:
+            fault(trainer)
         return trainer
 
     monkeypatch.setattr(run, "build_trainer", build_broken)
-    run.run_cell(CELLS[0], 3, 0.3, False, rehearsal=_tiny())
+    run.run_cell(CELLS[0], seed, 0.3, False, rehearsal=rehearsal)
     out = capsys.readouterr().out
     assert json.loads(out.strip().splitlines()[-1])["correct"] is False
-    failed = set(re.findall(r"check (\w+): .* -> FAIL", out))
+    return set(re.findall(r"check (\w+): .* -> FAIL", out))
+
+
+def _a_block_left_out(monkeypatch):
+    """A model whose last block runs and whose output is dropped: its
+    parameters are in the tree, as the reference reads it, and the forward
+    pass goes round them: in the scoring pass, the train step, ``predict``
+    and ``evaluate`` alike."""
+    from mercury_tpu.models import TransformerClassifier
+    from mercury_tpu.train import trainer as trainer_mod
+
+    class Skips(TransformerClassifier):
+        def __call__(self, x, train: bool = True):
+            x = self.embed(x)
+            for block in self.blocks[:-1]:
+                x = block(x)
+            self.blocks[-1](x)
+            return self.head(x)
+
+    create = trainer_mod.create_model
+
+    def create_skipping(name, **kwargs):
+        model = create(name, **kwargs)
+        fields = {f: getattr(model, f) for f in model.__dataclass_fields__
+                  if f not in ("parent", "name")}
+        return Skips(**fields)
+
+    monkeypatch.setattr(trainer_mod, "create_model", create_skipping)
+
+
+@pytest.mark.parametrize("fault, failing", [
+    (_a_block_left_out, {"loss_gap", "weight_gap", "logit_gap",
+                         "eval_loss_gap"}),
+    (lambda t: _evaluate_drops_rows(t, TRANSFORMER["reference"]),
+     {"eval_loss_gap"}),
+    (_keeps_params, {"update_norm_gap", "window_update_rms"}),
+], ids=["a_block_left_out", "evaluate_drops_rows", "stopped_optimizer"])
+def test_a_broken_transformer_is_not_correct(capsys, monkeypatch, fault,
+                                             failing):
+    """The second family's timed path broken underneath: ``correct:
+    false``, each by the numbers meant to catch it. (This family has one
+    mode, so an evaluate in training mode is no fault here; an evaluate
+    over part of the rows stands in its place.)"""
+    if fault is _a_block_left_out:       # breaks the model, not the trainer
+        fault, _ = None, fault(monkeypatch)
+    failed = _failed_checks_of_a_broken_run(capsys, monkeypatch, fault, 5,
+                                            _tiny(TRANSFORMER))
     assert failing <= failed, (failing, failed)
 
 
@@ -360,8 +478,8 @@ def test_reference_follows_the_models_equations(block):
     x = jax.random.normal(jax.random.key(9), (4, 32, 32, 3))
     with jax.default_matmul_precision("highest"):
         want = model.apply(v, x, train=False)
-    got = reference.resnet_forward(v["params"], v["batch_stats"], x,
-                                   [1, 2, 1, 1], block)
+    got = RESNET.resnet_forward(v["params"], v["batch_stats"], x,
+                                [1, 2, 1, 1], block)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
 
@@ -373,20 +491,20 @@ def test_check_a_passes_as_stated_and_fails_a_precision_lower(block):
     control's — the same forward with inputs and weights of every
     convolution rounded to fp8 — and a limit between the two passes the
     one and fails the other."""
-    arch = {"family": "resnet", "stage_sizes": [1, 2, 1, 1], "block": block,
+    arch = {"file": RESNET_FILE, "stage_sizes": [1, 2, 1, 1], "block": block,
             "mean": [0.5, 0.5, 0.5], "std": [0.25, 0.25, 0.25]}
     v = _seeded_variables(_tiny_resnet(block, jnp.float32), seed=3)
     images = np.asarray(jax.random.randint(
         jax.random.key(4), (64, 32, 32, 3), 0, 256), np.uint8)
-    ref = reference.logits(v["params"], v["batch_stats"], images, arch)
-    x = reference.normalize(jnp.asarray(images), arch["mean"], arch["std"])
+    ref = reference.outputs(v["params"], v["batch_stats"], images, arch)
+    x = RESNET.prepare(jnp.asarray(images), arch)
     gaps = {}
     for dtype in (jnp.float32, jnp.bfloat16):
         system = _tiny_resnet(block, dtype).apply(v, x, train=False)
         gaps[dtype] = check.logit_gap(system, ref)
     control = check.logit_gap(
-        reference.logits(v["params"], v["batch_stats"], images, arch,
-                         quantize="fp8"), ref)
+        reference.outputs(v["params"], v["batch_stats"], images, arch,
+                          quantize="fp8"), ref)
     assert gaps[jnp.float32] < 1e-4
     assert 3 * gaps[jnp.bfloat16] < control, (gaps, control)
     limit = (gaps[jnp.bfloat16] * control) ** 0.5
@@ -397,7 +515,7 @@ def test_check_a_passes_as_stated_and_fails_a_precision_lower(block):
 def test_numbers_fail_one_by_one():
     replayed = {"loss_gap": 0.001, "grad_norm_gap": 0.001,
                 "update_norm_gap": 0.001, "weight_gap": 0.001}
-    good = dict(system_logits=np.ones((4, 3)), ref_logits=np.ones((4, 3)),
+    good = dict(system_outputs=np.ones((4, 3)), ref_outputs=np.ones((4, 3)),
                 eval_loss=0.02, ref_eval_loss=0.02, replay=replayed,
                 window_update_rms=1e-3, window_losses=[0.5, 0.4],
                 steps_counted=200, steps_advanced=200, compiles=0)
@@ -407,7 +525,7 @@ def test_numbers_fail_one_by_one():
               "window_update_rms_floor": 1e-4}
     assert all(n.ok for n in check.numbers(limits, **good))
     for change, failing in [
-            (dict(system_logits=np.full((4, 3), 1.1)), "logit_gap"),
+            (dict(system_outputs=np.full((4, 3), 1.1)), "logit_gap"),
             # relative, however small the loss: 0.0205 against 0.02
             (dict(eval_loss=0.0205), "eval_loss_gap"),
             (dict(window_losses=[0.5, float("nan")]), "nonfinite_losses"),
@@ -454,7 +572,7 @@ def _program_steps(model, variables, arch, fields, seed):
         z, _ = model.apply({"params": p,
                             "batch_stats": variables["batch_stats"]},
                            b.images[0], train=True, mutable=["batch_stats"])
-        return jnp.mean(reference.per_example_nll(
+        return jnp.mean(RESNET.example_loss(
             z.astype(jnp.float32), b.labels[0]) / b.scaled_probs[0])
 
     steps, pending = [], batch(keys[0])
@@ -481,7 +599,7 @@ def test_replay_passes_as_stated_and_fails_a_precision_lower(block):
     forward lies well under the control's (the reference with every
     convolution's inputs and weights rounded to fp8), and a limit between
     the two passes the one and fails the other."""
-    arch = {"family": "resnet", "stage_sizes": [1, 2, 1, 1], "block": block,
+    arch = {"file": RESNET_FILE, "stage_sizes": [1, 2, 1, 1], "block": block,
             "adam": {"b1": 0.9, "b2": 0.999, "eps": 1e-8}}
     fields = {"world_size": 1, "base_lr": 0.001, "steps_per_epoch": 1,
               "num_epochs": 1000, "batch_size": 16}
@@ -504,14 +622,14 @@ def test_replay_passes_as_stated_and_fails_a_precision_lower(block):
 
     def weights(logits):
         return reference.scaled_probs(
-            reference.per_example_nll(logits, y), 0.0, 0, sampling)
+            RESNET.example_loss(logits, y), 0.0, 0, sampling)
 
     program, _ = _tiny_resnet(block, jnp.bfloat16).apply(
         v, x, train=True, mutable=["batch_stats"])
-    ref = weights(reference.forward(v["params"], None, x, arch))
+    ref = weights(RESNET.forward(v["params"], None, x, arch))
     sound = replay.weight_gap(weights(program), ref)
     control = replay.weight_gap(
-        weights(reference.forward(v["params"], None, x, arch, "fp8")), ref)
+        weights(RESNET.forward(v["params"], None, x, arch, "fp8")), ref)
     assert 2 * sound < control, (sound, control)
     limit = (sound * control) ** 0.5
     assert check.Number("weight_gap", sound, limit).ok
@@ -532,20 +650,33 @@ def test_a_metric_reports_only_in_the_cells_it_lists(monkeypatch):
 
 
 # ------------------------------------------------------------------ FLOPs
-@pytest.mark.parametrize(
-    "config", sorted(glob.glob(os.path.join(REPO, "perfbench", "configs",
-                                            "*.json"))),
-    ids=lambda p: os.path.basename(p)[:-5])
+CONFIG_FILES = sorted(glob.glob(os.path.join(REPO, "perfbench", "configs",
+                                             "*.json")))
+
+
+@pytest.mark.parametrize("config", CONFIG_FILES,
+                         ids=lambda p: os.path.basename(p)[:-5])
 def test_fwd_flops_per_example(config):
-    """The config file's constant is the conventional count (2 x MACs of
+    """The config file's constant is what its own family's file counts for
+    it (``fwd_flops_per_example(config)``: the operations the model
+    requires for one example's forward pass)."""
+    cfg = json.load(open(config))
+    want = reference.family(cfg["reference"]).fwd_flops_per_example(cfg)
+    assert want > 0 and cfg["fwd_flops_per_example"] == want
+
+
+@pytest.mark.parametrize(
+    "config", [p for p in CONFIG_FILES if json.load(open(p))["reference"]
+               ["file"] == RESNET_FILE],
+    ids=lambda p: os.path.basename(p)[:-5])
+def test_resnet_flops_are_xlas_without_the_padding_taps(config):
+    """The ResNet family's count is the conventional one (2 x MACs of
     every conv and the head, padding taps included: 1.11 GFLOP for
     ResNet-18, 2.60 for ResNet-50 at 32x32); without the padding taps it
     is XLA's own count of the plain reference's forward within 2 %."""
     cfg = json.load(open(config))
     arch = cfg["reference"]
-    want = flops.resnet_forward_flops(arch, cfg["image_size"],
-                                      cfg["num_classes"])
-    assert cfg["fwd_flops_per_example"] == want
+    want = RESNET.fwd_flops_per_example(cfg)
     from mercury_tpu.models import create_model
 
     model = create_model(cfg["train_config"]["model"],
@@ -554,11 +685,11 @@ def test_fwd_flops_per_example(config):
     shape = (1, cfg["image_size"], cfg["image_size"], 3)
     v = jax.eval_shape(lambda: model.init(jax.random.key(0),
                                           jnp.zeros(shape), train=False))
-    cost = jax.jit(lambda p, s, x: reference.forward(p, s, x, arch)).lower(
+    cost = jax.jit(lambda p, s, x: RESNET.forward(p, s, x, arch)).lower(
         v["params"], v["batch_stats"],
         jax.ShapeDtypeStruct(shape, jnp.float32)).cost_analysis()
-    exact = flops.resnet_forward_flops(arch, cfg["image_size"],
-                                       cfg["num_classes"], skip_padding=True)
+    exact = RESNET.resnet_forward_flops(arch, cfg["image_size"],
+                                        cfg["num_classes"], skip_padding=True)
     assert abs(cost["flops"] / exact - 1.0) < 0.02
     assert 0.8 * want < exact < want
 
@@ -592,6 +723,43 @@ def test_capture_cuts_to_the_step_module():
     assert len(cap.gap_summary(10)) <= 10
     # a capture that names no such module: everything counts as the step
     assert trace_reduce.Capture(events, "jit_absent").step_count() == 0
+
+
+def test_a_capture_that_lost_step_programs():
+    """The first traced run on a just-compiled step loses step programs
+    from its capture (PERF.md, PR 26: 93 and 115 of 120). Cut the middle
+    one of the fixture's three out, with its ops: the per-step readings
+    divide by the two it holds and stand where the whole capture's do, and
+    the run says how many were lost."""
+    events, _ = trace_reduce.load_events(FIXTURE)
+    whole = trace_reduce.Capture(events, "jit_fused_train_step")
+    lo, hi = whole.planes[0]["steps"][1]
+    cut = trace_reduce.Capture(
+        [e for e in events if not (e.get("ph") == "X" and e.get("pid") == 1
+                                   and lo <= float(e["ts"]) < hi)],
+        "jit_fused_train_step")
+    assert whole.step_count() == 3 and cut.step_count() == 2
+    assert whole.steps_held(3) == 3 and cut.steps_held(3) == 2
+    assert whole.lost_step_us(3) == 0.0
+    assert cut.lost_step_us(3) == pytest.approx(hi - lo)
+    # a capture that names no such module counts every op as the step's
+    assert trace_reduce.Capture(events, "jit_absent").steps_held(3) == 3
+    readings = {}
+    for name, capture in (("whole", whole), ("cut", cut)):
+        ctx = dict(capture=capture, steps=3, step_flops=1e9, peak_flops=1e12)
+        readings[name] = {r: cell_mod.reducer(r)(ctx) for r in (
+            "device_ms_per_step", "step_roofline_share", "device_idle_share")}
+    for r in ("device_ms_per_step", "step_roofline_share"):
+        assert readings["cut"][r] == pytest.approx(readings["whole"][r],
+                                                   rel=0.01), r
+    # by the steps asked for it would read a third low
+    assert cut.step_device_us() / 3e3 < 0.7 * readings["whole"][
+        "device_ms_per_step"]
+    assert readings["cut"]["device_idle_share"] == pytest.approx(
+        readings["whole"]["device_idle_share"], abs=3.0)
+    assert 100.0 * cut.step_idle()["idle_frac"] > 45.0  # uncorrected
+    assert run.lost_steps_note(whole, 3) is None
+    assert "lost 1 of 3 step programs" in run.lost_steps_note(cut, 3)
 
 
 @pytest.mark.parametrize("name, args, want", [
