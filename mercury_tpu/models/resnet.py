@@ -12,7 +12,11 @@ TPU-first details the reference never faced:
   params — MXU-friendly;
 - BatchNorm can be cross-replica: pass ``bn_axis_name`` to psum batch stats
   over the data mesh axis (the reference silently lets per-worker BN stats
-  drift — SURVEY.md §7 "hard parts"); ``None`` reproduces local/drifting BN.
+  drift — SURVEY.md §7 "hard parts"); ``None`` reproduces local/drifting BN;
+- a ``Bottleneck``'s closing 1×1 convolution + BatchNorm + shortcut add +
+  ReLU is one unit (:func:`_closing_unit`) whose batch statistic comes from
+  the convolution's INPUT moments when nothing differentiates the pass, so
+  the block's output is written once, by the convolution (PERF.md §6, PR 30).
 """
 
 from __future__ import annotations
@@ -21,7 +25,9 @@ from functools import partial
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 ModuleDef = Any
 
@@ -51,6 +57,88 @@ class BasicBlock(nn.Module):
         return nn.relu(residual + y)
 
 
+# The collection a ``Bottleneck`` sows a 1 into for each closing unit it
+# runs; mutable only where a caller counts them (train/step.py).
+MOMENT_UNITS = "bn_moment_units"
+
+
+def _stat_from_output(y, axis_name):
+    """Batch mean and variance of ``y`` over all but its last axis, as
+    ``nn.BatchNorm`` takes them (flax's ``_compute_stats``, fast variance):
+    f32 first and second moment, one ``pmean`` for both, clamped at 0."""
+    y = y.astype(jnp.float32)
+    axes = tuple(range(y.ndim - 1))
+    squares = lax.square(y)  # before the mean, as flax traces them
+    mu, mu2 = y.mean(axes), squares.mean(axes)
+    if axis_name is not None:
+        mu, mu2 = lax.pmean(jnp.stack((mu, mu2)), axis_name)
+    return mu, jnp.maximum(0.0, mu2 - lax.square(mu))
+
+
+def _stat_from_input(h, kernel, axis_name):
+    """The same statistic of ``y = conv1x1(h, kernel)`` without ``y``: over
+    the ``M`` rows of ``h``, ``mean(y) = mean(h) @ W`` and ``E[y²] =
+    diag(Wᵀ G W)`` with ``G = hᵀh / M`` — a ``[K, K]`` matrix over the
+    convolution's input, a quarter of its output's width. ``h`` and
+    ``kernel`` are what the convolution reads (both in its dtype); ``G``
+    accumulates in f32 from that ``h``, and the small products run at
+    full f32 precision, so only where rounding to bf16 falls differs from
+    :func:`_stat_from_output`. Both moments are linear in the rows, so
+    their ``pmean`` is the synced statistic, exactly."""
+    k = h.shape[-1]
+    rows = h.reshape(-1, k)
+    w = kernel.reshape(k, -1).astype(jnp.float32)
+    s = rows.astype(jnp.float32).mean(0)
+    gram = lax.dot_general(rows, rows, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32) / rows.shape[0]
+    if axis_name is not None:
+        synced = lax.pmean(jnp.concatenate((s[None], gram)), axis_name)
+        s, gram = synced[0], synced[1:]
+    mu = jnp.dot(s, w, precision=lax.Precision.HIGHEST)
+    mu2 = jnp.einsum("kc,kl,lc->c", w, gram, w,
+                     precision=lax.Precision.HIGHEST)
+    return mu, jnp.maximum(0.0, mu2 - lax.square(mu))
+
+
+def _closing_unit(dtype, epsilon, axis_name):
+    """``(h, shortcut, kernel, scale, bias) → (out, mean, var)``: a
+    ``Bottleneck``'s closing 1×1 convolution, train-mode BatchNorm, shortcut
+    add and ReLU, with the batch statistic it normalised by.
+
+    One algorithm, two exact ways to the statistic, chosen by what the pass
+    needs. Nothing differentiates it (the scoring forward): the statistic
+    comes from the input's moments, scale and shift are known before the
+    convolution runs, and XLA writes the block's output once, from the
+    convolution's own epilogue. Under ``jax.grad`` BatchNorm's backward
+    needs the convolution's raw output anyway, so the ``custom_vjp`` rule is
+    the vjp of the plain form — ``nn.Conv`` then ``nn.BatchNorm``'s
+    arithmetic, statistic from the output — and the differentiated pass is
+    what it was. ``mean``/``var`` leave for the running averages, which
+    nothing differentiates."""
+
+    def form(stat):
+        def apply(h, shortcut, kernel, scale, bias):
+            hc, kc = h.astype(dtype), kernel.astype(dtype)
+            y = lax.conv_general_dilated(
+                hc, kc, (1, 1), "SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            mean, var = stat(hc, kc, y)
+            # flax's ``_normalize``, op for op and shape for shape
+            axes, feature = tuple(range(y.ndim - 1)), (1,) * (y.ndim - 1) + (-1,)
+            mul = lax.rsqrt(jnp.expand_dims(var, axes) + epsilon)
+            z = (y - jnp.expand_dims(mean, axes)) * (mul * scale.reshape(feature))
+            out = nn.relu(shortcut + (z + bias.reshape(feature)).astype(dtype))
+            return out, lax.stop_gradient(mean), lax.stop_gradient(var)
+        return apply
+
+    plain = form(lambda h, kernel, y: _stat_from_output(y, axis_name))
+    unit = jax.custom_vjp(
+        form(lambda h, kernel, y: _stat_from_input(h, kernel, axis_name)))
+    unit.defvjp(lambda *args: jax.vjp(plain, *args),
+                lambda vjp, cotangents: vjp(cotangents))
+    return unit
+
+
 class Bottleneck(nn.Module):
     """1×1-3×3-1×1 bottleneck, expansion 4 (``pytorch_model.py:39-64``)."""
 
@@ -69,14 +157,30 @@ class Bottleneck(nn.Module):
         y = self.conv(self.filters, (3, 3), strides=(self.strides, self.strides))(y)
         y = self.norm()(y)
         y = nn.relu(y)
-        y = self.conv(self.filters * self.expansion, (1, 1))(y)
-        y = self.norm()(y)
-        if residual.shape != y.shape:
+        conv3 = self.conv(self.filters * self.expansion, (1, 1))
+        norm3 = self.norm()
+        if residual.shape != y.shape[:-1] + (conv3.features,):
             residual = self.conv(
                 self.filters * self.expansion, (1, 1), strides=(self.strides, self.strides)
             )(residual)
             residual = self.norm()(residual)
-        return nn.relu(residual + y)
+        if norm3.use_running_average or self.is_initializing():
+            return nn.relu(residual + norm3(conv3(y)))
+        # Batch statistic: the closing unit, on the two modules' own
+        # variables (same tree as the line above creates and reads).
+        self.sow(MOMENT_UNITS, "units", 1)
+        weights = norm3.variables["params"]
+        out, mean, var = _closing_unit(
+            norm3.dtype, norm3.epsilon, norm3.axis_name
+        )(y, residual, conv3.variables["params"]["kernel"],
+          weights["scale"], weights["bias"])
+        if norm3.is_mutable_collection("batch_stats"):
+            for name, stat in (("mean", mean), ("var", var)):
+                norm3.put_variable(
+                    "batch_stats", name,
+                    norm3.momentum * norm3.get_variable("batch_stats", name)
+                    + (1 - norm3.momentum) * stat)
+        return out
 
 
 class ResNet(nn.Module):

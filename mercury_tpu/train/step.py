@@ -37,6 +37,7 @@ from mercury_tpu.data.pipeline import (
     next_pool,
     normalize_images,
 )
+from mercury_tpu.models.resnet import MOMENT_UNITS
 from mercury_tpu.obs.diagnostics import (
     clip_fraction,
     ema_drift,
@@ -169,6 +170,7 @@ def make_train_step(
     scoring_model=None,
     io_constraints: bool = True,
     image_shape: Optional[Tuple[int, int, int]] = None,
+    trace_facts: Optional[Dict[str, int]] = None,
 ) -> Callable[..., Tuple[MercuryState, Dict[str, jax.Array]]]:
     """Build the jitted train step.
 
@@ -193,6 +195,12 @@ def make_train_step(
     when given, the candidate-scoring forward runs through it instead of
     ``model`` — the IS reweight divides by the realized probabilities, so
     a lower-precision scorer reranks candidates without biasing the loss.
+
+    ``trace_facts`` (optional) is filled as the step is traced with what
+    only the trace knows: ``bn_moment_units``, how many conv+BN units of
+    the scoring forward take their batch statistic from their input's
+    moments (``models/resnet.py::_closing_unit``; 0 where the model has
+    none). ``Trainer`` reports it as the instant ``trainer/bn_moment_units``.
 
     SHARDING CONTRACT (enforced by graftlint Layer 3, ``lint/
     sharding.py`` — see docs/LINT.md): the step's inputs are pinned with
@@ -491,6 +499,13 @@ def make_train_step(
             return pool_mean(_loss_per_sample(pool_logits, labels), stat_axis)
         return score_avg
 
+    def _note_moment_units(model_state):
+        """A forward that nothing differentiates ran: its closing units
+        (each sowed a 1) took their statistic from input moments."""
+        if trace_facts is not None:
+            trace_facts["bn_moment_units"] = len(
+                jax.tree_util.tree_leaves(model_state.get(MOMENT_UNITS, {})))
+
     def _apply_train(params, batch_stats, images, keep_stats: bool):
         """Train-mode forward. ``keep_stats=False`` (the scoring pass) uses
         batch statistics for normalization but discards the running-stat
@@ -506,9 +521,13 @@ def make_train_step(
         if batch_stats:
             variables["batch_stats"] = batch_stats
             mutable = ["batch_stats", "losses"]
+        if not keep_stats:
+            mutable.append(MOMENT_UNITS)
         logits, new_model_state = model.apply(
             variables, images, train=True, mutable=mutable
         )
+        if not keep_stats:
+            _note_moment_units(new_model_state)
         from mercury_tpu.utils.tree import sum_sowed_losses
 
         aux = sum_sowed_losses(new_model_state)
@@ -637,16 +656,17 @@ def make_train_step(
             # materialize at f32; the returned imgs keep the training
             # precision when the caller reuses them.
             variables = {"params": state.params}
-            mutable = ["losses"]
+            mutable = ["losses", MOMENT_UNITS]
             if state.batch_stats:
                 variables["batch_stats"] = state.batch_stats
-                mutable = ["batch_stats", "losses"]
+                mutable.append("batch_stats")
             with jax.named_scope("mercury_score_forward"):
                 s_in = imgs.astype(jnp.bfloat16) if scoring_bf16 else imgs
-                pool_logits, _ = scoring_model.apply(
+                pool_logits, model_state = scoring_model.apply(
                     variables, s_in, train=True, mutable=mutable
                 )
                 pool_logits = pool_logits.astype(jnp.float32)
+            _note_moment_units(model_state)
         with jax.named_scope("mercury_score_loss"):
             scores = _score_per_sample(pool_logits, labs)
         return imgs, pool_logits, scores

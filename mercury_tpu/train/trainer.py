@@ -485,11 +485,15 @@ class Trainer:
             self._step_x = (make_global_array(host_rows, self.mesh, P())
                             if flat_rows else self.dataset.x_train)
             self._step_y = self.dataset.y_train
+        # What only the trace of the step knows (make_train_step fills it
+        # as the step is traced: first dispatch of the first fit()).
+        self._trace_facts: Dict[str, int] = {}
         self.train_step = make_train_step(
             self.model, self.tx, config, self.mesh, self.dataset.mean,
             self.dataset.std, state_out_shardings=self._state_out_shardings,
             scoring_model=self.scoring_model,
             image_shape=self._image_shape,
+            trace_facts=self._trace_facts,
         )
         # K-step chunked variant: one dispatch per config.scan_steps steps
         # (lax.scan over the same body; jit is lazy, so this costs nothing
@@ -511,6 +515,7 @@ class Trainer:
                 state_out_shardings=self._state_out_shardings,
                 scoring_model=self.scoring_model,
                 image_shape=self._image_shape,
+                trace_facts=self._trace_facts,
             )
             if self.scan_steps > 1
             else None
@@ -1402,7 +1407,14 @@ class Trainer:
         # self time is the loop's bookkeeping), and what lies between two
         # of them is the caller's code.
         with self.tracer.call_span("trainer/fit", cat="trainer"):
-            return self._fit(num_epochs)
+            final_metrics = self._fit(num_epochs)
+            # How often the input-moments statistic engages is a fact of
+            # the traced step, like the ingest: once a call, after its
+            # steps (the first call's first dispatch traces the step).
+            self.tracer.instant(
+                "trainer/bn_moment_units", cat="trainer",
+                units=self._trace_facts.get("bn_moment_units", 0))
+            return final_metrics
 
     def _fit(self, num_epochs: Optional[int]) -> Dict[str, float]:
         cfg = self.config

@@ -206,3 +206,75 @@ def test_pool_ingest_dense_for_v5e(v5e_devices):
     assert not set_copies, set_copies
     accessed = compiled.cost_analysis()["bytes accessed"]
     assert accessed < 1.5e9, accessed
+
+
+# ------------------------------------------------- the scoring forward's bytes
+def _resnet50_shapes(devices, block_cls, rows):
+    """A bf16 ResNet-50 over ``rows`` CIFAR images, as shapes on one v5e
+    device: ``(model, variables, images)``."""
+    from mercury_tpu.models.resnet import ResNet
+
+    model = ResNet(stage_sizes=[3, 4, 6, 3], block_cls=block_cls,
+                   num_classes=100, compute_dtype=jnp.bfloat16)
+    sh = NamedSharding(Mesh(np.array(devices[:1]), ("data",)), P())
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((2, 32, 32, 3)),
+                           train=True))
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        variables)
+    images = jax.ShapeDtypeStruct((rows, 32, 32, 3), jnp.float32, sharding=sh)
+    return model, variables, images
+
+
+def test_pool_forward_writes_the_residual_maps_once_for_v5e(v5e_devices):
+    """The scoring forward at the benchmark cell's pool (2,560 rows, bf16,
+    batch-statistic BN), which nothing differentiates. With the statistic of
+    each Bottleneck's closing BatchNorm taken from the convolution's output
+    the v5e compiler counts 59.36 GB accessed, and the block's output is a
+    ``kLoop`` fusion that reads the raw ``bf16[2560,32,32,256]`` map back
+    (the two heaviest device ops of PERF.md section 5, PR 27); from the
+    input's moments it is the convolution's own epilogue: 49.45 GB."""
+    import re
+
+    from mercury_tpu.models.resnet import Bottleneck
+
+    model, variables, images = _resnet50_shapes(v5e_devices, Bottleneck, 2560)
+    compiled = jax.jit(
+        lambda v, x: model.apply(v, x, train=True, mutable=["batch_stats"])[0]
+    ).lower(variables, images).compile()
+    accessed = compiled.cost_analysis()["bytes accessed"]
+    assert accessed < 52e9, accessed
+    # a bitcast moves no bytes; any other loop fusion that writes a stage-1
+    # block output is the second pass this change removes
+    passes = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= bf16\[2560,32,32,256\]\S* fusion\(", line)
+              and "kind=kLoop" in line and "calls=%bitcast_fusion" not in line]
+    assert not passes, passes
+
+
+def test_differentiated_pass_is_the_plain_forms_for_v5e(v5e_devices):
+    """Under ``jax.grad`` the closing unit is the plain form (``nn.Conv``
+    then ``nn.BatchNorm``): the train forward and backward at the cell's
+    batch (256 rows) cost the v5e compiler what the parent's block costs
+    it, to the FLOP and the byte."""
+    from mercury_tpu.models.resnet import Bottleneck
+    from test_bn_moments import PlainBottleneck
+
+    def cost(block_cls):
+        model, variables, images = _resnet50_shapes(v5e_devices, block_cls,
+                                                    256)
+
+        def loss(params, batch_stats, x):
+            logits, new = model.apply(
+                {"params": params, "batch_stats": batch_stats}, x,
+                train=True, mutable=["batch_stats"])
+            return jnp.sum(jnp.square(logits)), new
+
+        compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+            variables["params"], variables["batch_stats"], images).compile()
+        analysis = compiled.cost_analysis()
+        return (analysis["flops"], analysis["bytes accessed"],
+                compiled.memory_analysis().temp_size_in_bytes)
+
+    assert cost(Bottleneck) == cost(PlainBottleneck)

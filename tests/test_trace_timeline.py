@@ -190,17 +190,35 @@ def _tiny(**kw):
 
 
 @pytest.fixture(scope="module")
-def traced_fit(tmp_path_factory):
-    """Two ``fit()`` calls of a tiny traced trainer; the tracer's spans."""
+def traced_events(tmp_path_factory):
+    """Two ``fit()`` calls of a tiny traced trainer; the tracer's record."""
     tr = Trainer(_tiny(trace=True, use_importance_sampling=True,
                        checkpoint_dir=str(tmp_path_factory.mktemp("ckpt"))),
                  mesh=host_cpu_mesh(1))
     try:
         tr.fit()
         tr.fit(num_epochs=5)
-        return [e for e in tr.tracer.snapshot() if e["ph"] == "X"]
+        return tr.tracer.snapshot()
     finally:
         tr.close()
+
+
+@pytest.fixture(scope="module")
+def traced_fit(traced_events):
+    """The spans of those two calls."""
+    return [e for e in traced_events if e["ph"] == "X"]
+
+
+def test_each_fit_reports_its_bn_moment_units(traced_events, traced_fit):
+    """Once a call, under its root span: how many conv+BN units of the
+    traced step's scoring forward take their statistic from input moments
+    (smallcnn has none; tests/test_bn_moments.py counts ResNet-50's 16)."""
+    fits = [e for e in traced_fit if e["name"] == "trainer/fit"]
+    marks = [e for e in traced_events
+             if e["name"] == "trainer/bn_moment_units"]
+    assert [e["args"]["units"] for e in marks] == [0, 0]
+    assert [e["args"]["parent"] for e in marks] == [
+        e["args"]["id"] for e in fits]
 
 
 def test_fit_has_a_root_span_per_call(traced_fit):
