@@ -541,7 +541,7 @@ class TrainConfig:
     # uint8 image rows under augmentation="noniid" without cutout are
     # ingested by data.pipeline.augment_normalize on every path — one
     # dense pass over the raw bytes, crop/flip as exact selection, then
-    # normalize (train/step.py::ingest_path picks it from what the step
+    # normalize (StepMode.ingest_path picks it from what the step
     # sees; PERF.md section 6, PR 26) — bit-identical at f32 to the
     # normalize_images + augment_batch chain (test-enforced). This flag
     # no longer changes the ingest: it names the scope the pass runs

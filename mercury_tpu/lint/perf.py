@@ -105,14 +105,16 @@ _HLO_ELEMENTWISE = frozenset({
 
 #: One HLO instruction: ``%name = <type> <opcode>(...)``.
 _HLO_INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\S+)\s+([\w\-]+)\(")
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S+)\s+([\w\-]+)\((.*?)\)")
 #: One HLO computation header: ``[ENTRY] %name (params) -> type {``.
 _HLO_COMPUTATION_RE = re.compile(
-    r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.+\{\s*$")
+    r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\((.*)\)\s*->\s*.+\{\s*$")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
-#: A bf16-typed operand in an instruction's argument list — HLO text
-#: prints operands with their shapes: ``convert(bf16[4,4]{1,0} %x)``.
-_BF16_OPERAND_RE = re.compile(r"\(\s*bf16\[")
+#: One ``name: type`` parameter of a computation header.
+_HLO_PARAM_RE = re.compile(r"%?([\w.\-]+):\s*(\w+\[)")
+#: An operand, with the shape older HLO text prints before it:
+#: ``convert(bf16[4,4]{1,0} %x)``; jax 0.9 prints ``convert(%x)``.
+_HLO_OPERAND_RE = re.compile(r"(?:(\w+\[)\S*\s+)?%?([\w.\-]+)")
 
 
 def default_perf_budgets_path() -> str:
@@ -240,16 +242,19 @@ def scan_hlo(hlo_text: str, plan: str) -> Dict[str, Any]:
     unfused = 0
     examples: List[str] = []
     in_fusion = False
+    types: Dict[str, str] = {}    # value name -> type, this computation's
     for line in hlo_text.splitlines():
         header = _HLO_COMPUTATION_RE.match(line)
         if header:
             comp = header.group(1)
             in_fusion = "fused" in comp
+            types = dict(_HLO_PARAM_RE.findall(header.group(2)))
             continue
         m = _HLO_INSTR_RE.match(line)
         if not m:
             continue
-        result_type, opcode = m.groups()
+        name, result_type, opcode, operands = m.groups()
+        types[name] = result_type
         om = _OP_NAME_RE.search(line)
         op_name = om.group(1) if om else ""
         scope = _attribute_scope(op_name)
@@ -257,7 +262,9 @@ def scan_hlo(hlo_text: str, plan: str) -> Dict[str, Any]:
             continue
         if (opcode == "convert" and result_type.startswith("f32")
                 and scope == "mercury_scoring"
-                and _BF16_OPERAND_RE.search(line)):
+                and any((shape or types.get(value, "")).startswith("bf16[")
+                        for shape, value
+                        in _HLO_OPERAND_RE.findall(operands))):
             # Only a bf16→f32 upcast is a leak: the scoring region fell
             # back to f32 math. Input-pixel conversions (u8/f32 → f32
             # normalization before the bf16 downcast) are the designed

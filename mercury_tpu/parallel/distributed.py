@@ -207,7 +207,7 @@ def worker_shard_global_arrays(
     capability parity with ``load_partition_data_distributed_cifar10``
     (``cifar10/data_loader.py:214-245``). ``flat_rows`` flattens each
     sample (``[W, L, H*W*C]``): the layout the step's selection ingest
-    gathers from without a relayout (``train/step.py::ingest_path``)."""
+    gathers from without a relayout (``StepMode.ingest_path``)."""
     sidx = np.asarray(dataset.shard_indices)
     xs = np.asarray(dataset.x_train)
     if flat_rows:

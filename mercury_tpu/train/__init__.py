@@ -4,5 +4,5 @@ from mercury_tpu.train.checkpoint import (  # noqa: F401
     save_checkpoint,
 )
 from mercury_tpu.train.state import MercuryState, create_state, make_optimizer  # noqa: F401
-from mercury_tpu.train.step import make_eval_step, make_train_step  # noqa: F401
+from mercury_tpu.train.step import make_train_step  # noqa: F401
 from mercury_tpu.train.trainer import Trainer, build_dataset  # noqa: F401

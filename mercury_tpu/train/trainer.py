@@ -32,6 +32,7 @@ Parity map:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import time
@@ -66,8 +67,9 @@ from mercury_tpu.obs.writer import (
 )
 from mercury_tpu.parallel.mesh import make_mesh
 from mercury_tpu.train import checkpoint as ckpt
+from mercury_tpu.train.mode import StepMode
 from mercury_tpu.train.state import MercuryState, create_state, make_optimizer
-from mercury_tpu.train.step import make_eval_epoch, make_eval_step, make_train_step
+from mercury_tpu.train.step import make_eval_epoch, make_train_step
 from mercury_tpu.utils.logging import get_logger
 
 _log = get_logger("mercury_tpu.train.trainer")
@@ -263,6 +265,12 @@ class Trainer:
                 "set augmentation='none'"
             )
         sample = jnp.zeros((1,) + sample_shape, jnp.float32)
+        params_sharded = tp > 1 or fs > 1
+        # The run's mode, decided once (train/mode.py): sampler kind,
+        # placement, sizes, optional state fields. All below reads this.
+        self._mode = mode = StepMode.from_config(
+            config, mesh_axes=dict(self.mesh.shape),
+            param_specs_pinned=params_sharded)
         self.state: MercuryState = create_state(
             jax.random.key(config.seed),
             self.model,
@@ -270,49 +278,15 @@ class Trainer:
             sample,
             config.world_size,
             int(self.dataset.shard_indices.shape[1]),
-            with_groupwise=(
-                config.use_importance_sampling and config.sampler == "groupwise"
-            ),
-            pending_batch_size=(
-                config.batch_size
-                if config.use_importance_sampling and config.pipelined_scoring
-                else 0
-            ),
             # The IID augmentation pipeline crops to 32 regardless of the raw
             # image size (exp_dataset.py:26-27); noniid/none keep the
             # dataset's own sample shape.
             pending_sample_shape=((32, 32, sample_shape[-1])
                                   if config.augmentation == "iid"
                                   else sample_shape),
-            zero_sharding=config.zero_sharding,
-            init_opt=(tp == 1 and fs == 1),
-            cached_pool_size=(
-                config.candidate_pool_size
-                if config.use_importance_sampling
-                and config.sampler == "pool"
-                and config.score_refresh_every > 1
-                else 0
-            ),
-            with_scoretable=(
-                config.use_importance_sampling
-                and config.sampler == "scoretable"
-            ),
-            # Selection-count ledger rides only when the step will
-            # actually scatter into it — scoretable sampler AND telemetry
-            # on (obs/sampler_health.py). A telemetry=False run carries
-            # no ledger at all, keeping its traced program byte-identical
-            # to the seed's (Layer-2/3 digests).
-            with_sel_counts=(
-                config.use_importance_sampling
-                and config.sampler == "scoretable"
-                and bool(config.telemetry)
-            ),
-            stream_depth=(config.prefetch_depth
-                          if config.data_placement == "host_stream" else 0),
-            stream_emit_size=self._stream_emit_size(),
-            stream_batch_size=config.batch_size,
+            init_opt=not params_sharded,
+            **mode.create_state_fields(),
         )
-        params_sharded = tp > 1 or fs > 1
         if params_sharded:
             # Commit params in the sharded layout — Megatron column/row
             # under tensor_parallel, per-leaf largest-dim FSDP under
@@ -369,18 +343,15 @@ class Trainer:
         # x_train/y_train stay host-side for eval. Built BEFORE the
         # dataset is globalized (it reads the process-local host copy,
         # identical on every process by seeded construction).
-        data_sharded = config.data_placement == "sharded"
-        host_stream = config.data_placement == "host_stream"
-        # Which ingest the step is built with (step.ingest_path reads it
+        data_sharded, host_stream = mode.data_sharded, mode.host_stream
+        # Which ingest the step is built with (StepMode.ingest_path reads it
         # off the rows' dtype and the augmentation). On the selection
         # ingest the step's image rows are FLAT — [N, H*W*C] uint8, a
         # host-side view of x_train — so the pool's gather is a dense row
         # gather and the step never relays the resident set out (PERF.md
         # section 6, PR 26); dataset.x_train keeps its [N, H, W, C] face
         # for evaluate() and every other reader.
-        from mercury_tpu.train.step import ingest_path
-
-        self._ingest_path = ingest_path(config, self.dataset.x_train.dtype)
+        self._ingest_path = mode.ingest_path(self.dataset.x_train.dtype)
         flat_rows = is_image and self._ingest_path == "select"
         self._image_shape = sample_shape if flat_rows else None
         # Read BEFORE the dataset is globalized, like the shard arrays
@@ -488,16 +459,17 @@ class Trainer:
         # What only the trace of the step knows (make_train_step fills it
         # as the step is traced: first dispatch of the first fit()).
         self._trace_facts: Dict[str, int] = {}
-        self.train_step = make_train_step(
-            self.model, self.tx, config, self.mesh, self.dataset.mean,
-            self.dataset.std, state_out_shardings=self._state_out_shardings,
+        build_step = functools.partial(
+            make_train_step, self.model, self.tx, config, self.mesh,
+            self.dataset.mean, self.dataset.std,
+            state_out_shardings=self._state_out_shardings,
             scoring_model=self.scoring_model,
             image_shape=self._image_shape,
             trace_facts=self._trace_facts,
         )
+        self.train_step = build_step()
         # K-step chunked variant: one dispatch per config.scan_steps steps
-        # (lax.scan over the same body; jit is lazy, so this costs nothing
-        # unless used).
+        # (lax.scan over the same driver).
         self.scan_steps = max(int(config.scan_steps), 1)
         if self.scan_steps > 1:
             for name in ("log_every", "eval_every", "checkpoint_every"):
@@ -508,19 +480,8 @@ class Trainer:
                         f"scan_steps={self.scan_steps}; cadence actions fire "
                         "at most once per chunk (at chunk boundaries)"
                     )
-        self.train_step_many = (
-            make_train_step(
-                self.model, self.tx, config, self.mesh,
-                self.dataset.mean, self.dataset.std, scan_steps=self.scan_steps,
-                state_out_shardings=self._state_out_shardings,
-                scoring_model=self.scoring_model,
-                image_shape=self._image_shape,
-                trace_facts=self._trace_facts,
-            )
-            if self.scan_steps > 1
-            else None
-        )
-        self.eval_step = make_eval_step(self.model)
+        self.train_step_many = (build_step(scan_steps=self.scan_steps)
+                                if self.scan_steps > 1 else None)
         # Shard eval batches over the mesh so evaluation uses every device
         # (single-controller only: multi-process would need global eval
         # arrays; there the replicated path is correct, just redundant).
@@ -605,29 +566,29 @@ class Trainer:
         # "files" tails the per-host shards on the writer's drain thread
         # (observer); "allgather" runs a small dedicated jitted gather at
         # the log gate instead. Neither touches the fused step program.
-        mode = config.crosshost_telemetry
-        if mode not in ("auto", "off", "files", "allgather"):
+        xh_mode = config.crosshost_telemetry
+        if xh_mode not in ("auto", "off", "files", "allgather"):
             raise ValueError(
-                f"crosshost_telemetry={mode!r}: expected one of "
+                f"crosshost_telemetry={xh_mode!r}: expected one of "
                 "'auto', 'off', 'files', 'allgather'")
-        if mode == "auto":
-            mode = "files" if jax.process_count() > 1 else "off"
-        if mode == "files" and not config.log_dir:
-            mode = "off"  # file aggregation needs shards to tail
-        self._crosshost_mode = mode
+        if xh_mode == "auto":
+            xh_mode = "files" if jax.process_count() > 1 else "off"
+        if xh_mode == "files" and not config.log_dir:
+            xh_mode = "off"  # file aggregation needs shards to tail
+        self._crosshost_mode = xh_mode
         self._host_agg: Optional[HostShardAggregator] = None
         self._crosshost_gather: Optional[CrossHostGatherAggregator] = None
         if pidx == 0:
-            if mode == "files":
+            if xh_mode == "files":
                 self._host_agg = HostShardAggregator(
                     config.log_dir,
                     processes=jax.process_count(),
                     window=config.crosshost_window,
                 )
-            elif mode == "allgather":
+            elif xh_mode == "allgather":
                 self._crosshost_gather = CrossHostGatherAggregator(
                     window=config.crosshost_window)
-        elif mode == "allgather":
+        elif xh_mode == "allgather":
             # Non-zero hosts still participate in the collective.
             self._crosshost_gather = CrossHostGatherAggregator(
                 window=config.crosshost_window)
@@ -652,8 +613,7 @@ class Trainer:
                 slow_step_factor=config.anomaly_slow_step_factor,
                 ess_floor=config.slo_ess_floor,
                 stall_frac_max=(config.slo_stall_frac_max
-                                if config.data_placement == "host_stream"
-                                else 0.0),
+                                if mode.host_stream else 0.0),
                 mfu_floor=config.slo_mfu_floor,
                 straggler_factor=config.anomaly_straggler_factor,
                 gini_max=config.slo_selection_gini_max,
@@ -675,12 +635,7 @@ class Trainer:
         # — the ledger is a global array and device_get on another host's
         # shards raises (same constraint as the async scorer fleet).
         self._sampler_monitor: Optional[SamplerHealthMonitor] = None
-        if (
-            config.use_importance_sampling
-            and config.sampler == "scoretable"
-            and config.telemetry
-            and jax.process_count() == 1
-        ):
+        if mode.use_ledger and jax.process_count() == 1:
             self._sampler_monitor = SamplerHealthMonitor(
                 np.asarray(self.dataset.shard_indices),
                 np.asarray(self.dataset.y_train),
@@ -750,10 +705,6 @@ class Trainer:
         if host_stream:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
-            from mercury_tpu.data.stream import (
-                HostStreamSource,
-                PrefetchPipeline,
-            )
             from mercury_tpu.parallel.distributed import host_worker_slice
             from mercury_tpu.train.step import make_host_stream_prime
 
@@ -780,24 +731,13 @@ class Trainer:
                     self.mesh, config.mesh_axis)
             # The selection ingest streams flat rows too: host_rows is a
             # view, so the gather reads the same bytes either way.
-            source = HostStreamSource(
-                host_rows if flat_rows
-                else np.asarray(self.dataset.x_train),
-                decode_workers=config.decode_workers,
-            )
+            self._stream_rows = (host_rows if flat_rows
+                                 else np.asarray(self.dataset.x_train))
             self._stream_x_sharding = NamedSharding(
                 self.mesh, P(config.mesh_axis)
             )
             self._stream_gen = 0
-            self._stream_pipe = PrefetchPipeline(
-                source,
-                (config.world_size, self._stream_emit_size()),
-                self._stream_x_sharding,
-                depth=config.prefetch_depth,
-                tracer=self.tracer,
-                local_workers=self._stream_local_workers,
-                faults=self._faults,
-            )
+            self._stream_pipe = self._new_stream_pipe()
             if self.supervisor is not None:
                 # escalates=False: training cannot proceed without input,
                 # so past the restart budget a prefetch death propagates
@@ -838,9 +778,7 @@ class Trainer:
         # arm_retrace_guard(); when live, the log gate emits
         # lint/retrace_events + lint/compile_count per tick.
         self._retrace_monitor = None
-        if (config.use_importance_sampling
-                and config.sampler == "scoretable"
-                and config.refresh_mode == "async"):
+        if mode.async_refresh:
             from mercury_tpu.sampling.scorer_service import (
                 ScorerService,
                 validate_scorer_composition,
@@ -1034,21 +972,6 @@ class Trainer:
         return doc
 
     # -------------------------------------------------------- host streaming
-    def _stream_emit_size(self) -> int:
-        """Rows streamed per worker per step (mirrors ``make_train_step``):
-        the candidate pool for the pool sampler, refresh window + train
-        batch for the scoretable one, the batch itself for uniform."""
-        cfg = self.config
-        if cfg.use_importance_sampling and cfg.sampler == "scoretable":
-            if cfg.refresh_mode == "async":
-                # Async streams only the train rows — the scorer fleet
-                # owns the refresh sweep host-side.
-                return int(cfg.batch_size)
-            return int(cfg.refresh_size) + int(cfg.batch_size)
-        if cfg.use_importance_sampling:
-            return int(cfg.candidate_pool_size)
-        return int(cfg.batch_size)
-
     def _host_stream_step(self, step: int = 0):
         """One pop→step→push cycle: train on the oldest prefetched batch,
         hand the step's emitted t+depth indices straight back to the
@@ -1139,6 +1062,24 @@ class Trainer:
                 ])
                 self._stream_pipe.push(gidx)
 
+    def _new_stream_pipe(self):
+        """A prefetch pipeline of generation ``_stream_gen`` over the rows
+        the step streams, ``StepMode.emit_size`` of them per worker."""
+        from mercury_tpu.data.stream import HostStreamSource, PrefetchPipeline
+
+        cfg = self.config
+        return PrefetchPipeline(
+            HostStreamSource(self._stream_rows,
+                             decode_workers=cfg.decode_workers),
+            (cfg.world_size, self._mode.emit_size),
+            self._stream_x_sharding,
+            depth=cfg.prefetch_depth,
+            tracer=self.tracer,
+            local_workers=self._stream_local_workers,
+            faults=self._faults,
+            generation=self._stream_gen,
+        )
+
     def _restart_stream_pipe(self) -> None:
         """Supervisor restart: tear down the dead pipeline and build a
         generation-bumped replacement, resuming from the stream cursor.
@@ -1147,29 +1088,13 @@ class Trainer:
         ``_refill_stream_pipe`` recomputes ALL depth in-flight gathers
         from it — so the restarted trajectory is bit-identical to an
         uninterrupted one (test-enforced)."""
-        from mercury_tpu.data.stream import HostStreamSource, PrefetchPipeline
-
-        cfg = self.config
         old = self._stream_pipe
         self._stream_gen += 1
         try:
             old.close(timeout=5.0)
         except Exception as exc:
             _log.warning("dead prefetch pipeline close() raised: %s", exc)
-        source = HostStreamSource(
-            np.asarray(self.dataset.x_train),
-            decode_workers=cfg.decode_workers,
-        )
-        self._stream_pipe = PrefetchPipeline(
-            source,
-            (cfg.world_size, self._stream_emit_size()),
-            self._stream_x_sharding,
-            depth=cfg.prefetch_depth,
-            tracer=self.tracer,
-            local_workers=self._stream_local_workers,
-            faults=self._faults,
-            generation=self._stream_gen,
-        )
+        self._stream_pipe = self._new_stream_pipe()
         self._refill_stream_pipe()
 
     # --------------------------------------------------- async scorer fleet
@@ -1869,8 +1794,7 @@ class Trainer:
             # than committing a device-replicated full split.
             conv = (np.asarray
                     if jax.process_count() > 1
-                    or self.config.data_placement in ("sharded",
-                                                      "host_stream")
+                    or self._mode.placement != "replicated"
                     else jnp.asarray)
             self._eval_cache[train] = (
                 conv(np.asarray(x)[idx]),
@@ -1989,20 +1913,12 @@ class Trainer:
 
     def _state_sharding_tree(self, params_sh, opt_sh):
         """``(state shardings, metrics sharding)`` for this trainer's
-        state with the given params / optimizer layouts; which optional
-        sampler fields it carries is read off the state itself."""
+        state with the given params / optimizer layouts."""
         from mercury_tpu.train.step import mercury_state_out_shardings
 
-        st = self.state
         return mercury_state_out_shardings(
             self.mesh, self.config.mesh_axis, params_sh, opt_sh,
-            has_groupwise=st.groupwise is not None,
-            has_pending=st.pending is not None,
-            has_cached_pool=st.cached_pool is not None,
-            has_scoretable=st.scoretable is not None,
-            has_pending_sel=st.pending_sel is not None,
-            has_sel_counts=st.sel_counts is not None,
-        )
+            **self._mode.state_fields())
 
     def _state_shardings(self) -> MercuryState:
         """``NamedSharding`` prefix tree of the layout the step program
@@ -2141,9 +2057,7 @@ class Trainer:
         # table and stream cursor across, re-prime the lookahead ring for
         # the new topology (make_host_stream_prime on the restored,
         # step-folded rng) and seed each host's pipeline from it.
-        self._recommit_state(
-            reprime_stream=self.config.data_placement == "host_stream"
-        )
+        self._recommit_state(reprime_stream=self._mode.host_stream)
         return step
 
     def restore(self, directory: Optional[str] = None, step: Optional[int] = None) -> int:

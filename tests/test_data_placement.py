@@ -478,7 +478,7 @@ class TestSelectIngestTrajectory:
     @pytest.mark.parametrize("placement", [
         "replicated", "sharded", "host_stream"])
     def test_two_steps_bitwise_old_chain(self, mesh1, monkeypatch, placement):
-        import mercury_tpu.train.step as step_mod
+        import mercury_tpu.train.stages as step_mod  # the ingest stage
 
         # host_stream pipelines its own selection; the others pipeline
         # the scoring (the benchmark cell's path: state.pending.images).
