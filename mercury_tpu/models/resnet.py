@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from mercury_tpu.ops import input_moments_pallas
+
 ModuleDef = Any
 
 
@@ -58,7 +60,7 @@ class BasicBlock(nn.Module):
 
 
 # The collection a ``Bottleneck`` sows a 1 into for each closing unit it
-# runs; mutable only where a caller counts them (train/step.py).
+# runs; mutable only where a caller counts them (train/stages.py).
 MOMENT_UNITS = "bn_moment_units"
 
 
@@ -75,22 +77,31 @@ def _stat_from_output(y, axis_name):
     return mu, jnp.maximum(0.0, mu2 - lax.square(mu))
 
 
-def _stat_from_input(h, kernel, axis_name):
+def _normalize(y, mean, var, scale, bias, epsilon, dtype):
+    """``nn.BatchNorm``'s normalise of ``y`` by a given statistic (flax's
+    ``_normalize``, op for op and shape for shape), with the per-channel
+    ``mul`` it scaled by."""
+    axes, feature = tuple(range(y.ndim - 1)), (1,) * (y.ndim - 1) + (-1,)
+    mul = lax.rsqrt(jnp.expand_dims(var, axes) + epsilon)
+    mul = mul * scale.reshape(feature)
+    z = (y - jnp.expand_dims(mean, axes)) * mul
+    return (z + bias.reshape(feature)).astype(dtype), mul.reshape(-1)
+
+
+def _stat_from_input(moments, rows, kernel, axis_name):
     """The same statistic of ``y = conv1x1(h, kernel)`` without ``y``: over
     the ``M`` rows of ``h``, ``mean(y) = mean(h) @ W`` and ``E[y²] =
     diag(Wᵀ G W)`` with ``G = hᵀh / M`` — a ``[K, K]`` matrix over the
-    convolution's input, a quarter of its output's width. ``h`` and
-    ``kernel`` are what the convolution reads (both in its dtype); ``G``
-    accumulates in f32 from that ``h``, and the small products run at
-    full f32 precision, so only where rounding to bf16 falls differs from
-    :func:`_stat_from_output`. Both moments are linear in the rows, so
+    convolution's input, a quarter of its output's width. ``moments`` are
+    ``(Σ h, hᵀh)`` in f32 over the ``rows`` rows of the ``h`` the convolution
+    reads (in its dtype), ``kernel`` what it reads too; the small products
+    run at full f32 precision, so only where rounding to bf16 falls differs
+    from :func:`_stat_from_output`. Both moments are linear in the rows, so
     their ``pmean`` is the synced statistic, exactly."""
-    k = h.shape[-1]
-    rows = h.reshape(-1, k)
+    s, gram = moments
+    k = s.shape[0]
     w = kernel.reshape(k, -1).astype(jnp.float32)
-    s = rows.astype(jnp.float32).mean(0)
-    gram = lax.dot_general(rows, rows, (((0,), (0,)), ((), ())),
-                           preferred_element_type=jnp.float32) / rows.shape[0]
+    s, gram = s / rows, gram / rows
     if axis_name is not None:
         synced = lax.pmean(jnp.concatenate((s[None], gram)), axis_name)
         s, gram = synced[0], synced[1:]
@@ -101,39 +112,50 @@ def _stat_from_input(h, kernel, axis_name):
 
 
 def _closing_unit(dtype, epsilon, axis_name):
-    """``(h, shortcut, kernel, scale, bias) → (out, mean, var)``: a
-    ``Bottleneck``'s closing 1×1 convolution, train-mode BatchNorm, shortcut
-    add and ReLU, with the batch statistic it normalised by.
+    """``(y2, shortcut, scale2, bias2, kernel, scale, bias) → (out, stat2,
+    stat)``: a ``Bottleneck`` from ``conv2``'s raw output on — train-mode
+    BatchNorm + ReLU, the closing 1×1 convolution, train-mode BatchNorm,
+    shortcut add and ReLU — with the two batch statistics ``(mean, var)`` it
+    normalised by.
 
-    One algorithm, two exact ways to the statistic, chosen by what the pass
-    needs. Nothing differentiates it (the scoring forward): the statistic
-    comes from the input's moments, scale and shift are known before the
-    convolution runs, and XLA writes the block's output once, from the
-    convolution's own epilogue. Under ``jax.grad`` BatchNorm's backward
-    needs the convolution's raw output anyway, so the ``custom_vjp`` rule is
-    the vjp of the plain form — ``nn.Conv`` then ``nn.BatchNorm``'s
-    arithmetic, statistic from the output — and the differentiated pass is
-    what it was. ``mean``/``var`` leave for the running averages, which
+    One algorithm, two exact ways to the closing statistic, chosen by what
+    the pass needs. Nothing differentiates it (the scoring forward): the
+    statistic comes from the moments of the convolution's input ``h``, scale
+    and shift are known before the convolution runs, and XLA writes the
+    block's output once, from the convolution's own epilogue. Those moments
+    are one kernel's one read of the raw ``y2`` (``ops.input_moments_pallas``
+    normalises and clips each tile itself), which is why the unit begins
+    there: the closing convolution is the map's only other reader, with the
+    same normalise + ReLU as its prologue, and ``h`` is never written. Under
+    ``jax.grad`` BatchNorm's backward needs the convolution's raw output
+    anyway, so the ``custom_vjp`` rule is the vjp of the plain form —
+    ``nn.BatchNorm``, ReLU, ``nn.Conv`` then ``nn.BatchNorm``'s arithmetic,
+    each statistic from the map it normalises — and the differentiated pass
+    is what it was. The statistics leave for the running averages, which
     nothing differentiates."""
 
-    def form(stat):
-        def apply(h, shortcut, kernel, scale, bias):
-            hc, kc = h.astype(dtype), kernel.astype(dtype)
+    def form(from_input):
+        def apply(y2, shortcut, scale2, bias2, kernel, scale, bias):
+            stat2 = _stat_from_output(y2, axis_name)
+            z2, mul2 = _normalize(y2, *stat2, scale2, bias2, epsilon, dtype)
+            hc, kc = nn.relu(z2), kernel.astype(dtype)
             y = lax.conv_general_dilated(
                 hc, kc, (1, 1), "SAME",
                 dimension_numbers=("NHWC", "HWIO", "NHWC"))
-            mean, var = stat(hc, kc, y)
-            # flax's ``_normalize``, op for op and shape for shape
-            axes, feature = tuple(range(y.ndim - 1)), (1,) * (y.ndim - 1) + (-1,)
-            mul = lax.rsqrt(jnp.expand_dims(var, axes) + epsilon)
-            z = (y - jnp.expand_dims(mean, axes)) * (mul * scale.reshape(feature))
-            out = nn.relu(shortcut + (z + bias.reshape(feature)).astype(dtype))
-            return out, lax.stop_gradient(mean), lax.stop_gradient(var)
+            if from_input:
+                moments = input_moments_pallas(
+                    y2, stat2[0], mul2, bias2.astype(jnp.float32), dtype)
+                stat = _stat_from_input(moments, y2.size // y2.shape[-1], kc,
+                                        axis_name)
+            else:
+                stat = _stat_from_output(y, axis_name)
+            z, _ = _normalize(y, *stat, scale, bias, epsilon, dtype)
+            return (nn.relu(shortcut + z), lax.stop_gradient(stat2),
+                    lax.stop_gradient(stat))
         return apply
 
-    plain = form(lambda h, kernel, y: _stat_from_output(y, axis_name))
-    unit = jax.custom_vjp(
-        form(lambda h, kernel, y: _stat_from_input(h, kernel, axis_name)))
+    plain = form(from_input=False)
+    unit = jax.custom_vjp(form(from_input=True))
     unit.defvjp(lambda *args: jax.vjp(plain, *args),
                 lambda vjp, cotangents: vjp(cotangents))
     return unit
@@ -155,8 +177,7 @@ class Bottleneck(nn.Module):
         y = self.norm()(y)
         y = nn.relu(y)
         y = self.conv(self.filters, (3, 3), strides=(self.strides, self.strides))(y)
-        y = self.norm()(y)
-        y = nn.relu(y)
+        norm2 = self.norm()
         conv3 = self.conv(self.filters * self.expansion, (1, 1))
         norm3 = self.norm()
         if residual.shape != y.shape[:-1] + (conv3.features,):
@@ -165,21 +186,24 @@ class Bottleneck(nn.Module):
             )(residual)
             residual = self.norm()(residual)
         if norm3.use_running_average or self.is_initializing():
-            return nn.relu(residual + norm3(conv3(y)))
-        # Batch statistic: the closing unit, on the two modules' own
-        # variables (same tree as the line above creates and reads).
+            return nn.relu(residual + norm3(conv3(nn.relu(norm2(y)))))
+        # Batch statistic: the closing unit, from ``conv2``'s raw output on,
+        # on the three modules' own variables (same tree as the line above
+        # creates and reads).
         self.sow(MOMENT_UNITS, "units", 1)
-        weights = norm3.variables["params"]
-        out, mean, var = _closing_unit(
+        weights2, weights3 = (norm.variables["params"] for norm in (norm2, norm3))
+        out, stat2, stat3 = _closing_unit(
             norm3.dtype, norm3.epsilon, norm3.axis_name
-        )(y, residual, conv3.variables["params"]["kernel"],
-          weights["scale"], weights["bias"])
+        )(y, residual, weights2["scale"], weights2["bias"],
+          conv3.variables["params"]["kernel"],
+          weights3["scale"], weights3["bias"])
         if norm3.is_mutable_collection("batch_stats"):
-            for name, stat in (("mean", mean), ("var", var)):
-                norm3.put_variable(
-                    "batch_stats", name,
-                    norm3.momentum * norm3.get_variable("batch_stats", name)
-                    + (1 - norm3.momentum) * stat)
+            for norm, stat in ((norm2, stat2), (norm3, stat3)):
+                for name, value in zip(("mean", "var"), stat):
+                    norm.put_variable(
+                        "batch_stats", name,
+                        norm.momentum * norm.get_variable("batch_stats", name)
+                        + (1 - norm.momentum) * value)
         return out
 
 

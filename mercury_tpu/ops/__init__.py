@@ -1,4 +1,5 @@
 from mercury_tpu.ops.mercury_kernels import (  # noqa: F401
+    input_moments_pallas,
     on_tpu,
     per_sample_nll_pallas,
     score_and_draw_pallas,

@@ -15,17 +15,25 @@ Two kernels cover the importance-sampling inner loop (the math of
    (N = the whole shard table, after the step's jax-native decay and
    refresh scatter).
 
+A third kernel serves the model, not the sampler:
+
+3. :func:`input_moments_pallas` — the first and second moments of a
+   ``Bottleneck``'s activated ``conv2`` map (``Σ h``, ``hᵀh``) from one read
+   of the convolution's RAW output: BatchNorm's normalise + ReLU run on each
+   tile in VMEM, the Gram product on the MXU, and ``h`` never reaches HBM
+   (``models/resnet.py::_stat_from_input``; PERF.md §6, PR 32).
+
 Uniform variates are passed in (from ``jax.random``) rather than drawn with
 the in-kernel TPU PRNG, so the draw is reproducible from a JAX key and the
 kernels run identically under ``interpret=True`` on CPU (how the test suite
 exercises them without a chip).
 
-Each kernel is a single block, no grid. The draw kernel holds its scores
+Kernels 1 and 2 are a single block each, no grid. The draw kernel holds its scores
 lane-dense — ``[N/128, 128]`` f32, 4 bytes per candidate — because Mosaic
 tiles an ``[N, 1]`` f32 column ``(8, 128)``, 512 bytes per candidate: a
 50,000-slot table is 0.2 MB lane-dense and 24 MB as a column, past the
-16 MB scoped-VMEM limit of a v5e. ``tests/test_tpu_aot.py`` compiles both
-kernels for the v5e target at the shapes ``Trainer`` produces.
+16 MB scoped-VMEM limit of a v5e. ``tests/test_tpu_aot.py`` compiles every
+kernel for the v5e target at the shapes ``Trainer`` produces.
 """
 
 from __future__ import annotations
@@ -248,3 +256,152 @@ def score_and_draw_pallas(
         )
     probs = probs.reshape(n_pad)[:n]
     return probs, selected, probs[selected] * n
+
+
+# ----------------------------------------------------------------- kernel 3
+#: Bytes of the raw map one grid step brings into VMEM (twice that with
+#: Pallas's double buffering); the f32 intermediates live a chunk at a time.
+#: On the v5e 4 MiB blocks run the 64-wide maps 15 % faster than 1 MiB ones.
+_MOMENTS_BLOCK_BYTES = 4 << 20
+#: Lanes (batch-in-lanes view) or rows (channels-in-lanes view) activated
+#: and contracted at a time inside a grid step. The row loop is unrolled as
+#: the kernel is lowered (a rolled one serialises on the accumulator: 3x
+#: slower at 256 rows), and every trace of the step pays for that: 64
+#: chunks of 256 rows a block cost 16 s of warm set-up for 0.1 ms a step
+#: (PERF.md section 6, PR 32), so 1,024.
+_MOMENTS_LANE_CHUNK = 256
+_MOMENTS_ROW_CHUNK = 1024
+
+
+def _activate(y, mean, mul, bias, dtype):
+    """BatchNorm's normalise + ReLU on a tile of the raw map, in flax's
+    arithmetic (``_normalize``): f32, cast to ``dtype``, then ``max 0``."""
+    z = (y.astype(jnp.float32) - mean) * mul + bias
+    return jnp.maximum(z.astype(dtype), 0)
+
+
+def _zero_at_first_step(*refs):
+    """The accumulators stay in VMEM across the grid: clear them once."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        for ref in refs:
+            ref[...] = jnp.zeros_like(ref)
+
+
+def _moments_lanes_kernel(y_ref, mean_ref, mul_ref, bias_ref, s_ref, g_ref,
+                          *, dtype, chunk):
+    """``y_ref``: ``[P, C, N]`` — ``P`` groups of spatial positions, ``C``
+    channels (of one or several stacked positions) in the sublanes, the
+    batch in the lanes. Accumulates ``s [C, 1] = Σ h`` and ``g [C, C] =
+    h hᵀ`` (contracting the lanes) over the grid; ``mean``/``mul``/``bias``
+    are ``[C, 1]`` f32."""
+    _zero_at_first_step(s_ref, g_ref)
+    mean, mul, bias = mean_ref[...], mul_ref[...], bias_ref[...]
+
+    def group(j, carry):
+        s, g = jnp.zeros_like(s_ref), jnp.zeros_like(g_ref)
+        for lo in range(0, y_ref.shape[2], chunk):
+            h = _activate(y_ref[j, :, lo:lo + chunk], mean, mul, bias, dtype)
+            g = g + lax.dot_general(h, h, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s + jnp.sum(h.astype(jnp.float32), axis=1, keepdims=True)
+        s_ref[...] += s
+        g_ref[...] += g
+        return carry
+
+    lax.fori_loop(0, y_ref.shape[0], group, 0)
+
+
+def _moments_sublanes_kernel(y_ref, mean_ref, mul_ref, bias_ref, s_ref, g_ref,
+                             *, dtype, chunk, rows):
+    """``y_ref``: ``[T, K]`` — rows (position x batch) in the sublanes,
+    channels in the lanes. Accumulates ``s [1, K] = Σ h`` and ``g [K, K] =
+    hᵀ h`` (contracting the sublanes) over the grid; rows past ``rows`` (the
+    last block's padding) count as 0. ``mean``/``mul``/``bias``: ``[1, K]``."""
+    _zero_at_first_step(s_ref, g_ref)
+    mean, mul, bias = mean_ref[...], mul_ref[...], bias_ref[...]
+    t = y_ref.shape[0]
+    first = pl.program_id(0) * t
+
+    def add(lo, size):
+        h = _activate(y_ref[pl.ds(lo, size), :], mean, mul, bias, dtype)
+        if rows % t:
+            row = first + lo + lax.broadcasted_iota(jnp.int32, (size, 1), 0)
+            h = jnp.where(row < rows, h, jnp.zeros_like(h))
+        g_ref[...] += lax.dot_general(h, h, (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        s_ref[...] += jnp.sum(h.astype(jnp.float32), axis=0, keepdims=True)
+
+    def whole(i, carry):
+        add(pl.multiple_of(i * chunk, chunk), chunk)
+        return carry
+
+    if t >= chunk:
+        lax.fori_loop(0, t // chunk, whole, 0, unroll=True)
+    if t % chunk:           # a map of fewer rows than a block, in one block
+        add(t - t % chunk, t % chunk)
+
+
+def input_moments_pallas(y: jax.Array, mean: jax.Array, mul: jax.Array,
+                         bias: jax.Array, dtype) -> Tuple[jax.Array, jax.Array]:
+    """``(Σ h [K], hᵀh [K, K])`` in f32 over the rows of ``h = relu(((y −
+    mean) · mul + bias).astype(dtype))``, from ONE read of the raw NHWC map
+    ``y [N, H, W, K]``: ``h`` is formed tile by tile in VMEM and never
+    written. ``mean``/``mul``/``bias``: ``[K]`` f32.
+
+    Both moments are sums over rows, so any row order gives them; the
+    kernel takes the map in the order the TPU compiler already holds it in,
+    and the view below is then a ``bitcast`` of the convolution's output,
+    not a relayout (``tests/test_tpu_aot.py``). Narrow maps (``K`` < 128)
+    lie batch-in-lanes, channels in the sublanes: ``[H·W, K, N]``, with
+    ``128 // K`` positions stacked so the product fills the MXU's width
+    (its diagonal blocks sum to ``G``). Wide maps, and narrow ones whose
+    positions cannot be stacked in whole tiles, lie channels-in-lanes:
+    ``[H·W·N, K]``."""
+    n, hh, ww, k = y.shape
+    hw, itemsize = hh * ww, y.dtype.itemsize
+    sublanes = 8 * 4 // itemsize          # rows of one packed tile
+    stack = _LANES // k if k < _LANES else 0
+    if stack and not (_LANES % k or k % sublanes or hw % stack):
+        c, groups = stack * k, hw // stack
+        view = jnp.transpose(y, (1, 2, 3, 0)).reshape(groups, c, n)
+        # the most groups a block may hold that divide them all
+        p = max(d for d in range(1, groups + 1) if groups % d == 0
+                and d * c * n * itemsize <= max(_MOMENTS_BLOCK_BYTES,
+                                                c * n * itemsize))
+        kernel = functools.partial(
+            _moments_lanes_kernel, dtype=dtype,
+            chunk=_MOMENTS_LANE_CHUNK if n % _LANES == 0 else n)
+        grid, block, index = groups // p, (p, c, n), lambda i: (i, 0, 0)
+        vector, side = (c, 1), c       # per-channel operands: columns
+    else:
+        stack, rows = 1, hw * n
+        view = jnp.transpose(y, (1, 2, 0, 3)).reshape(rows, k)
+        t = max(_MOMENTS_ROW_CHUNK,
+                _MOMENTS_BLOCK_BYTES // (k * itemsize)
+                // _MOMENTS_ROW_CHUNK * _MOMENTS_ROW_CHUNK)
+        t = rows if rows <= t else t
+        kernel = functools.partial(
+            _moments_sublanes_kernel, dtype=dtype, chunk=_MOMENTS_ROW_CHUNK,
+            rows=rows)
+        grid, block, index = pl.cdiv(rows, t), (t, k), lambda i: (i, 0)
+        vector, side = (1, k), k       # per-channel operands: rows
+    # under a ``shard_map`` that checks them: the sums vary as the map does
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            vma=jax.typeof(y).vma)
+    whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
+    with jax.named_scope("mercury_moments_kernel"):
+        s, g = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[pl.BlockSpec(block, index)] + [whole(vector)] * 3,
+            out_specs=(whole(vector), whole((side, side))),
+            out_shape=(f32(vector), f32((side, side))),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=_interpret(),
+        )(view, *(jnp.tile(v.astype(jnp.float32), stack).reshape(vector)
+                  for v in (mean, mul, bias)))
+    # the stacked positions' diagonal blocks (one block where none is)
+    return (s.reshape(stack, k).sum(0),
+            sum(g[i * k:(i + 1) * k, i * k:(i + 1) * k] for i in range(stack)))

@@ -84,6 +84,11 @@ def _compile(fn, devices, *shapes):
     return _compiled(fn, devices, *shapes).as_text()
 
 
+#: ``conv2``'s raw output in each stage of the cell's scoring forward.
+CONV2_MAPS = [(2560, 32, 32, 64), (2560, 16, 16, 128), (2560, 8, 8, 256),
+              (2560, 4, 4, 512)]
+
+
 class TestKernelsCompileForV5e:
     @pytest.mark.parametrize("n,c,dtype", [
         (320, 10, jnp.float32), (32, 10, jnp.float32),
@@ -118,6 +123,58 @@ class TestKernelsCompileForV5e:
         text = _compile(draw, v5e_devices, ((2,), jnp.uint32),
                         ((n,), jnp.float32), ((), jnp.float32))
         assert "tpu_custom_call" in text
+
+
+    @pytest.mark.parametrize("shape,dtype", [
+        *[(shape, jnp.bfloat16) for shape in CONV2_MAPS],
+        # what else reaches the kernel: every non-differentiated Bottleneck
+        # forward, whatever the pool, the image and the dtype
+        ((320, 32, 32, 64), jnp.bfloat16),    # 320 lanes: one chunk, not 128s
+        ((80, 32, 32, 64), jnp.bfloat16),     # the pool of 320 over 4 chips
+        ((320, 4, 4, 512), jnp.bfloat16),     # 5,120 rows: last block masked
+        ((32, 4, 4, 512), jnp.bfloat16),      # 512 rows: less than a chunk
+        ((2560, 32, 32, 64), jnp.float32),    # f32 maps, both views
+        ((2560, 16, 16, 128), jnp.float32),
+        ((2560, 3, 3, 64), jnp.bfloat16),     # K < 128, nothing to stack
+        ((256, 56, 56, 64), jnp.bfloat16),    # ImageNet's first and last
+        ((256, 7, 7, 512), jnp.bfloat16),     # stage: odd rows of positions
+        ((2560, 32, 32, 32), jnp.bfloat16),   # four positions stacked
+    ], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple)
+        else jnp.dtype(v).name)
+    def test_input_moments(self, v5e_devices, mosaic, shape, dtype):
+        """The closing unit's moments kernel at the benchmark cell's four
+        ``conv2`` maps (pool of 2,560, bf16): the batch-in-lanes view with
+        two positions stacked (K = 64) and the channels-in-lanes view; and
+        at the other pools, image sizes and dtype a ``Bottleneck`` model is
+        run at, since no shape falls back to XLA's two passes."""
+        k = shape[-1]
+        text = _compile(
+            lambda y, mean, mul, bias: mercury_kernels.input_moments_pallas(
+                y, mean, mul, bias, dtype),
+            v5e_devices, (shape, dtype), *[((k,), jnp.float32)] * 3)
+        assert text.count("tpu_custom_call") == 1
+
+    def test_synced_unit_checks_vma(self, v5e_devices, mosaic):
+        """A ``Bottleneck`` with ``bn_axis_name`` under a ``shard_map`` that
+        checks varying manual axes, over the four chips: the kernel's sums
+        are typed as varying as its map is, and the ``pmean`` leaves the
+        statistics replicated (off the chip Pallas's interpreter cannot run
+        under that check: ``tests/test_bn_moments.py``)."""
+        from mercury_tpu.compat import shard_map
+        from test_bn_moments import _blocks
+
+        mesh = Mesh(np.array(v5e_devices), ("data",))
+        block, _, x, variables = _blocks(64, False, jnp.bfloat16,
+                                         axis_name="data")
+        shaped = lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec))
+        text = jax.jit(shard_map(
+            lambda v, rows: block.apply(v, rows, mutable=["batch_stats"]),
+            mesh=mesh, in_specs=(P(), P("data")), out_specs=(P("data"), P()),
+            check_vma=True,
+        )).lower(jax.tree.map(lambda a: shaped(a, P()), variables),
+                 shaped(x, P("data"))).compile().as_text()
+        assert text.count("tpu_custom_call") == 1 and "all-reduce" in text
 
 
 # ---------------------------------------------------------------- fused step
@@ -209,13 +266,13 @@ def test_pool_ingest_dense_for_v5e(v5e_devices):
 
 
 # ------------------------------------------------- the scoring forward's bytes
-def _resnet50_shapes(devices, block_cls, rows):
-    """A bf16 ResNet-50 over ``rows`` CIFAR images, as shapes on one v5e
-    device: ``(model, variables, images)``."""
+def _resnet50_shapes(devices, block_cls, rows, dtype=jnp.bfloat16):
+    """A ResNet-50 (bf16 unless told) over ``rows`` CIFAR images, as shapes
+    on one v5e device: ``(model, variables, images)``."""
     from mercury_tpu.models.resnet import ResNet
 
     model = ResNet(stage_sizes=[3, 4, 6, 3], block_cls=block_cls,
-                   num_classes=100, compute_dtype=jnp.bfloat16)
+                   num_classes=100, compute_dtype=dtype)
     sh = NamedSharding(Mesh(np.array(devices[:1]), ("data",)), P())
     variables = jax.eval_shape(
         lambda: model.init(jax.random.key(0), jnp.zeros((2, 32, 32, 3)),
@@ -227,30 +284,131 @@ def _resnet50_shapes(devices, block_cls, rows):
     return model, variables, images
 
 
-def test_pool_forward_writes_the_residual_maps_once_for_v5e(v5e_devices):
-    """The scoring forward at the benchmark cell's pool (2,560 rows, bf16,
-    batch-statistic BN), which nothing differentiates. With the statistic of
-    each Bottleneck's closing BatchNorm taken from the convolution's output
-    the v5e compiler counts 59.36 GB accessed, and the block's output is a
-    ``kLoop`` fusion that reads the raw ``bf16[2560,32,32,256]`` map back
-    (the two heaviest device ops of PERF.md section 5, PR 27); from the
-    input's moments it is the convolution's own epilogue: 49.45 GB."""
+def _scoring_forward(devices, rows, dtype=jnp.bfloat16):
+    """The scoring forward over a pool of ``rows`` (batch-statistic BN,
+    nothing differentiates it), compiled for one v5e with the kernels as
+    Mosaic calls: ``(executable, ENTRY instructions)``, each instruction as
+    ``(name, result type, op, operand names, rest)``."""
     import re
 
     from mercury_tpu.models.resnet import Bottleneck
 
-    model, variables, images = _resnet50_shapes(v5e_devices, Bottleneck, 2560)
-    compiled = jax.jit(
-        lambda v, x: model.apply(v, x, train=True, mutable=["batch_stats"])[0]
-    ).lower(variables, images).compile()
+    model, variables, images = _resnet50_shapes(devices, Bottleneck, rows,
+                                                dtype)
+    with pytest.MonkeyPatch.context() as patch:   # as the ``mosaic`` fixture
+        patch.setattr(mercury_kernels, "on_tpu", lambda: True)
+        compiled = jax.jit(
+            lambda v, x: model.apply(v, x, train=True,
+                                     mutable=["batch_stats"])[0]
+        ).lower(variables, images).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    instructions = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) ([\w-]+)"
+                     r"\((.*?)\)(.*)$", line)
+        if m:
+            name, result, op, operands, rest = m.groups()
+            instructions.append(
+                (name, result, op, re.findall(r"%[\w.\-]+", operands), rest))
+    return compiled, instructions
+
+
+@pytest.fixture(scope="module")
+def pool_forward(v5e_devices):
+    """The scoring forward at the benchmark cell's pool: 2,560 rows, bf16."""
+    return _scoring_forward(v5e_devices, 2560)
+
+
+def _maps_the_kernels_read(instructions, rows=2560, dtype="bf16"):
+    """Sixteen closing units, sixteen Mosaic calls, each handed one ``conv2``
+    raw map through one ``bitcast``: ``(instructions by name, the predicate
+    for a conv2-sized map, the sixteen maps' names)``."""
+    import re
+
+    by_name = {name: (result, op, operands, rest)
+               for name, result, op, operands, rest in instructions}
+    calls = [name for name, (_, op, _, rest) in by_name.items()
+             if op == "custom-call" and "tpu_custom_call" in rest]
+    assert len(calls) == 16, calls
+    is_map = re.compile(r"%s\[(%s)\]" % (dtype, "|".join(
+        ",".join(map(str, (rows,) + shape[1:])) for shape in CONV2_MAPS))
+    ).match
+    raw = []
+    for call in calls:
+        view = by_name[call][2][0]
+        assert by_name[view][1] == "bitcast", by_name[view]
+        (source,) = by_name[view][2]
+        assert is_map(by_name[source][0]), by_name[source]
+        raw.append(source)
+    assert len(set(raw)) == 16
+    return by_name, is_map, raw
+
+
+def test_pool_forward_writes_the_residual_maps_once_for_v5e(pool_forward):
+    """With the statistic of each Bottleneck's closing BatchNorm taken from
+    the convolution's output the v5e compiler counts 59.36 GB accessed, and
+    the block's output is a ``kLoop`` fusion that reads the raw
+    ``bf16[2560,32,32,256]`` map back (the two heaviest device ops of
+    PERF.md section 5, PR 27); from the input's moments it is the
+    convolution's own epilogue: 49.45 GB with the moments as two XLA passes
+    (PR 30), 44.83 GB with them as one kernel call, whose operands the
+    compiler does not count (2.31 GB: PERF.md section 6, PR 32)."""
+    compiled, instructions = pool_forward
     accessed = compiled.cost_analysis()["bytes accessed"]
-    assert accessed < 52e9, accessed
+    assert accessed < 45.5e9, accessed
     # a bitcast moves no bytes; any other loop fusion that writes a stage-1
-    # block output is the second pass this change removes
-    passes = [line for line in compiled.as_text().splitlines()
-              if re.search(r"= bf16\[2560,32,32,256\]\S* fusion\(", line)
-              and "kind=kLoop" in line and "calls=%bitcast_fusion" not in line]
+    # block output is the second pass PR 30 removed
+    passes = [name for name, result, op, _, rest in instructions
+              if result.startswith("bf16[2560,32,32,256]") and op == "fusion"
+              and "kind=kLoop" in rest and "calls=%bitcast_fusion" not in rest]
     assert not passes, passes
+
+
+def test_pool_forward_reads_each_conv2_map_twice_for_v5e(pool_forward):
+    """Sixteen closing units, sixteen Mosaic calls, and each ``conv2`` raw
+    map has two readers: the kernel, through a ``bitcast`` (the operand view
+    is the layout the compiler already holds the map in: batch in the lanes
+    for the 64-wide maps, channels in the lanes for the others), and the
+    closing convolution, with BatchNorm_1's normalise + ReLU as its
+    prologue. No activated copy of a map is written, none is relaid out, and
+    no reduction passes over one outside a convolution's epilogue."""
+    import re
+
+    _, instructions = pool_forward
+    # the sixteen maps the kernels read, each through one bitcast ...
+    by_name, is_map, raw = _maps_the_kernels_read(instructions)
+    # every ENTRY instruction whose result is one conv2-sized map is a
+    # convolution fusion's output: nothing else writes one
+    writers = {(op, re.search(r"kind=(\w+)", rest).group(1)
+                if op == "fusion" else "")
+               for _, (result, op, _, rest) in by_name.items()
+               if is_map(result) and op != "get-tuple-element"}
+    assert writers <= {("fusion", "kOutput")}, writers
+    # ... and their other reader: the closing convolution, and only it
+    for source in raw:
+        readers = sorted(
+            (op, rest) for _, (_, op, operands, rest) in by_name.items()
+            if source in operands)
+        assert [op for op, _ in readers] == ["bitcast", "fusion"], readers
+        assert ("kind=kOutput" in readers[1][1]
+                and "conv_general_dilated" in readers[1][1]), readers[1]
+
+
+@pytest.mark.parametrize("rows,dtype", [(320, jnp.bfloat16),
+                                        (2560, jnp.float32)],
+                         ids=["pool-of-320", "f32"])
+def test_scoring_forward_hands_over_bitcasts_at_other_shapes_for_v5e(
+        v5e_devices, rows, dtype):
+    """The kernel is every non-differentiated ``Bottleneck`` forward's, not
+    the cell's alone: at the reference pool (320 rows: 320 lanes are no
+    multiple of 128) and at f32 the whole forward compiles for the v5e too,
+    and the compiler still holds each ``conv2`` map in the order the kernel
+    takes it in, so no map is relaid out on the way."""
+    _, instructions = _scoring_forward(v5e_devices, rows, dtype)
+    _maps_the_kernels_read(
+        instructions, rows, {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype])
 
 
 def test_differentiated_pass_is_the_plain_forms_for_v5e(v5e_devices):

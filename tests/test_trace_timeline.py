@@ -212,13 +212,34 @@ def traced_fit(traced_events):
 def test_each_fit_reports_its_bn_moment_units(traced_events, traced_fit):
     """Once a call, under its root span: how many conv+BN units of the
     traced step's scoring forward take their statistic from input moments
-    (smallcnn has none; tests/test_bn_moments.py counts ResNet-50's 16)."""
+    (smallcnn has no ``Bottleneck``; the ResNet-50 fit below counts 16)."""
     fits = [e for e in traced_fit if e["name"] == "trainer/fit"]
     marks = [e for e in traced_events
              if e["name"] == "trainer/bn_moment_units"]
     assert [e["args"]["units"] for e in marks] == [0, 0]
     assert [e["args"]["parent"] for e in marks] == [
         e["args"]["id"] for e in fits]
+
+
+@pytest.mark.parametrize("model, counted", [("resnet50", 16), ("resnet18", 0)])
+def test_bn_moment_units_are_the_traced_steps_count(model, counted,
+                                                    monkeypatch):
+    """A ``Bottleneck`` model's ``fit()`` reports what its step counted as
+    it was traced: every closing unit of ResNet-50's scoring forward, none
+    for ResNet-18's ``BasicBlock``s. The step is traced, not run (XLA:CPU
+    would compile it for minutes), and the call's loop is empty."""
+    config = TrainConfig(
+        model=model, dataset="synthetic", world_size=1, batch_size=4,
+        presample_batches=2, log_every=0, eval_every=0, heartbeat_every=0,
+        use_importance_sampling=True, trace=True)
+    with Trainer(config, mesh=host_cpu_mesh(1)) as tr:
+        jax.eval_shape(tr.train_step, tr.state, tr._step_x, tr._step_y,
+                       tr.dataset.shard_indices)
+        monkeypatch.setattr(tr, "_fit", lambda num_epochs: {})
+        tr.fit()
+        (mark,) = [e for e in tr.tracer.snapshot()
+                   if e["name"] == "trainer/bn_moment_units"]
+    assert mark["args"]["units"] == counted
 
 
 def test_fit_has_a_root_span_per_call(traced_fit):
