@@ -50,7 +50,9 @@ products rounded to fp8 (e4m3; the family's file says which), the nearest
 precision below the bfloat16 the configurations state, put in the program's
 place. The limit stands between the two. A number the control hardly moves
 is held against the fault it is there to catch, at about three times the
-sound runs' largest.
+sound runs' largest. Where the configuration sizes the training side's
+blocks (``check.train_block_rows``) the control's one scale per tensor
+spans a block of rows, so its readings are that size's own.
 
 **Not covered.** Which rows the draw picks (replay.py says why); the step
 that primes the pipeline (its batch is in no state); a cell without
@@ -93,11 +95,36 @@ def sample_rows(limits: Dict[str, Any]) -> int:
     return int(limits.get("sample_rows", SAMPLE))
 
 
-def block_rows(limits: Dict[str, Any], n_rows: int) -> int:
-    """Rows the evaluate side's reference hands ``forward`` at a time:
-    ``check.block_rows``, else 250 where that divides the split (one shape,
-    one compile) and 64 where it does not."""
-    return int(limits.get("block_rows", 250 if n_rows % 250 == 0 else 64))
+def block_rows(limits: Dict[str, Any], n_rows: Optional[int] = None) -> int:
+    """Rows of a split (``n_rows`` of them) the evaluate side's reference
+    hands ``forward`` at a time: ``check.block_rows``, else 250 where that
+    divides the split (one shape, one compile) and 64 where it does not.
+    Of the inference check's sample (no ``n_rows``): ``check.block_rows``,
+    else 64."""
+    return int(limits.get("block_rows",
+                          250 if n_rows and n_rows % 250 == 0 else 64))
+
+
+def train_block_rows(limits: Dict[str, Any],
+                     rows_independent: bool) -> Optional[int]:
+    """Rows the training side's reference (the pool's scoring, the batch's
+    gradient) hands ``forward`` at a time: ``check.train_block_rows``. None
+    where the configuration names none: the whole pool and the whole batch
+    in one forward each. Only a family that declares its rows independent
+    (``rows_independent``) may name one."""
+    rows = limits.get("train_block_rows")
+    if rows is None:
+        return None
+    if not rows_independent:
+        raise SystemExit(
+            "perfbench: check.train_block_rows is set, and the "
+            "configuration's family file does not declare ROWS_INDEPENDENT "
+            "= True: a forward that couples the rows of a batch takes the "
+            "whole pool and the whole batch at once")
+    if int(rows) < 1:
+        raise SystemExit(f"perfbench: check.train_block_rows is {rows!r}, "
+                         "want 1 or more")
+    return int(rows)
 
 
 def sample_indices(seed: int, n_test: int, k: int = SAMPLE) -> np.ndarray:

@@ -28,6 +28,13 @@ modules. It offers ``INTERFACE``:
   pass requires, from the whole configuration file; the file's constant is
   held to it.
 
+Beside them it may declare ``ROWS_INDEPENDENT = True``: in training mode
+each row's outputs are a function of that row and the parameters alone (no
+batch statistic, no dropout). Only then may the configuration size the
+training side's blocks (``check.train_block_rows``): the pool is scored and
+the batch differentiated so many rows at a time, and no more than a block's
+outputs and activations ever exist.
+
 Nothing here looks inside ``inputs``, ``outputs`` or ``labels`` beyond
 their leading row axis, and of ``arch`` it reads ``file``, ``sampling`` and
 ``adam`` alone.
@@ -79,7 +86,23 @@ def _load(path: str):
     if missing:
         raise TypeError(f"{path} offers no {', '.join(missing)}: a family's "
                         f"file offers {', '.join(INTERFACE)}")
+    if not isinstance(getattr(module, "ROWS_INDEPENDENT", False), bool):
+        raise TypeError(f"{path}: ROWS_INDEPENDENT is "
+                        f"{module.ROWS_INDEPENDENT!r}, want True or False")
     return module
+
+
+def rows_independent(arch: Mapping[str, Any]) -> bool:
+    """Whether the family's file declares that in training mode a row's
+    outputs depend on that row and the parameters alone."""
+    return getattr(family(arch), "ROWS_INDEPENDENT", False)
+
+
+def _need_independent_rows(arch: Mapping[str, Any]) -> None:
+    if not rows_independent(arch):
+        raise ValueError(f"{arch['file']} does not declare ROWS_INDEPENDENT "
+                         "= True: its training-mode forward takes the whole "
+                         "pool and the whole batch at once")
 
 
 def round_to(x, quantize: Optional[str]):
@@ -137,17 +160,48 @@ def eval_loss(params, model_state, raw_rows, labels,
 
 # ------------------------------------------------------ the training step
 def make_loss_and_grad(arch: Mapping[str, Any],
-                       quantize: Optional[str] = None):
+                       quantize: Optional[str] = None,
+                       block_rows: Optional[int] = None):
     """``(params, inputs, labels, scaled_probs) -> (loss, grads)`` of the
     reweighted training loss ``mean(loss_i / (N p_i))``, the family's
-    forward in training mode over the batch, float32."""
+    forward in training mode over the batch, float32. With ``block_rows``
+    (a family of independent rows) each block of so many rows is
+    differentiated alone, ``sum_i(loss_i / (N p_i))`` over its rows; loss
+    and gradients add up in float32 on the device and are divided by the
+    batch's ``N`` once."""
     fam = family(arch)
 
-    def loss(params, inputs, labels, scaled_probs):
+    def weighted(params, inputs, labels, scaled_probs):
         z = fam.forward(params, None, inputs, arch, quantize)
-        return jnp.mean(fam.example_loss(z, labels) / scaled_probs)
+        return fam.example_loss(z, labels) / scaled_probs
 
-    return jax.jit(jax.value_and_grad(loss))
+    if block_rows is None:
+        def loss(params, inputs, labels, scaled_probs):
+            return jnp.mean(weighted(params, inputs, labels, scaled_probs))
+
+        return jax.jit(jax.value_and_grad(loss))
+    _need_independent_rows(arch)
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def add_block(so_far, params, *block):
+        part = jax.value_and_grad(
+            lambda *a: jnp.sum(weighted(*a)))(params, *block)
+        return jax.tree.map(jnp.add, so_far, part)
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def over(so_far, n):
+        return jax.tree.map(lambda a: a / n, so_far)
+
+    def loss_and_grad(params, inputs, labels, scaled_probs):
+        params = jax.device_put(params)
+        so_far = (jnp.zeros((), jnp.float32),
+                  jax.tree.map(jnp.zeros_like, params))
+        for rows in _blocks(inputs.shape[0], block_rows):
+            so_far = add_block(so_far, params, inputs[rows], labels[rows],
+                               scaled_probs[rows])
+        return over(so_far, jnp.float32(inputs.shape[0]))
+
+    return loss_and_grad
 
 
 def cosine_lr(count: int, peak: float, decay_steps: int) -> float:
@@ -199,11 +253,15 @@ def pool_slots(key, perm, cursor: int, pool_size: int):
 def score_pool(params, step_key, perm, cursor: int, ema_value: float,
                ema_count: int, x_train, y_train, shard_row,
                arch: Mapping[str, Any], pool_size: int,
-               quantize: Optional[str] = None):
+               quantize: Optional[str] = None,
+               block_rows: Optional[int] = None):
     """What one Mercury step makes of its pool, from the state before it:
     ``(inputs [N, ...], labels [N, ...], losses [N], scaled_probs [N] =
     N p)``. ``step_key`` is the state's key; of its 8-way split the first
-    shuffles the stream and the second augments the pool."""
+    shuffles the stream and the second augments the pool. The pool is
+    prepared and augmented whole, under the one key; with ``block_rows``
+    (a family of independent rows) it is then scored so many rows at a
+    time."""
     import numpy as np
 
     fam, sampling = family(arch), arch["sampling"]
@@ -211,17 +269,31 @@ def score_pool(params, step_key, perm, cursor: int, ema_value: float,
     slots = pool_slots(keys[0], perm, cursor, pool_size)
     rows = np.asarray(shard_row)[slots]
     labels = jnp.asarray(np.asarray(y_train)[rows])
+    raw = jnp.asarray(np.asarray(x_train)[rows])
 
-    @jax.jit
-    def run(params, raw, labels, key):
-        inputs = fam.augment(key, fam.prepare(raw, arch), arch)
-        losses = fam.example_loss(
+    def ingest(raw, key):
+        return fam.augment(key, fam.prepare(raw, arch), arch)
+
+    def score(params, inputs, labels):
+        return fam.example_loss(
             fam.forward(params, None, inputs, arch, quantize), labels)
-        return inputs, losses
 
-    inputs, losses = run(params, jnp.asarray(np.asarray(x_train)[rows]),
-                         labels, keys[1])
-    losses = np.asarray(losses, np.float64)
+    if block_rows is None:
+        @jax.jit
+        def run(params, raw, labels, key):
+            inputs = ingest(raw, key)
+            return inputs, score(params, inputs, labels)
+
+        inputs, losses = run(params, raw, labels, keys[1])
+        losses = np.asarray(losses, np.float64)
+    else:
+        _need_independent_rows(arch)
+        inputs, params = jax.jit(ingest)(raw, keys[1]), jax.device_put(params)
+        score = jax.jit(score)
+        losses = np.concatenate(
+            [np.asarray(score(params, inputs[block], labels[block]),
+                        np.float64)
+             for block in _blocks(pool_size, block_rows)])
     return (np.asarray(inputs), np.asarray(labels), losses,
             scaled_probs(losses, ema_value, ema_count, sampling))
 

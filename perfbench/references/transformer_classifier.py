@@ -7,7 +7,9 @@ learned positions, blocks of multi-head softmax attention (separate
 head size) and a GELU MLP (tanh approximation), each added to its input,
 a final LayerNorm, the mean over positions, a dense head. LayerNorm has
 scale and bias and eps 1e-6. No dropout and no running statistic: training
-and inference mode are one, and ``model_state`` is ignored.
+and inference mode are one, ``model_state`` is ignored, and a row's logits
+depend on that row alone (``ROWS_INDEPENDENT``: the training side of the
+reference may go by row blocks, ``check.train_block_rows``).
 
 ``jax.numpy`` in float32 under ``jax.default_matmul_precision("highest")``;
 it reads a parameter tree by the names flax gives the program's modules
@@ -42,6 +44,8 @@ from perfbench.references.resnet import (  # noqa: F401  (class NLL)
     _dense, eval_example_loss, example_loss)
 
 LN_EPS = 1e-6
+#: LayerNorm normalises within a position, attention mixes within a row.
+ROWS_INDEPENDENT = True
 
 
 def _layer_norm(x, p):

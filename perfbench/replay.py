@@ -6,7 +6,8 @@ the window then drives, fed by ``fit()``'s own call — and keeps, after each
 step, what the state shows of it: the drawn batch of the NEXT step (the
 ``pending`` batch of pipelined scoring, read by position: the inputs as
 augmented, the labels, and ``scaled_probs = N p_i``, each ``[W, B, ...]``),
-Adam's first moment, the step's scalars, and at both ends the parameters.
+the step's scalars, after the first two steps Adam's first moment (the
+first replayed gradient is read from it), and at both ends the parameters.
 Step 1 primes the pipeline and trains on a batch no state ever shows, so
 the replay starts from the state after it.
 
@@ -100,7 +101,9 @@ class Recorder:
         kept = dict(
             metrics={k: float(v) for k, v in _host(metrics).items()
                      if np.ndim(v) == 0},
-            mu=_host(adam.mu), pending=_host(state.pending))
+            pending=_host(state.pending))
+        if len(self.steps) < 2:     # the first gradient: mu before and after
+            kept.update(mu=_host(adam.mu))
         if first:
             kept.update(nu=_host(adam.nu), count=int(adam.count),
                         stream=_host(state.stream), rng=_host(state.rng),
@@ -177,9 +180,12 @@ def system_steps(steps, arch) -> Dict[str, Any]:
         change=_diff(steps[STEPS]["params"], steps[0]["params"]))
 
 
-def reference_steps(steps, arch, fields, quantize=None) -> Dict[str, Any]:
+def reference_steps(steps, arch, fields, quantize=None,
+                    train_block_rows=None) -> Dict[str, Any]:
     """The same of the reference, which follows the recorded batches on
-    its own trajectory from the state after the priming step."""
+    its own trajectory from the state after the priming step
+    (``train_block_rows`` rows of a batch at a time, where the
+    configuration says so)."""
     import jax
 
     start, adam = steps[0], arch["adam"]
@@ -189,7 +195,8 @@ def reference_steps(steps, arch, fields, quantize=None) -> Dict[str, Any]:
                                   "batch statistics")
     peak_lr = float(fields["base_lr"]) * world
     decay = int(fields["steps_per_epoch"]) * int(fields["num_epochs"])
-    loss_and_grad = reference.make_loss_and_grad(arch, quantize)
+    loss_and_grad = reference.make_loss_and_grad(arch, quantize,
+                                                 train_block_rows)
     flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731  [W,B]->[WB]
     params, mu, nu, count = (start["params"], start["mu"], start["nu"],
                              start["count"])
@@ -220,7 +227,8 @@ def step_gaps(system, ref) -> Dict[str, float]:
         update_norm_gap=worst_leaf_gap(system["change"], ref["change"]))
 
 
-def reference_weights(steps, dataset, arch, fields, quantize=None):
+def reference_weights(steps, dataset, arch, fields, quantize=None,
+                      train_block_rows=None):
     """``N p_i`` of the rows the program drew in the first replayed step:
     its pool rebuilt from the state before it and scored by the reference
     with the program's parameters. None where a drawn row is not in the
@@ -235,7 +243,8 @@ def reference_weights(steps, dataset, arch, fields, quantize=None):
         jax.random.wrap_key_data(jax.numpy.asarray(start["rng"][0])),
         start["stream"].perm[0], int(start["stream"].cursor[0]),
         float(start["ema"].value[0]), int(start["ema"].count[0]),
-        x_train, y_train, shard_indices[0], arch, pool_size, quantize)
+        x_train, y_train, shard_indices[0], arch, pool_size, quantize,
+        train_block_rows)
     at = _match_rows(drawn[INPUTS][0], inputs)
     found = at >= 0
     print(f"[perfbench] replay pool: {int(found.sum())} of {len(at)} drawn "
@@ -254,35 +263,41 @@ def weight_gap(system, ref) -> float:
 
 
 def compare(steps: List[Dict[str, Any]], dataset, arch: Dict[str, Any],
-            fields: Dict[str, Any], control: Optional[str] = None):
+            fields: Dict[str, Any], control: Optional[str] = None,
+            train_block_rows: Optional[int] = None):
     """The replay's numbers from a ``Recorder``'s steps: the program
     against the reference. ``dataset`` is ``(x_train, y_train,
     shard_indices)`` on the host, rows as ``trainer.dataset`` holds them;
     ``fields`` the job's ``TrainConfig`` fields (learning rate, schedule
-    length, batch and pool). With
-    ``control`` (a lower precision) returns a second dict as well: the
-    reference in that precision, put in the program's place."""
+    length, batch and pool); ``train_block_rows`` the rows the reference
+    scores and differentiates at a time (``check.train_block_rows``; None:
+    the whole pool and the whole batch). With ``control`` (a lower
+    precision) returns a second dict as well: the reference in that
+    precision, put in the program's place."""
     if len(steps) != STEPS + 1:
         raise ValueError(f"recorded {len(steps)} steps, want {STEPS + 1}")
     if steps[0]["pending"] is None:
         raise NotImplementedError(
             "the replay reads the drawn batch from the state's pending "
             "batch: the cell needs pipelined_scoring")
-    system, ref = system_steps(steps, arch), reference_steps(steps, arch,
-                                                             fields)
+    system = system_steps(steps, arch)
+    ref = reference_steps(steps, arch, fields, None, train_block_rows)
     for i, (a, b) in enumerate(zip(system["losses"], ref["losses"])):
         print(f"[perfbench] replay step {i + 2}: train/loss {a!r} "
               f"reference {b!r}", flush=True)
     out = step_gaps(system, ref)
-    lower = (step_gaps(reference_steps(steps, arch, fields, control), ref)
+    lower = (step_gaps(reference_steps(steps, arch, fields, control,
+                                       train_block_rows), ref)
              if control else None)
     if int(fields["world_size"]) == 1:
-        weights = reference_weights(steps, dataset, arch, fields)
+        weights = reference_weights(steps, dataset, arch, fields, None,
+                                    train_block_rows)
         out["weight_gap"] = weight_gap(
             steps[1]["pending"][SCALED_PROBS][0], weights)
         if control:
             lower["weight_gap"] = weight_gap(reference_weights(
-                steps, dataset, arch, fields, control), weights)
+                steps, dataset, arch, fields, control, train_block_rows),
+                weights)
     else:
         print("[perfbench] replay pool: not rebuilt across workers",
               flush=True)

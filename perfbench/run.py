@@ -125,6 +125,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     cache_dir = configure_compile_cache()
     cell = Cell(workload, rehearsal)
+    limits, arch = cell.config["check"], cell.config["reference"]
+    train_block = check.train_block_rows(limits,
+                                         reference.rows_independent(arch))
     device = find_devices(cell.chips, rehearsal is not None)
     fields = cell.train_config_fields(seed, trace)
     steps_per_call = cell.steps_per_call
@@ -173,7 +176,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         x_test, y_test = np.asarray(ds.x_test), np.asarray(ds.y_test)
         train_split = (np.asarray(ds.x_train), np.asarray(ds.y_train),
                        np.asarray(ds.shard_indices))
-        limits = cell.config["check"]
         idx = check.sample_indices(seed, x_test.shape[0],
                                    check.sample_rows(limits))
         system_outputs = trainer.predict(x_test[idx])
@@ -229,13 +231,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     # ------------------------------------------------- the reference check
     t_ref = time.perf_counter()
-    arch = cell.config["reference"]
     block = check.block_rows(limits, x_test.shape[0])
 
     def ref_side(quantize=None):
         """(outputs of the sample at the warm weights, loss over the whole
         test split at the final weights) by the plain reference."""
-        sample = reference.outputs(*warm_weights, x_test[idx], arch, quantize)
+        sample = reference.outputs(*warm_weights, x_test[idx], arch, quantize,
+                                   block_rows=check.block_rows(limits))
         return sample, reference.eval_loss(*final_weights, x_test, y_test,
                                            arch, quantize, block_rows=block)
 
@@ -243,7 +245,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     say(f"reference: inference and evaluate sides took "
         f"{time.perf_counter() - t_ref:.2f} s")
     replayed = replay.compare(recorder.steps, train_split, arch, fields,
-                              "fp8" if control else None)
+                              "fp8" if control else None, train_block)
     if control:
         replayed, replayed_lower = replayed
     numbers = check.numbers(

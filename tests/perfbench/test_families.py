@@ -118,13 +118,10 @@ def test_the_test_of_shared_code_sees_what_it_should():
 
 
 # ------------------------------------ the evaluate side, one block at a time
-def test_the_evaluate_side_hands_forward_one_block_at_a_time(monkeypatch):
-    """No array of a split's outputs is ever built: ``forward`` sees
-    ``block_rows`` rows at most, and the mean is the float64 mean of the
-    per-example values."""
-    arch = dict(TOKENS)
+def _spy_on_forward(monkeypatch, arch):
+    """The leading sizes the family's ``forward`` is handed from here on
+    (under ``jit``: once a traced shape)."""
     fam = reference.family(arch)
-    params, x, y = _token_data(n=23)
     seen, real = [], fam.forward
 
     def spy(params, model_state, inputs, arch, quantize=None):
@@ -132,6 +129,18 @@ def test_the_evaluate_side_hands_forward_one_block_at_a_time(monkeypatch):
         return real(params, model_state, inputs, arch, quantize)
 
     monkeypatch.setattr(fam, "forward", spy)
+    return seen
+
+
+def test_the_evaluate_side_hands_forward_one_block_at_a_time(monkeypatch):
+    """No array of a split's outputs is ever built: ``forward`` sees
+    ``block_rows`` rows at most, and the mean is the float64 mean of the
+    per-example values."""
+    arch = dict(TOKENS)
+    fam = reference.family(arch)
+    params, x, y = _token_data(n=23)
+    real = fam.forward
+    seen = _spy_on_forward(monkeypatch, arch)
     got = reference.eval_loss(params, None, x, y, arch, block_rows=5)
     assert seen and max(seen) <= 5 and 3 in seen  # 23 = 4 x 5 + 3
     want = np.asarray(fam.example_loss(real(params, None, x, arch), y),

@@ -4,8 +4,10 @@ sequence out. The inputs are ``int32 [N, T]`` tokens, the labels ``int32
 head (``[N, T, V]`` float32 logits), the per-example loss the mean over
 ``T`` of the token cross-entropy. No program stands behind it: it shows
 that the shared functions of ``perfbench/reference.py``, ``replay.py`` and
-``check.py`` take such a family as they are. The fp8 control rounds both
-operands of the head's product.
+``check.py`` take such a family as they are. A row's logits depend on that
+row alone (``ROWS_INDEPENDENT``), so the training side may go by row
+blocks. The fp8 control rounds both operands of the head's product (one
+scale per tensor: over a block, where the training side goes by blocks).
 """
 
 import jax
@@ -14,6 +16,8 @@ import numpy as np
 from jax import lax
 
 from perfbench.reference import round_to
+
+ROWS_INDEPENDENT = True
 
 
 def prepare(raw_rows, arch):
