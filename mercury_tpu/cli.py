@@ -76,6 +76,9 @@ def parse_config(argv: Optional[Sequence[str]] = None) -> tuple[TrainConfig, arg
         if (isinstance(value, str) and value.lower() in ("none", "")
                 and "Optional" in ftype):
             value = None
+        # Tuples of ints (model_cut) arrive as "4,0,8".
+        if isinstance(value, str) and "Tuple[int" in ftype:
+            value = tuple(int(v) for v in value.split(","))
         # Optional[bool] fields (e.g. use_pallas) arrive as strings; a bare
         # string "false" would be truthy downstream.
         if (isinstance(value, str) and "bool" in ftype

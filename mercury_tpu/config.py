@@ -9,7 +9,7 @@ that can be serialized into run names and checkpoints.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,6 +28,13 @@ class TrainConfig:
     num_classes: Optional[int] = None  # None → derived from dataset; set → validated
     image_size: int = 32             # ingest resize for dataset="imagefolder";
                                      # array datasets carry their own shapes
+    # Token models and datasets (models/decoder.py, data/tokens.py). The
+    # model's name selects the published widths; what a chip holds of it is
+    # the cut: (layers kept, first expert held, experts held), None = whole.
+    # The vocabulary rows held are ``num_classes`` (None: the dataset's own
+    # slice); ``seq_len`` is the length of the token dataset's sequences.
+    model_cut: Optional[Tuple[int, int, int]] = None
+    seq_len: int = 8192
 
     # Parallelism -----------------------------------------------------------
     world_size: int = 4              # number of data-parallel workers (mesh size)

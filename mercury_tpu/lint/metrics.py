@@ -44,7 +44,7 @@ from typing import Dict, List, Set, Tuple
 #: key and checked against the registry.
 KEY_RE = re.compile(
     r"^(train|test|sampler|sampler_dist|perf|time|data|obs|anomaly|host"
-    r"|prof|scorer|threads|lint|fault|supervisor|checkpoint|plan)"
+    r"|prof|scorer|threads|lint|fault|supervisor|checkpoint|plan|moe)"
     r"/[a-z0-9_]+(/[a-z0-9_]+)?$")
 
 #: Backticked tokens in the docs, brace families included

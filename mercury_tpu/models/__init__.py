@@ -22,6 +22,10 @@ from mercury_tpu.models.resnet import (  # noqa: F401
     ResNet101,
     ResNet152,
 )
+from mercury_tpu.models.decoder import (  # noqa: F401
+    LM_WIDTHS,
+    CausalDecoder,
+)
 from mercury_tpu.models.moe import MoEMLP  # noqa: F401
 from mercury_tpu.models.simple import SmallCNN  # noqa: F401
 from mercury_tpu.models.transformer import (  # noqa: F401
@@ -53,7 +57,10 @@ def create_model(
     """Build a model by name.
 
     Names: ``resnet18/34/50/101/152``, ``vgg11/13/16/19``, ``mobilenetv2``,
-    ``bilstm_attention``, ``transformer``, ``vit``. ``bn_axis_name`` enables
+    ``bilstm_attention``, ``transformer``, ``vit``, and the causal decoders
+    of ``LM_WIDTHS`` (``smallthinker-21b-a3b``: the name selects the
+    published widths, ``num_classes`` the vocabulary rows held, ``cut`` the
+    layers and experts held). ``bn_axis_name`` enables
     cross-replica synced BatchNorm over the given mesh axis (ignored by
     models without BN).
     """
@@ -93,4 +100,7 @@ def create_model(
             kwargs.setdefault("max_len", (32 // kwargs["patch_size"]) ** 2)
         return TransformerClassifier(num_classes=num_classes, compute_dtype=cd,
                                      param_dtype=pd, **kwargs)
+    if name in LM_WIDTHS:
+        return CausalDecoder(num_classes=num_classes, widths=LM_WIDTHS[name],
+                             compute_dtype=cd, param_dtype=pd, **kwargs)
     raise ValueError(f"unknown model {name!r}")

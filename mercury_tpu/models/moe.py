@@ -183,3 +183,121 @@ class MoEMLP(nn.Module):
         y = jnp.einsum("ne,end->nd", onehot.astype(all_out.dtype), all_out)
         y = y * gate_val[:, None].astype(y.dtype)
         return y.reshape(orig_shape).astype(x.dtype), aux
+
+
+# ----------------------------------------------- top-k over a share, no drops
+#: The flax collection a model sows its last routed layer's ``load`` into
+#: (:func:`routed_experts`): ``held_pair_share``, ``load_max_over_mean``.
+MOE_LOAD = "moe_load"
+
+
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    """``x[perm]`` for a permutation of the rows: its transpose is the
+    gather by the inverse, not a scatter."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inverse):
+    return x[perm], inverse
+
+
+def _permute_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+@jax.custom_vjp
+def _rows_of_pairs(h, order, inverse):
+    """A row of ``h [T, D]`` for each of the ``k * T`` (choice, token)
+    pairs in sorted order (pair ``p`` is token ``p % T``); its transpose
+    sums each token's ``k`` rows, gathered by the inverse."""
+    return h[order % h.shape[0]]
+
+
+def _rows_of_pairs_fwd(h, order, inverse):
+    return h[order % h.shape[0]], (inverse, h.shape[0])
+
+
+def _rows_of_pairs_bwd(res, g):
+    inverse, t = res
+    return jnp.sum(g[inverse].reshape(-1, t, g.shape[-1]), axis=0), None, None
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
+def route_top_k(router_logits, top_k: int, first_expert: int, held: int):
+    """Top-k routing of ``[T, E]`` float32 router logits over ALL ``E``
+    experts, for a layer that holds experts ``first_expert ..
+    first_expert + held - 1``: ``(weights [T, k], slot [k * T], order
+    [k * T], group_sizes [held], is_held [T, k])``. The weights are the
+    softmax over the ``k`` chosen logits (ties to the lower index). The
+    ``k * T`` (choice, token) pairs, choice-major (pair ``c * T + t`` is
+    token ``t``'s ``c``-th choice: a ``[k, T, D]`` array of their rows has
+    whole tiles, which ``[T, k, D]`` at ``k = 6`` has not), are sorted by
+    expert with the pairs of experts held elsewhere last: ``order[s]`` is
+    the pair in sorted slot ``s``, ``slot`` its inverse, ``group_sizes``
+    the pairs of each held expert. Nothing has a capacity: every pair has
+    a slot."""
+    with jax.named_scope("mercury_moe_route"):
+        logits, chosen = lax.top_k(router_logits, top_k)
+        weights = jax.nn.softmax(logits, axis=-1)
+        local = chosen - first_expert
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held).T.reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        # the inverse of a permutation by a second sort: a scatter of
+        # single elements is the slow way on the TPU
+        slot = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        return weights, slot, order, group_sizes, is_held
+
+
+def routed_experts(h, router_logits, gate, up, down, top_k: int,
+                   first_expert: int = 0):
+    """Top-k routed ReGLU experts over the share of them held here, with no
+    capacity and no dropped token, whatever the imbalance: ``h [T, D]``,
+    ``router_logits [T, E]`` float32 over all ``E`` experts, ``gate`` /
+    ``up`` ``[held, D, F]`` and ``down`` ``[held, F, D]`` the held experts'
+    weights. Every token is routed over all ``E``; the (token, expert)
+    pairs whose expert is held are grouped by expert and go through three
+    grouped matrix products (``lax.ragged_dot``; pairs of experts held
+    elsewhere lie past the last group and are not computed); the outputs
+    return to their tokens weighted. On one chip there is no exchange.
+    Returns ``(y [T, D] float32, load)`` with ``load = (held_pair_share,
+    load_max_over_mean)``: the share of pairs that fell on held experts
+    (``held / E`` at uniform routing) and the pairs of the busiest held
+    expert over the mean."""
+    t, d = h.shape
+    weights, slot, order, group_sizes, is_held = route_top_k(
+        router_logits, top_k, first_expert, gate.shape[0])
+    with jax.named_scope("mercury_moe_route"):
+        # A row of h for every pair, in sorted order: [k * T, D]. The rows
+        # past the last group are no product's business, forward or
+        # backward: the select keeps what a kernel leaves there out of h's
+        # gradient.
+        in_a_group = (jnp.arange(t * top_k, dtype=jnp.int32)
+                      < jnp.sum(group_sizes))[:, None]
+        pairs = jnp.where(in_a_group, _rows_of_pairs(h, order, slot), 0)
+
+    def grouped(x, w):
+        return lax.ragged_dot(x, w, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+    hidden = (jax.nn.relu(grouped(pairs, gate))
+              * grouped(pairs, up)).astype(h.dtype)
+    out = grouped(hidden, down).astype(h.dtype)
+    with jax.named_scope("mercury_moe_route"):
+        back = _permute(out, slot, order).reshape(top_k, t, d)
+        # rows past the last group were never written: select, not scale
+        back = jnp.where(is_held.T[..., None], back, 0)
+        y = jnp.sum(back * weights.T[..., None], axis=0)
+        sizes = group_sizes.astype(jnp.float32)
+        load = (jnp.sum(sizes) / (t * top_k),
+                jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9))
+    return y, load

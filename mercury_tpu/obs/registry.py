@@ -25,6 +25,12 @@ METRIC_KEYS: Dict[str, str] = {
     "train/sparse_rate": "gradient-compression sparsity (0 when off)",
     "train/moe_aux": "MoE load-balancing aux loss (0 when off)",
     "train/grad_norm": "global L2 norm of the post-allreduce gradient",
+    # moe/* — routing of the last layer of routed experts, train pass
+    # (a causal decoder, models/decoder.py; no other model emits them)
+    "moe/held_pair_share":
+        "share of the (token, expert) pairs that fell on experts held here",
+    "moe/load_max_over_mean":
+        "pairs of the busiest held expert over the mean of the held ones",
     "train/eval_loss": "train-split eval loss (inference mode)",
     "train/eval_acc": "train-split eval accuracy (inference mode)",
     # test/* — eval pass over the held-out split

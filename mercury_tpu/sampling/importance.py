@@ -78,6 +78,44 @@ def per_sample_loss(
     return nll
 
 
+def sequence_loss(hidden: jax.Array, head: jax.Array,
+                  labels: jax.Array) -> jax.Array:
+    """The next-token loss of ONE sequence and its share of positions
+    predicted right, ``[2]`` float32: ``hidden [T, D]`` the final hidden
+    states, ``head [D, V]`` the output projection, ``labels [T]`` the ids
+    that follow. The loss is the mean over the ``T`` positions of the token
+    negative log-likelihood over the ``V`` rows, from float32 logits."""
+    with jax.named_scope("mercury_lm_head"):
+        logits = jnp.dot(hidden, head, preferred_element_type=jnp.float32)
+        nll = (jax.nn.logsumexp(logits, axis=-1)
+               - jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0])
+        hit = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+        return jnp.stack([jnp.mean(nll), jnp.mean(hit)])
+
+
+def sequence_rows(outputs, labels: jax.Array) -> jax.Array:
+    """Rows of per-token labels reduced to the unit that is scored and
+    drawn, a sequence: ``outputs`` is what a model of per-token logits
+    returns, ``(hidden [n, T, D], head [D, V])`` (``models/decoder.py``),
+    ``labels [n, T]``; ``[n, 2]`` float32, a row's :func:`sequence_loss`
+    and hit share. The head's product and the token loss run a row at a
+    time (``lax.map``), each under ``jax.checkpoint``: no more than one
+    row's ``[T, V]`` logits ever exist, forward or backward."""
+    hidden, head = outputs
+    return jax.lax.map(
+        lambda row: jax.checkpoint(sequence_loss)(row[0], head, row[1]),
+        (hidden, labels))
+
+
+def token_logits(outputs) -> jax.Array:
+    """The whole ``[n, T, V]`` float32 logits of ``outputs`` (as
+    :func:`sequence_rows` takes them): for the few rows ``predict`` is
+    given."""
+    hidden, head = outputs
+    with jax.named_scope("mercury_lm_head"):
+        return jnp.dot(hidden, head, preferred_element_type=jnp.float32)
+
+
 def per_sample_grad_norm_bound(
     logits: jax.Array, labels: jax.Array, label_smoothing: float = 0.0
 ) -> jax.Array:

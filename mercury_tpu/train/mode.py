@@ -18,6 +18,7 @@ from typing import Any, Dict, Mapping, Optional, Tuple
 import numpy as np
 
 from mercury_tpu.config import TrainConfig
+from mercury_tpu.data.tokens import TOKEN_DATASETS
 
 #: The sampler ladder, one closed set. ``uniform``: the baseline; ``pool``
 #: scores a fresh candidate pool every step; ``pipelined`` trains on the batch
@@ -65,6 +66,7 @@ class StepMode:
     fused_input: bool
     scoring_bf16: bool
     moe_aux_weight: Optional[float]   # None: the model sows no MoE loss
+    token_rows: bool              # rows of token ids, per-token labels
 
     # ------------------------------------------------------------ the kind
     @property
@@ -141,7 +143,12 @@ class StepMode:
         — uint8 rows under the noniid crop/flip, one dense pass over the
         raw bytes (``data.pipeline.select_crop_flip``) — or ``"chain"`` —
         ``normalize_images`` then the augmentation (float inputs, ``iid``,
-        ``none``, cutout). Read off what the step sees; no field picks it."""
+        ``none``, cutout) — or ``"tokens"``: rows of integer ids (a token
+        dataset), which are the model's inputs as they are: no statistic
+        to normalise by, and ``Trainer`` has held ``augmentation`` to
+        ``"none"``. Read off what the step sees; no field picks it."""
+        if np.issubdtype(np.dtype(dtype), np.signedinteger):
+            return "tokens"
         select = (np.dtype(dtype) == np.uint8
                   and self.augmentation == "noniid" and not self.cutout)
         return "select" if select else "chain"
@@ -310,6 +317,20 @@ class StepMode:
             raise ValueError(
                 f"unknown importance_score {config.importance_score!r}"
             )
+        # A token dataset's rows carry per-token labels: the loss seam
+        # reduces each to its mean token loss (stages.row_loss_and_score).
+        token_rows = config.dataset in TOKEN_DATASETS
+        if token_rows and (
+                config.sampler != "pool" or config.label_smoothing
+                or config.importance_score != "loss"
+                or config.data_placement == "host_stream"
+                or (telemetry and config.variance_probe_every)):
+            raise ValueError(
+                "rows of per-token labels train under sampler='pool' "
+                "(uniform, pool, pipelined or cadence) scored by their "
+                "loss, device-resident, without label smoothing and "
+                "without the variance probe: the other paths read one "
+                "class label or whole logits a row")
         probe_every = int(config.variance_probe_every)
         if probe_every < 0:
             raise ValueError(
@@ -416,4 +437,5 @@ class StepMode:
             scoring_bf16=config.scoring_dtype == "bfloat16",
             moe_aux_weight=(config.moe_aux_weight
                             if config.moe_experts is not None else None),
+            token_rows=token_rows,
         )

@@ -508,7 +508,7 @@ def rescore_trained(ctx: StepContext, drawn: Drawn, logits, ema, tel):
     pass anyway); with-replacement duplicates average. Returns the table's
     scores after it, the EMA and the telemetry pair."""
     mode = ctx.mode
-    train_scores = ctx.score_per_sample(
+    train_scores = ctx.rows.score(
         logits.astype(jnp.float32), drawn.labels)
     if mode.async_refresh:
         # With no refresh forward, the EMA mean (decay target, smoothing

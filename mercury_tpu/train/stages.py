@@ -8,7 +8,19 @@ over a ``TrainConfig``. The named scopes opened here (``mercury_pool_ingest``
 ``mercury_scoring``; ``mercury_draw``; ``mercury_augmentation`` /
 ``mercury_input_fuse``; ``mercury_train``, ``mercury_grad_sync``,
 ``mercury_optimizer``, ``mercury_variance_probe``) are what the per-layer
-metrics, ``lint/audit.py`` and ``obs/profile_parse.py`` key on.
+metrics, ``lint/audit.py`` and ``obs/profile_parse.py`` key on. A model of
+per-token logits opens its own inside the forward scopes
+(``models/decoder.py``: ``mercury_attention``, ``mercury_moe`` with
+``mercury_moe_route``, ``mercury_lm_head``).
+
+**The loss seam** (:func:`row_loss_and_score`, chosen once from the mode) is
+what the stages read a forward's outputs through: ``reduce`` what the model
+returned to what is carried of it, then ``loss``, ``score`` and ``hits`` a
+row. One class label a row: the logits are carried whole. Rows of per-token
+labels (``StepMode.token_rows``): the model returns hidden states and its
+head, and ``reduce`` is ``sampling.importance.sequence_rows`` (head and token
+loss a row at a time, so that no more than a row's logits exist): ``[n, 2]``,
+a row's loss, which is its score too, and its hit share.
 """
 
 from __future__ import annotations
@@ -27,6 +39,7 @@ from mercury_tpu.data.pipeline import (
     augment_normalize,
     normalize_images,
 )
+from mercury_tpu.models.moe import MOE_LOAD
 from mercury_tpu.models.resnet import MOMENT_UNITS
 from mercury_tpu.obs.diagnostics import global_grad_norm
 from mercury_tpu.parallel import collectives as coll
@@ -37,6 +50,7 @@ from mercury_tpu.sampling.importance import (
     pool_mean,
     reweighted_loss,
     select_from_pool,
+    sequence_rows,
 )
 from mercury_tpu.train.mode import StepMode
 from mercury_tpu.utils.quantize import sparsity, stochastic_quantize
@@ -49,6 +63,16 @@ from mercury_tpu.utils.tree import (
 RowFn = Callable[[jax.Array, jax.Array], jax.Array]
 
 
+class RowFns(NamedTuple):
+    """The loss seam: how the stages read a forward's outputs, by row."""
+
+    reduce: Callable[[Any, Optional[jax.Array]], jax.Array]
+    #                               (model outputs, labels) -> what is carried
+    loss: RowFn                   # (carried, labels) -> [n] training loss
+    score: RowFn                  # (carried, labels) -> [n] importance score
+    hits: RowFn                   # (carried, labels) -> [n] predicted right
+
+
 class StepContext(NamedTuple):
     """Everything a stage may read besides its array arguments."""
 
@@ -59,30 +83,50 @@ class StepContext(NamedTuple):
     mean: np.ndarray
     std: np.ndarray
     image_shape: Optional[Tuple[int, int, int]]   # flat uint8 rows' (H, W, C)
-    loss_per_sample: RowFn        # (logits, labels) -> [n] training loss
-    score_per_sample: RowFn       # (logits, labels) -> [n] importance score
+    rows: RowFns                  # the loss seam (row_loss_and_score)
     param_specs: Any              # per-leaf specs of pinned params, or None
     trace_facts: Optional[Dict[str, int]]
 
 
-def row_loss_and_score(mode: StepMode) -> Tuple[RowFn, RowFn]:
-    """The per-row training loss and the candidate scorer, built once from
-    the mode and handed to the score and update stages. Training losses
-    always use the first — the IS reweighting is score-agnostic, so any
-    scorer stays unbiased."""
-    if mode.use_pallas:
+def row_fns(token_rows: bool = False, use_pallas: bool = False,
+            label_smoothing: float = 0.0,
+            importance_score: str = "loss") -> RowFns:
+    """The loss seam of rows of per-token labels (``token_rows``: loss =
+    score = the mean over a sequence's positions of the token negative
+    log-likelihood, hits the share of positions predicted right) or of one
+    class label a row (cross-entropy, by the Pallas kernel under
+    ``use_pallas``; scored by the loss or by the gradient-norm bound)."""
+    if token_rows:
+        def column(i):
+            return lambda carried, labels: carried[:, i]
+
+        return RowFns(sequence_rows, column(0), column(0), column(1))
+
+    if use_pallas:
         from mercury_tpu.ops import per_sample_nll_pallas as loss
     else:
         def loss(logits, labels):
-            return per_sample_loss(logits, labels, mode.label_smoothing)
+            return per_sample_loss(logits, labels, label_smoothing)
 
-    if mode.importance_score == "grad_norm":
+    if importance_score == "grad_norm":
         def score(logits, labels):
             return per_sample_grad_norm_bound(
-                logits, labels, mode.label_smoothing)
+                logits, labels, label_smoothing)
     else:
         score = loss
-    return loss, score
+
+    def hits(logits, labels):
+        return (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
+
+    return RowFns(lambda outputs, labels: outputs, loss, score, hits)
+
+
+def row_loss_and_score(mode: StepMode) -> RowFns:
+    """The loss seam, built once from the mode and handed to the score and
+    update stages. Training losses always use ``loss`` — the IS reweighting
+    is score-agnostic, so any scorer stays unbiased."""
+    return row_fns(mode.token_rows, mode.use_pallas, mode.label_smoothing,
+                   mode.importance_score)
 
 
 def pool_loss_metric(ctx: StepContext, pool_logits, labels, score_avg):
@@ -91,7 +135,7 @@ def pool_loss_metric(ctx: StepContext, pool_logits, labels, score_avg):
     statistic — that's the selection math); comparing pool-loss curves
     across score modes must compare the same quantity."""
     if ctx.mode.importance_score == "grad_norm":
-        return pool_mean(ctx.loss_per_sample(pool_logits, labels),
+        return pool_mean(ctx.rows.loss(pool_logits, labels),
                          ctx.mode.stat_axis)
     return score_avg
 
@@ -104,39 +148,53 @@ def _note_moment_units(ctx: StepContext, model_state) -> None:
             jax.tree_util.tree_leaves(model_state.get(MOMENT_UNITS, {})))
 
 
-def _apply(module, params, batch_stats, images, moment_units: bool):
+def _apply(ctx: StepContext, module, params, batch_stats, images,
+           moment_units: bool, labels=None):
     """Train-mode ``module.apply`` with the collections it may write:
-    ``(logits, written)``. ``moment_units``: a forward that nothing
+    ``(logits, written)``, the logits as the loss seam carries them
+    (``ctx.rows.reduce``). ``moment_units``: a forward that nothing
     differentiates lets the closing units take their batch statistic from
     input moments (``models/resnet.py::_closing_unit``)."""
-    variables, mutable = {"params": params}, ["losses"]
+    variables, mutable = {"params": params}, ["losses", MOE_LOAD]
     if batch_stats:
         variables["batch_stats"] = batch_stats
         mutable.append("batch_stats")
     if moment_units:
         mutable.append(MOMENT_UNITS)
-    return module.apply(variables, images, train=True, mutable=mutable)
+    outputs, written = module.apply(variables, images, train=True,
+                                    mutable=mutable)
+    return ctx.rows.reduce(outputs, labels), written
+
+
+def moe_load(model_state) -> Dict[str, jax.Array]:
+    """What a model of routed experts sowed of its last such layer's
+    routing (``models/decoder.py``): ``{"held_pair_share": ...,
+    "load_max_over_mean": ...}``, empty for every other model."""
+    return {name: value[-1] for name, value in
+            model_state.get(MOE_LOAD, {}).items()}
 
 
 def apply_train(ctx: StepContext, params, batch_stats, images,
-                keep_stats: bool):
+                keep_stats: bool, labels=None):
     """Train-mode forward. ``keep_stats=False`` (the scoring pass) uses
     batch statistics for normalization but discards the running-stat
     update — the clean version of the reference's quirk where
     ``update_samples``'s no_grad forwards still mutate BN running means
     (``pytorch_collab.py:101`` runs the net in train mode).
 
-    Returns ``(logits, new_stats, aux)`` where ``aux`` is the sum of
+    Returns ``(logits, new_stats, aux, load)`` where ``aux`` is the sum of
     any sowed ``"losses"`` collection entries (the MoE router's
-    load-balancing loss; 0.0 for models that sow nothing)."""
+    load-balancing loss; 0.0 for models that sow nothing) and ``load``
+    is :func:`moe_load`'s dict."""
     logits, new_model_state = _apply(
-        ctx.model, params, batch_stats, images, moment_units=not keep_stats)
+        ctx, ctx.model, params, batch_stats, images,
+        moment_units=not keep_stats, labels=labels)
     if not keep_stats:
         _note_moment_units(ctx, new_model_state)
     aux = sum_sowed_losses(new_model_state)
     keep = batch_stats and keep_stats
     return (logits, new_model_state["batch_stats"] if keep else batch_stats,
-            aux)
+            aux, moe_load(new_model_state))
 
 
 def augment(mode: StepMode, key, images):
@@ -163,10 +221,13 @@ def ingest(ctx: StepContext, key, raw, out_dtype=None):
     raw bytes (``data.pipeline.augment_normalize``: crop and flip as exact
     selection, normalize last) under ``mercury_augmentation`` —
     ``mercury_input_fuse`` with ``fused_input``; float inputs, ``iid`` and
-    cutout keep the ``normalize_images`` + :func:`augment` chain. Both consume
+    cutout keep the ``normalize_images`` + :func:`augment` chain; rows of
+    integer ids (a token dataset) are the model's inputs as they are. Both consume
     ``key`` identically and agree bit for bit at f32 (tests/test_ops.py).
     ``out_dtype`` (the bf16 scoring ingest) is the LAST op on both paths."""
     mode = ctx.mode
+    if mode.ingest_path(raw.dtype) == "tokens":
+        return raw
     if mode.fused_input and raw.dtype != jnp.uint8:
         raise ValueError(
             "fused_input ingests raw uint8 rows (the chain owns "
@@ -246,8 +307,8 @@ def score_rows(ctx: StepContext, state, raw, labs, ka, reuse_images=True):
         )
     if scoring_model is None:
         with jax.named_scope("mercury_score_forward"):
-            pool_logits, _, _ = apply_train(
-                ctx, state.params, state.batch_stats, imgs, False
+            pool_logits, _, _, _ = apply_train(
+                ctx, state.params, state.batch_stats, imgs, False, labs
             )
     else:
         # Same params, lower-precision compute (scoring_dtype) — scores only
@@ -259,11 +320,12 @@ def score_rows(ctx: StepContext, state, raw, labs, ka, reuse_images=True):
         with jax.named_scope("mercury_score_forward"):
             s_in = imgs.astype(jnp.bfloat16) if scoring_bf16 else imgs
             pool_logits, model_state = _apply(
-                scoring_model, state.params, state.batch_stats, s_in, True)
+                ctx, scoring_model, state.params, state.batch_stats, s_in,
+                True, labs)
             pool_logits = pool_logits.astype(jnp.float32)
         _note_moment_units(ctx, model_state)
     with jax.named_scope("mercury_score_loss"):
-        scores = ctx.score_per_sample(pool_logits, labs)
+        scores = ctx.rows.score(pool_logits, labs)
     return imgs, pool_logits, scores
 
 
@@ -286,13 +348,13 @@ def probe_var_ratio(ctx: StepContext, state, sel_images, sel_labels,
     def run(_):
         with jax.named_scope("mercury_variance_probe"):
             if scoring_model is None:
-                logits, _, _ = apply_train(
+                logits, _, _, _ = apply_train(
                     ctx, state.params, state.batch_stats, sel_images, False
                 )
             else:
                 s_in = (sel_images.astype(jnp.bfloat16)
                         if scoring_bf16 else sel_images)
-                logits, _ = _apply(scoring_model, state.params,
+                logits, _ = _apply(ctx, scoring_model, state.params,
                                    state.batch_stats, s_in, False)
             g = per_sample_grad_norm_bound(
                 logits.astype(jnp.float32), sel_labels,
@@ -336,21 +398,21 @@ def train_update(ctx: StepContext, state, rng, sel_images, sel_labels,
     # --- train forward/backward with the unbiased IS reweighting
     # mean(loss_i/(N·p_i)) (:132-148) --------------------------------
     def loss_fn(params):
-        logits, new_bs, aux = apply_train(
-            ctx, params, state.batch_stats, sel_images, True
+        logits, new_bs, aux, load = apply_train(
+            ctx, params, state.batch_stats, sel_images, True, sel_labels
         )
-        losses = ctx.loss_per_sample(logits, sel_labels)
+        losses = ctx.rows.loss(logits, sel_labels)
         total = reweighted_loss(losses, scaled_probs)
         if mode.moe_aux_weight is not None:
             # Switch load-balancing term (sowed by the MoE blocks).
             total = total + mode.moe_aux_weight * aux
-        return total, (logits, new_bs, aux)
+        return total, (logits, new_bs, aux, load)
 
     # One scope for both halves: jax marks the backward's ops itself
     # (``transpose(jvp(...))`` in the op's path), which is what the
     # device trace splits forward from backward by.
     with jax.named_scope("mercury_train"):
-        (loss, (logits, new_batch_stats, moe_aux)), grads = (
+        (loss, (logits, new_batch_stats, moe_aux, load)), grads = (
             jax.value_and_grad(loss_fn, has_aux=True)(state.params))
 
     # --- optional quantization: each worker stochastically quantizes its
@@ -369,7 +431,7 @@ def train_update(ctx: StepContext, state, rng, sel_images, sel_labels,
 
     loss_mean = lax.pmean(loss, axis)
     correct = lax.psum(
-        jnp.sum((jnp.argmax(logits, -1) == sel_labels).astype(jnp.float32)), axis
+        jnp.sum(ctx.rows.hits(logits, sel_labels)), axis
     )
     count = lax.psum(jnp.asarray(mode.batch_size, jnp.float32), axis)
 
@@ -461,7 +523,8 @@ def train_update(ctx: StepContext, state, rng, sel_images, sel_labels,
 
     return dict(
         loss_mean=loss_mean, acc=correct / count, logits=logits,
-        moe_aux=moe_aux, sparse_rate=sparse_rate, grad_norm=grad_norm,
+        moe_aux=moe_aux, moe_load=load, sparse_rate=sparse_rate,
+        grad_norm=grad_norm,
         new_params=new_params, new_batch_stats=new_batch_stats,
         new_opt_state=new_opt_state,
     )

@@ -47,7 +47,8 @@ class PendingBatch(NamedTuple):
     (``pytorch_collab.py:116,132``), not a re-load by index."""
 
     images: jax.Array        # [B, H, W, C] float32 — augmented + normalized
-    labels: jax.Array        # [B] int32
+                             # ([B, T] int32 rows of a token dataset)
+    labels: jax.Array        # [B] int32 ([B, T] per-token labels)
     scaled_probs: jax.Array  # [B] float32 — p_i·N for the unbiased reweight
 
 
@@ -161,6 +162,7 @@ def create_state(
     with_groupwise: bool = False,
     pending_batch_size: int = 0,
     pending_sample_shape: Optional[tuple] = None,
+    pending_label_shape: tuple = (),
     zero_sharding: bool = False,
     init_opt: bool = True,
     cached_pool_size: int = 0,
@@ -223,8 +225,14 @@ def create_state(
         shape = (tuple(pending_sample_shape) if pending_sample_shape is not None
                  else tuple(sample_batch.shape[1:]))
         pending = PendingBatch(
-            images=jnp.zeros((n_workers, pending_batch_size) + shape, jnp.float32),
-            labels=jnp.zeros((n_workers, pending_batch_size), jnp.int32),
+            # rows of token ids stay the integers they are
+            images=jnp.zeros(
+                (n_workers, pending_batch_size) + shape,
+                sample_batch.dtype
+                if jnp.issubdtype(sample_batch.dtype, jnp.integer)
+                else jnp.float32),
+            labels=jnp.zeros((n_workers, pending_batch_size)
+                             + tuple(pending_label_shape), jnp.int32),
             scaled_probs=jnp.ones((n_workers, pending_batch_size), jnp.float32),
         )
     cached_pool = None

@@ -43,7 +43,6 @@ from mercury_tpu.obs.sampler_health import (
 from mercury_tpu.sampling.importance import (
     EMAState,
     draw_with_replacement,
-    per_sample_loss,
 )
 from mercury_tpu.sampling.scoretable import (
     table_draw_inverse_cdf,
@@ -66,6 +65,7 @@ from mercury_tpu.train.samplers import (
 from mercury_tpu.train.stages import (
     StepContext,
     probe_var_ratio,
+    row_fns,
     row_loss_and_score,
     train_update,
 )
@@ -164,6 +164,10 @@ def _finish(ctx: StepContext, state, upd, drawn: Drawn, stream, ema, k_next,
         "train/sparse_rate": lax.pmean(upd["sparse_rate"], axis),
         "train/moe_aux": lax.pmean(upd["moe_aux"], axis),
     }
+    # Routing of the last layer of routed experts in the train pass
+    # (models/decoder.py sows it; no other model does).
+    for name, value in upd["moe_load"].items():
+        metrics[f"moe/{name}"] = lax.pmean(value, axis)
     if mode.telemetry:
         clip_frac, drift = tel
         metrics["sampler/ess"] = lax.pmean(
@@ -368,11 +372,10 @@ def make_train_step(
         param_specs = jax.tree_util.tree_map(
             lambda s: s.spec, state_out_shardings[0].params
         )
-    loss_per_sample, score_per_sample = row_loss_and_score(mode)
     ctx = StepContext(
         mode=mode, model=model, scoring_model=scoring_model, tx=tx,
         mean=mean, std=std, image_shape=image_shape,
-        loss_per_sample=loss_per_sample, score_per_sample=score_per_sample,
+        rows=row_loss_and_score(mode),
         param_specs=param_specs, trace_facts=trace_facts,
     )
 
@@ -495,6 +498,7 @@ def make_host_stream_prime(config: TrainConfig, mesh: Mesh):
 def _make_epoch_scan(
     name: str, model, mean, std, eval_augmentation, mesh, axis,
     init: Callable[[], Tuple], accumulate: Callable[..., Tuple],
+    token_rows: bool = False,
 ):
     """One-dispatch pass over a pre-batched split: ``lax.scan`` over
     ``[nb, B, ...]`` uint8 arrays — normalize, (``"iid"``: the reference's
@@ -512,6 +516,10 @@ def _make_epoch_scan(
 
         def body(carry, batch):
             imgs_u8, labels, mask = batch
+            if token_rows:
+                # rows of token ids are the model's inputs as they are
+                outputs = model.apply(variables, imgs_u8, train=False)
+                return accumulate(carry, outputs, labels, mask), None
             imgs = normalize_images(imgs_u8, mean, std)
             if eval_augmentation == "iid":
                 from mercury_tpu.data.transforms import eval_transform_iid
@@ -566,6 +574,7 @@ def make_per_class_epoch(
 def make_eval_epoch(
     model, mean: np.ndarray, std: np.ndarray, eval_augmentation: str = "none",
     mesh: Optional[Mesh] = None, axis: str = "data",
+    token_rows: bool = False,
 ) -> Callable[..., Tuple[jax.Array, jax.Array, jax.Array]]:
     """One-dispatch full-split eval → ``(loss_sum, correct, count)``: the
     reference's ``evaluate`` walks a DataLoader batch-by-batch from the host
@@ -574,15 +583,20 @@ def make_eval_epoch(
     transform — resize(33) → random crop(32) (``exp_dataset.py:63-68``; yes,
     it random-crops at eval) — with a fixed key so eval stays deterministic;
     the live non-IID path normalizes only (``cifar10/data_loader.py:92-96``).
+    ``token_rows``: rows of token ids with per-token labels, read through
+    the step's own loss seam (``stages.row_fns``): the loss of a row is the
+    mean over its positions, its hit the share predicted right.
     """
+    rows = row_fns(token_rows)
 
     def init():
         return (jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))
 
-    def accumulate(carry, logits, labels, mask):
-        losses = per_sample_loss(logits, labels)
+    def accumulate(carry, outputs, labels, mask):
+        logits = rows.reduce(outputs, labels)
+        losses = rows.loss(logits, labels)
         maskf = mask.astype(jnp.float32)
-        hit = (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
+        hit = rows.hits(logits, labels)
         loss_sum, correct, count = carry
         return (
             loss_sum + jnp.sum(losses * maskf),
@@ -591,4 +605,5 @@ def make_eval_epoch(
         )
 
     return _make_epoch_scan("eval_epoch", model, mean, std,
-                            eval_augmentation, mesh, axis, init, accumulate)
+                            eval_augmentation, mesh, axis, init, accumulate,
+                            token_rows)

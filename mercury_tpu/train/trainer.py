@@ -46,7 +46,12 @@ from mercury_tpu.config import TrainConfig
 from mercury_tpu.data import cifar
 from mercury_tpu.data.partition import partition_data
 from mercury_tpu.data.pipeline import ShardedDataset, eval_batches, make_sharded_dataset
-from mercury_tpu.models import create_model
+from mercury_tpu.data.tokens import (
+    DEFAULT_VOCAB,
+    TOKEN_DATASETS,
+    load_token_dataset,
+)
+from mercury_tpu.models import LM_WIDTHS, create_model
 from mercury_tpu.obs.accounting import ThroughputMeter, analytic_flops_per_step
 from mercury_tpu.obs.aggregate import (
     CrossHostGatherAggregator,
@@ -66,6 +71,7 @@ from mercury_tpu.obs.writer import (
     try_tensorboard_sink,
 )
 from mercury_tpu.parallel.mesh import make_mesh
+from mercury_tpu.sampling.importance import token_logits
 from mercury_tpu.train import checkpoint as ckpt
 from mercury_tpu.train.mode import StepMode
 from mercury_tpu.train.state import MercuryState, create_state, make_optimizer
@@ -87,11 +93,19 @@ def build_dataset(config: TrainConfig, seed_offset: int = 0) -> ShardedDataset:
             config.data_dir, image_size=config.image_size,
             seed=config.seed + seed_offset,
         )
+    elif config.dataset in TOKEN_DATASETS:
+        train, test, info = load_token_dataset(
+            config.dataset, config.num_classes or DEFAULT_VOCAB,
+            config.seq_len, seed=config.seed + seed_offset,
+        )
     else:
         train, test, info = cifar.load_dataset(
             config.dataset, data_dir=config.data_dir, seed=config.seed + seed_offset
         )
-    mode = "hetero" if config.noniid else "homo"
+    # Dirichlet skew is over one class label a row; rows of per-token
+    # labels are dealt out evenly.
+    mode = ("hetero" if config.noniid
+            and config.dataset not in TOKEN_DATASETS else "homo")
     shards = partition_data(
         train[1],
         config.world_size,
@@ -207,7 +221,29 @@ class Trainer:
             )
 
         bn_axis = config.mesh_axis if config.batch_norm == "sync" else None
+        # Rows of token ids with per-token labels ([N, T]): the step's
+        # mode refuses what cannot take such rows (StepMode.token_rows).
+        self._token_rows = config.dataset in TOKEN_DATASETS
         model_kw = {}
+        if config.model in LM_WIDTHS:
+            if not self._token_rows:
+                raise ValueError(
+                    f"model={config.model!r} reads rows of token ids; "
+                    f"dataset {config.dataset!r} has none "
+                    f"(token datasets: {TOKEN_DATASETS})")
+            from mercury_tpu.ops import on_tpu
+
+            # the attention kernel under the step's own switch
+            # (StepMode.use_pallas: None is "on the TPU")
+            model_kw.update(
+                cut=config.model_cut,
+                use_pallas=(on_tpu() if config.use_pallas is None
+                            else bool(config.use_pallas)))
+        elif self._token_rows or config.model_cut is not None:
+            raise ValueError(
+                f"dataset {config.dataset!r} / model_cut="
+                f"{config.model_cut} need a model of per-token logits "
+                f"({sorted(LM_WIDTHS)}), got model={config.model!r}")
         if config.moe_experts is not None:
             if config.model not in ("transformer", "vit"):
                 raise ValueError(
@@ -257,6 +293,7 @@ class Trainer:
         # itself: [H, W, C] for images, [T, F] for sequences (the BiLSTM
         # speech path — beyond the reference, which never trains MyLSTM).
         sample_shape = tuple(int(s) for s in self.dataset.x_train.shape[1:])
+        label_shape = tuple(int(s) for s in self.dataset.y_train.shape[1:])
         is_image = len(sample_shape) == 3
         if not is_image and config.augmentation != "none":
             raise ValueError(
@@ -264,7 +301,10 @@ class Trainer:
                 f"dataset {config.dataset!r} has sample shape {sample_shape} — "
                 "set augmentation='none'"
             )
-        sample = jnp.zeros((1,) + sample_shape, jnp.float32)
+        # (parameters do not depend on a token row's length: a short one)
+        sample = (jnp.zeros((1, min(sample_shape[0], 128)), jnp.int32)
+                  if self._token_rows
+                  else jnp.zeros((1,) + sample_shape, jnp.float32))
         params_sharded = tp > 1 or fs > 1
         # The run's mode, decided once (train/mode.py): sampler kind,
         # placement, sizes, optional state fields. All below reads this.
@@ -284,6 +324,7 @@ class Trainer:
             pending_sample_shape=((32, 32, sample_shape[-1])
                                   if config.augmentation == "iid"
                                   else sample_shape),
+            pending_label_shape=label_shape,
             init_opt=not params_sharded,
             **mode.create_state_fields(),
         )
@@ -508,7 +549,8 @@ class Trainer:
                                           if config.augmentation == "iid"
                                           else "none",
                                           mesh=eval_mesh,
-                                          axis=config.mesh_axis)
+                                          axis=config.mesh_axis,
+                                          token_rows=self._token_rows)
         # --- fault-injection plane (mercury_tpu/faults.py): built BEFORE
         # every subsystem that hooks into it (metric writer, prefetch
         # pipeline, scorer fleet, checkpoint writes, the fit loop). None
@@ -654,6 +696,10 @@ class Trainer:
         self.logger = AsyncMetricWriter(sinks, observers=observers,
                                         faults=self._faults,
                                         journal=self._journal)
+        if config.model in LM_WIDTHS:
+            # Routing of the last layer of routed experts, from the record
+            # the log gate already fetches (the drain thread's host copy).
+            self.logger.add_observer(self._note_moe_load)
         # --- host supervisor (runtime/supervisor.py): liveness + restart
         # + the degradation ladder. Units register below as the worker
         # fleets are built; the writer-observer hook makes the supervisor
@@ -692,6 +738,11 @@ class Trainer:
         # Round up to a multiple of world_size so the sharded-eval batch
         # dimension always divides the mesh axis (e.g. world_size=5 → 260).
         self._eval_batch = -(-256 // config.world_size) * config.world_size
+        if self._token_rows:
+            # a row is a whole sequence, and the model takes rows one at a
+            # time anyway: a scanned batch no wider than the test split
+            self._eval_batch = -(-min(int(self.dataset.x_test.shape[0]), 8)
+                                 // config.world_size) * config.world_size
         self._eval_cache: Dict[bool, tuple] = {}
         self._ckpt_thread = None  # in-flight async checkpoint write
 
@@ -1341,6 +1392,17 @@ class Trainer:
                 units=self._trace_facts.get("bn_moment_units", 0))
             return final_metrics
 
+    def _note_moe_load(self, record: Dict[str, Any]) -> None:
+        """The instant ``trainer/moe_load``: what share of the (token,
+        expert) pairs fell on the experts held here, and the busiest held
+        expert's pairs over the mean (``models/moe.py::routed_experts``),
+        in the train pass of the logged step."""
+        if "moe/held_pair_share" in record:
+            self.tracer.instant(
+                "trainer/moe_load", cat="trainer",
+                held_pair_share=float(record["moe/held_pair_share"]),
+                load_max_over_mean=float(record["moe/load_max_over_mean"]))
+
     def _fit(self, num_epochs: Optional[int]) -> Dict[str, float]:
         cfg = self.config
         num_epochs = num_epochs or cfg.num_epochs
@@ -1536,7 +1598,8 @@ class Trainer:
                 if crossed(cfg.eval_every, step, k):
                     with self.tracer.span("trainer/eval", cat="trainer",
                                           step=step):
-                        final_metrics = self.evaluate()
+                        final_metrics = self.evaluate(
+                            include_train=not self._token_rows)
                     self.logger.log_scalars(step, final_metrics)
                     print(
                         f"  eval @ {step}: "
@@ -1574,7 +1637,10 @@ class Trainer:
         if not final_metrics:
             with self.tracer.span("trainer/eval", cat="trainer", step=step,
                                   closing=True):
-                final_metrics = self.evaluate()
+                # (a token dataset's train split is left out: a row is a
+                # whole sequence, and the split costs dozens of steps)
+                final_metrics = self.evaluate(
+                    include_train=not self._token_rows)
         if cfg.checkpoint_dir:
             with self.tracer.span("trainer/final_checkpoint", cat="trainer",
                                   step=step):
@@ -1835,7 +1901,10 @@ class Trainer:
 
         ``inputs``: ``[N, H, W, C]`` images (uint8 or float — normalized
         with the dataset's statistics, as eval does) or ``[N, T, F]``
-        sequences (passed through). Returns ``[N, num_classes]`` float32
+        sequences (passed through), or ``[N, T]`` rows of token ids (the
+        model's inputs as they are; returns ``[N, T, vocabulary rows]``,
+        the one place whole per-token logits exist: hand it few rows).
+        Returns ``[N, num_classes]`` float32
         logits; ``argmax(-1)`` gives class predictions. The reference has
         no inference entry point at all — evaluation is the closest thing
         (``pytorch_collab.py:201-234``).
@@ -1850,9 +1919,14 @@ class Trainer:
             model = self.model
             mean, std = self.dataset.mean, self.dataset.std
             iid_eval = self.config.augmentation == "iid"
+            token_rows = self._token_rows
 
             def fwd(params, batch_stats, x):
                 from mercury_tpu.data.pipeline import normalize_images
+
+                if token_rows:
+                    return token_logits(
+                        model.apply({"params": params}, x, train=False))
 
                 # The exact eval-path preprocessing (make_eval_epoch):
                 # normalize (no-op stats for sequences), and the IID
@@ -1880,6 +1954,10 @@ class Trainer:
         One scanned device dispatch over the cached eval batches (same
         sharding as ``evaluate``). Returns ``[num_classes]`` float64;
         classes absent from the split are NaN."""
+        if self._token_rows:
+            raise ValueError("per_class_accuracy reads one class label a "
+                             "row; this dataset's rows carry per-token "
+                             "labels")
         if not hasattr(self, "_per_class_fn"):
             from mercury_tpu.train.step import make_per_class_epoch
 

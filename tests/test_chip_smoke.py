@@ -15,10 +15,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_body_runs_tiny_on_the_cpu_mesh():
     """The same phases the chip runs — IS / uniform / scan trainers,
     kernel parity (interpret mode here), the four-device placement phase
-    — at ``smallcnn`` size. Any failed check raises."""
+    — at ``smallcnn`` size, and the token path at the decoder's CPU size.
+    Any failed check raises."""
     out = chip_smoke.run(tiny=True)
     assert set(out) == {"one_chip_is", "one_chip_uniform", "one_chip_scan",
-                        "kernels", "four_chip_is"}
+                        "one_chip_tokens", "kernels", "four_chip_is"}
     for name, facts in out.items():
         if name != "kernels":
             assert facts["compiles_after_first"] == 0, name

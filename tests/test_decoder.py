@@ -1,0 +1,321 @@
+"""The causal decoder, its attention forms, its routed experts and the
+next-token seam, at a small size on the CPU: d_model 64, 4 query heads on 1
+key/value head of 16, window 8 at T 32, 16 experts top-3 of which 4 are
+held, vocabulary 96 (``chip_smoke.register_tiny_lm``: the size its token
+phase runs). The plain reference's side of the same model is
+``tests/perfbench/test_smallthinker.py``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chip_smoke import register_tiny_lm
+from mercury_tpu import TrainConfig
+from mercury_tpu.data.tokens import zipf_tokens
+from mercury_tpu.models import LM_WIDTHS, create_model
+from mercury_tpu.models import decoder
+from mercury_tpu.models.moe import route_top_k, routed_experts
+from mercury_tpu.sampling.importance import (
+    sequence_loss,
+    sequence_rows,
+    token_logits,
+)
+from mercury_tpu.train.stages import row_fns
+
+TINY = register_tiny_lm()
+WIDTHS = LM_WIDTHS[TINY]
+VOCAB, T = 96, 32
+
+
+def _qkv(t=T, kv=2, groups=2, hd=16, seed=0):
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((kv, groups, t, hd)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((kv, t, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((kv, t, hd)), jnp.float32)
+    return q * hd ** -0.5, k, v
+
+
+# --------------------------------------------------------------- attention
+@pytest.mark.parametrize("window", [None, 8, 5])
+def test_blockwise_attention_is_the_dense_one(window):
+    """(v) A block of queries at a time over the keys its mask can reach
+    gives what the [T, T] form gives, forward and gradient; float32 on both
+    sides, so only the order of a row's sum differs (1e-5)."""
+    q, k, v = _qkv()
+
+    def loss(fn, *a):
+        return jnp.sum(jnp.square(fn(*a)))
+
+    dense = jax.value_and_grad(
+        lambda *a: loss(decoder.dense_attention, *a, window), (0, 1, 2))
+    block = jax.value_and_grad(
+        lambda *a: loss(decoder.blockwise_attention, *a, window, 8),
+        (0, 1, 2))
+    (a, ga), (b, gb) = dense(q, k, v), block(q, k, v)
+    np.testing.assert_allclose(a, b, rtol=1e-5)
+    for x, y in zip(ga, gb):
+        np.testing.assert_allclose(x, y, atol=1e-5)
+
+
+def test_a_window_masks_exactly_the_keys_behind_it():
+    """(iv) At T <= window a windowed layer is full attention; past it the
+    two differ, and the windowed output of query i is full attention over
+    keys i - window + 1 .. i alone."""
+    q, k, v = _qkv()
+    full = decoder.dense_attention(q, k, v, None)
+    np.testing.assert_array_equal(
+        decoder.dense_attention(q, k, v, T), full)
+    windowed = decoder.blockwise_attention(q, k, v, 8, 8)
+    np.testing.assert_allclose(windowed[:, :, :8], full[:, :, :8], rtol=1e-5,
+                               atol=1e-6)
+    assert float(jnp.abs(windowed[:, :, 8:] - full[:, :, 8:]).max()) > 1e-3
+    i = 20
+    alone = decoder.dense_attention(q[:, :, i - 7:i + 1], k[:, i - 7:i + 1],
+                                    v[:, i - 7:i + 1], None)[:, :, -1]
+    np.testing.assert_allclose(windowed[:, :, i], alone, rtol=1e-5, atol=1e-6)
+
+
+def test_splash_kernel_is_the_blockwise_form():
+    """The TPU path's kernel (interpreted here) against the XLA form at the
+    kernel's own shapes (head size 128, T 256, 2 query heads a key/value
+    head), causal and windowed, forward and gradient. bfloat16 products in
+    the kernel's inner loop are not in play at float32 inputs; the
+    tolerance is for its online softmax (1e-4)."""
+    q, k, v = _qkv(t=256, kv=1, groups=2, hd=128)
+    assert decoder.splash_takes(256, 128) and not decoder.splash_takes(T, 16)
+    for window in (None, 128):
+        want, gw = jax.value_and_grad(lambda q: jnp.sum(jnp.square(
+            decoder.blockwise_attention(q, k, v, window, 128))))(q)
+        got, gg = jax.value_and_grad(lambda q: jnp.sum(jnp.square(
+            decoder.splash_attention(q, k, v, window))))(q)
+        np.testing.assert_allclose(got, want, rtol=1e-4)
+        np.testing.assert_allclose(gg, gw, atol=1e-4)
+
+
+def test_rope_moves_with_the_positions_and_nope_does_not():
+    """(iv) Scores of rotated queries and keys depend on the distance of
+    their positions alone: a shift of both leaves them; unrotated ones have
+    no position at all. So a NoPE layer is invariant to a shift of positions
+    and a RoPE layer's q and k are not."""
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((T, 2, 16)), jnp.float32)
+    a, b = (decoder.rotate_half(x, 10_000.0, offset) for offset in (0, 5))
+    assert float(jnp.abs(a - b).max()) > 1e-2          # not invariant
+    scores = [jnp.einsum("qhd,khd->hqk", r, r) for r in (a, b)]
+    np.testing.assert_allclose(*scores, atol=1e-4)     # distances are
+    np.testing.assert_array_equal(decoder.rotate_half(x, 10_000.0)[0], x[0])
+
+
+# ----------------------------------------------------------------- experts
+def _experts(held, seed=0, d=64, f=32):
+    rng = np.random.default_rng(seed)
+    mk = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.1, jnp.float32)  # noqa: E731
+    return mk(held, d, f), mk(held, d, f), mk(held, f, d)
+
+
+def test_the_shares_of_four_holders_add_up_to_the_uncut_layer():
+    """(iii) The layer told to hold experts 0-3, 4-7, 8-11, 12-15 in turn
+    gives four partial outputs whose sum is the uncut layer's, which is the
+    dense sum over the chosen experts of weight x expert."""
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.standard_normal((T, 64)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((T, 16)), jnp.float32)
+    gate, up, down = _experts(16)
+    whole, (share, _) = routed_experts(h, r, gate, up, down, 3, 0)
+    assert float(share) == 1.0
+    parts = 0.0
+    for first in range(0, 16, 4):
+        held = slice(first, first + 4)
+        y, (share, _) = routed_experts(h, r, gate[held], up[held],
+                                       down[held], 3, first)
+        parts = parts + y
+        assert 0.0 < float(share) < 1.0
+    np.testing.assert_allclose(parts, whole, atol=1e-5)
+    logits, chosen = jax.lax.top_k(r, 3)
+    w = jax.nn.softmax(logits, -1)
+    dense = sum(
+        jnp.sum(jnp.where(chosen == e, w, 0.0), -1)[:, None]
+        * ((jax.nn.relu(h @ gate[e]) * (h @ up[e])) @ down[e])
+        for e in range(16))
+    np.testing.assert_allclose(whole, dense, atol=1e-5)
+
+
+def test_no_token_is_dropped_when_one_held_expert_takes_them_all():
+    """(vi) Every token's first choice is held expert 1: its group holds all
+    T pairs, no capacity cuts any, and the output is that expert's, weighted,
+    for every token. The gradient reaches the expert from every token."""
+    rng = np.random.default_rng(2)
+    h = jnp.asarray(rng.standard_normal((T, 64)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((T, 16)), jnp.float32)
+    r = r.at[:, 1].set(10.0).at[:, :4].add(jnp.asarray([0, 0, -20., -20.]))
+    gate, up, down = _experts(4)
+    weights, _, _, sizes, is_held = route_top_k(r, 3, 0, 4)
+    assert int(sizes[1]) == T and int(sizes[2]) == int(sizes[3]) == 0
+    y, (_, busiest) = routed_experts(h, r, gate, up, down, 3, 0)
+    # the held choices are expert 1 and, for some tokens, expert 0
+    _, chosen = jax.lax.top_k(r, 3)
+    per_choice = jnp.stack([jnp.stack(
+        [(jax.nn.relu(h @ gate[e]) * (h @ up[e])) @ down[e]
+         for e in range(4)], 0)[jnp.clip(chosen[:, c], 0, 3),
+                                jnp.arange(T)] for c in range(3)], 1)
+    want = jnp.sum(jnp.where(is_held, weights, 0.0)[..., None] * per_choice,
+                   1)
+    np.testing.assert_allclose(y, want, atol=1e-5)
+    assert float(busiest) >= 2.0
+    g = jax.grad(lambda gate: jnp.sum(routed_experts(
+        h, r, gate, up, down, 3, 0)[0]))(gate)
+    assert float(jnp.abs(g[1]).max()) > 0 and float(jnp.abs(g[2]).max()) == 0
+
+
+# ---------------------------------------------------------------- the seam
+def test_the_row_at_a_time_head_and_loss_are_the_whole_product():
+    """(v) ``sequence_loss`` a row at a time is log-softmax cross-entropy
+    over the whole [N, T, V] logits, mean over T; the seam of token rows
+    (``stages.row_fns(token_rows=True)``) reduces a model's outputs to
+    it, and reads loss, score and hits off the reduction."""
+    rng = np.random.default_rng(3)
+    hidden = jnp.asarray(rng.standard_normal((3, T, 64)), jnp.float32)
+    head = jnp.asarray(rng.standard_normal((64, VOCAB)) * 0.1, jnp.float32)
+    labels = jnp.asarray(rng.integers(0, VOCAB, (3, T)), jnp.int32)
+    rows = jax.lax.map(lambda a: sequence_loss(a[0], head, a[1]),
+                       (hidden, labels))
+    seam = row_fns(token_rows=True)
+    assert seam.reduce is sequence_rows
+    np.testing.assert_array_equal(seam.reduce((hidden, head), labels), rows)
+    logits = hidden @ head
+    np.testing.assert_array_equal(token_logits((hidden, head)), logits)
+    logp = jax.nn.log_softmax(logits, -1)
+    want = -jnp.take_along_axis(logp, labels[..., None], -1)[..., 0].mean(-1)
+    np.testing.assert_allclose(seam.loss(rows, labels), want, rtol=1e-6)
+    np.testing.assert_array_equal(seam.score(rows, labels),
+                                  seam.loss(rows, labels))
+    np.testing.assert_allclose(
+        seam.hits(rows, labels),
+        (jnp.argmax(logits, -1) == labels).mean(-1), rtol=1e-6)
+    # one class label a row: the logits are carried whole
+    plain = row_fns()
+    assert plain.reduce(logits, None) is logits
+    np.testing.assert_array_equal(
+        plain.hits(logits[:, 0], labels[:, 0]),
+        jnp.argmax(logits[:, 0], -1) == labels[:, 0])
+
+
+def test_the_models_rows_are_its_whole_logits_reduced():
+    model = create_model(TINY, num_classes=VOCAB,
+                         compute_dtype="float32", cut=(2, 4, 4))
+    (x, y), _ = zipf_tokens(VOCAB, T, train_size=4, test_size=0, seed=1)
+    variables = model.init(jax.random.key(0), x[:1], train=False)
+    assert sorted(variables["params"]) == ["embed", "final_norm", "head",
+                                           "layer0", "layer1"]
+    assert variables["params"]["layer0"]["gate"].shape == (4, 64, 32)
+    assert variables["params"]["layer0"]["router"].shape == (64, 16)
+    hidden, head = model.apply(variables, x, train=False)
+    assert hidden.shape == (4, T, 64) and head.shape == (64, VOCAB)
+    logits = token_logits((hidden, head))
+    assert logits.shape == (4, T, VOCAB) and logits.dtype == jnp.float32
+    outputs, written = model.apply({"params": variables["params"]}, x,
+                                   train=True, mutable=[decoder.MOE_LOAD])
+    rows = sequence_rows(outputs, jnp.asarray(y))
+    logp = jax.nn.log_softmax(logits, -1)
+    want = -jnp.take_along_axis(logp, y[..., None], -1)[..., 0].mean(-1)
+    np.testing.assert_allclose(rows[:, 0], want, rtol=1e-5)
+    load = written[decoder.MOE_LOAD]
+    assert 0.0 < float(load["held_pair_share"][0]) < 1.0
+    assert float(load["load_max_over_mean"][0]) >= 1.0
+    with pytest.raises(ValueError, match="cut"):
+        create_model(TINY, num_classes=VOCAB,
+                     cut=(2, 14, 4)).init(jax.random.key(0), x[:1])
+
+
+def test_zipf_tokens_are_seeded_shifted_and_half_patterned():
+    (x, y), (xt, yt) = zipf_tokens(VOCAB, T, train_size=6, test_size=2,
+                                   seed=5, pattern=4)
+    again = zipf_tokens(VOCAB, T, train_size=6, test_size=2, seed=5,
+                        pattern=4)
+    np.testing.assert_array_equal(x, again[0][0])
+    assert x.shape == y.shape == (6, T) and xt.shape == (2, T)
+    assert x.dtype == np.int32 and 0 <= x.min() and x.max() < VOCAB
+    np.testing.assert_array_equal(x[:, 1:], y[:, :-1])       # shifted by one
+    np.testing.assert_array_equal(x[1, 4:], x[1, :-4])       # odd: pattern
+    assert (x[0, 4:] != x[0, :-4]).any()                      # even: iid
+    other = zipf_tokens(VOCAB, T, train_size=6, test_size=2, seed=6)[0][0]
+    assert (other != x).any()
+
+
+# ------------------------------------------------------------- Trainer.fit
+def _config(**kw):
+    fields = dict(
+        model=TINY, dataset="tokens_zipf",
+        model_cut=(4, 0, 4), num_classes=VOCAB, seq_len=T, world_size=1,
+        batch_size=2, presample_batches=3, augmentation="none",
+        compute_dtype="float32", pipelined_scoring=True, steps_per_epoch=1,
+        num_epochs=1000, log_every=4, eval_every=0, checkpoint_every=0,
+        heartbeat_every=0, trace=True)
+    fields.update(kw)
+    return TrainConfig(**fields)
+
+
+@pytest.mark.parametrize("world", [1, 2])
+def test_fit_on_the_token_dataset(world):
+    """(vii) A few steps through ``Trainer.fit()`` under pipelined scoring:
+    the loss is finite and falls from ln(96), ``state.pending`` holds
+    integer rows and per-token labels as the replay reads them,
+    ``evaluate()`` and ``predict()`` are shaped as the harness assumes, the
+    routing reaches the log record and the tracer."""
+    from mercury_tpu.train import Trainer
+
+    records = []
+    with Trainer(_config(world_size=world)) as t:
+        assert t._ingest_path == "tokens" and t._token_rows
+        t.logger.add_observer(lambda rec: records.append(dict(rec)))
+        out = t.fit(num_epochs=9)
+        assert set(out) == {"test/eval_loss", "test/eval_acc"}
+        assert np.isfinite(out["test/eval_loss"])
+        assert out["test/eval_loss"] < np.log(VOCAB)
+        pending = t.state.pending
+        assert pending.images.shape == (world, 2, T)
+        assert pending.images.dtype == jnp.int32
+        assert pending.labels.shape == (world, 2, T)
+        assert pending.scaled_probs.shape == (world, 2)
+        ds = t.dataset
+        assert ds.x_train.shape == (512, T) and ds.y_test.shape == (8, T)
+        logits = t.predict(np.asarray(ds.x_test)[:2])
+        assert logits.shape == (2, T, VOCAB)
+        both = t.evaluate(include_train=True)
+        assert "train/eval_loss" in both
+        assert both["test/eval_loss"] == out["test/eval_loss"]
+        with pytest.raises(ValueError, match="per-token"):
+            t.per_class_accuracy()
+        events = t.tracer.snapshot()
+    assert len(records) == 2 and np.isfinite(records[-1]["train/loss"])
+    assert 0.0 < records[-1]["moe/held_pair_share"] <= 1.0
+    loads = [e for e in events if e["name"] == "trainer/moe_load"]
+    assert len(loads) == 2
+    assert loads[-1]["args"]["held_pair_share"] == pytest.approx(
+        records[-1]["moe/held_pair_share"])
+    units = [e for e in events if e["name"] == "trainer/bn_moment_units"]
+    assert units and units[-1]["args"]["units"] == 0
+
+
+@pytest.mark.parametrize("fields, match", [
+    (dict(model="smallcnn"), "per-token logits"),
+    (dict(dataset="synthetic", num_classes=None), "token ids"),
+    (dict(sampler="scoretable", pipelined_scoring=False), "sampler='pool'"),
+    (dict(importance_score="grad_norm"), "sampler='pool'"),
+    (dict(label_smoothing=0.1), "sampler='pool'"),
+])
+def test_what_cannot_take_token_rows_is_refused(fields, match):
+    from mercury_tpu.train import Trainer
+
+    with pytest.raises(ValueError, match=match):
+        Trainer(_config(**fields))
+
+
+def test_uniform_sampling_takes_token_rows_too():
+    from mercury_tpu.train import Trainer
+
+    with Trainer(_config(use_importance_sampling=False,
+                         pipelined_scoring=False, trace=False)) as t:
+        out = t.fit(num_epochs=3)
+    assert np.isfinite(out["test/eval_loss"])

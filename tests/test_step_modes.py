@@ -29,6 +29,10 @@ from typing import Any, Dict, List, Tuple
 
 import pytest
 
+#: The token row's model: ``chip_smoke.register_tiny_lm`` adds it to the
+#: registry of the checkout that is traced (:func:`build`).
+TINY_LM = "smallthinker-tiny"
+
 BASE: Dict[str, Any] = dict(
     model="smallcnn", dataset="synthetic", world_size=2, batch_size=8,
     presample_batches=2, num_epochs=1, steps_per_epoch=100, eval_every=0,
@@ -84,11 +88,26 @@ def _matrix() -> List[Tuple[str, Dict[str, Any]]]:
             model="transformer", dataset="synthetic_seq",
             augmentation="none", tensor_parallel=2, batch_size=4,
             pipelined_scoring=True)),
+        # rows of token ids, per-token labels, a per-sequence loss
+        ("pipelined-tokens", dict(
+            model=TINY_LM, dataset="tokens_zipf",
+            model_cut=(2, 0, 4), num_classes=96, seq_len=32,
+            augmentation="none", batch_size=2, pipelined_scoring=True)),
     ]
     return rows
 
 
 MATRIX: List[Tuple[str, Dict[str, Any]]] = _matrix()
+
+
+def _register(model) -> None:
+    """The token row's model is no published one: the checkout's
+    ``chip_smoke.py`` adds it to the registry (ImportError from a checkout
+    older than the row)."""
+    if model == TINY_LM:
+        from chip_smoke import register_tiny_lm
+
+        register_tiny_lm()
 
 
 def build(fields: Dict[str, Any]):
@@ -97,6 +116,7 @@ def build(fields: Dict[str, Any]):
     from mercury_tpu.config import TrainConfig
     from mercury_tpu.train.trainer import Trainer
 
+    _register(fields.get("model"))
     trainer = Trainer(TrainConfig(**dict(BASE, **fields)))
     if trainer._scorer_fleet is not None:
         trainer._scorer_fleet.close()
@@ -150,14 +170,20 @@ def _abstract_state(config, mode, shard_len=64):
     from mercury_tpu.train.state import create_state, make_optimizer
 
     seq = config.dataset == "synthetic_seq"
-    sample_shape = (16, 8) if seq else (32, 32, 3)
-    model = create_model(config.model, num_classes=10,
+    tokens = config.dataset == "tokens_zipf"
+    _register(config.model)
+    sample_shape = ((config.seq_len,) if tokens
+                    else (16, 8) if seq else (32, 32, 3))
+    model = create_model(config.model, num_classes=config.num_classes or 10,
                          compute_dtype=config.compute_dtype,
-                         param_dtype=config.param_dtype)
+                         param_dtype=config.param_dtype,
+                         **({"cut": config.model_cut} if tokens else {}))
     tx = make_optimizer(config.optimizer, config.lr, 100, config.weight_decay)
     return jax.eval_shape(lambda: create_state(
-        jax.random.key(0), model, tx, jnp.zeros((1,) + sample_shape),
+        jax.random.key(0), model, tx,
+        jnp.zeros((1,) + sample_shape, jnp.int32 if tokens else jnp.float32),
         config.world_size, shard_len, pending_sample_shape=sample_shape,
+        pending_label_shape=sample_shape if tokens else (),
         **mode.create_state_fields())), model, tx
 
 
@@ -299,6 +325,8 @@ ILLEGAL = [
     (dict(scorer_tenants=2), {},
      "scorer_tenants requires refresh_mode='async'"),
     (dict(importance_score="weird"), {}, "unknown importance_score 'weird'"),
+    (dict(dataset="tokens_zipf", importance_score="grad_norm"), {},
+     "rows of per-token labels train under sampler='pool'"),
     (dict(variance_probe_every=-1), {},
      "variance_probe_every must be >= 0, got -1"),
     (dict(telemetry=True, variance_probe_every=2), dict(scan_steps=4),
@@ -336,7 +364,7 @@ def test_illegal_combination_raises_its_message(fields, kw, phrase):
 
 
 def test_one_case_per_rule_and_none_left_in_the_step_builder():
-    """32 rules, all in ``StepMode.from_config``; ``make_train_step`` and
+    """33 rules, all in ``StepMode.from_config``; ``make_train_step`` and
     ``make_host_stream_prime`` raise nothing themselves."""
     import ast
     import inspect
@@ -350,7 +378,7 @@ def test_one_case_per_rule_and_none_left_in_the_step_builder():
     src = inspect.getsource(mode.StepMode.from_config)
     tree = ast.parse("class _:\n" + src)
     assert sum(isinstance(n, ast.Raise) for n in ast.walk(tree)) == len(
-        ILLEGAL) == 32
+        ILLEGAL) == 33
     assert raises(step.make_train_step) == 0
     assert raises(step.make_host_stream_prime) == 0
 
@@ -400,7 +428,11 @@ def digests(text_dir: str = "", only: str = "") -> Dict[str, str]:
     for name, fields in MATRIX:
         if not want(name):
             continue
-        trainer = build(fields)
+        try:
+            trainer = build(fields)
+        except ImportError:     # a row newer than the checkout traced
+            print(name, "absent", flush=True)
+            continue
         try:
             step = (trainer.train_step_many if fields.get("scan_steps", 1) > 1
                     else trainer.train_step)

@@ -186,12 +186,17 @@ def _compile_trainer_step(devices, world, **kw):
     from mercury_tpu.train import Trainer
     from mercury_tpu.train.step import make_train_step
 
-    config = TrainConfig(
+    fields = dict(
         model="resnet18", dataset="synthetic", world_size=world,
         batch_size=32, presample_batches=10, use_pallas=True,
-        log_every=0, eval_every=0, heartbeat_every=0, **kw)
+        log_every=0, eval_every=0, heartbeat_every=0)
+    fields.update(kw)
+    config = TrainConfig(**fields)
     mesh = Mesh(np.array(devices[:world]), (config.mesh_axis,))
-    with Trainer(config) as t:
+    # The trainer runs its model once on the CPU (the parameters' init):
+    # the kernels turn into Mosaic calls only once it stands.
+    with Trainer(config) as t, pytest.MonkeyPatch.context() as patch:
+        patch.setattr(mercury_kernels, "on_tpu", lambda: True)
         scan = t.scan_steps
         step = make_train_step(t.model, t.tx, config, mesh, t.dataset.mean,
                                t.dataset.std, scan_steps=scan,
@@ -203,7 +208,7 @@ def _compile_trainer_step(devices, world, **kw):
                 x.shape, x.dtype,
                 sharding=NamedSharding(mesh, x.sharding.spec)),
             (t.state, t._step_x, ds.y_train, ds.shard_indices))
-    return step.lower(*args).compile()
+        return step.lower(*args).compile()
 
 
 @pytest.mark.slow
@@ -436,3 +441,84 @@ def test_differentiated_pass_is_the_plain_forms_for_v5e(v5e_devices):
                 compiled.memory_analysis().temp_size_in_bytes)
 
     assert cost(Bottleneck) == cost(PlainBottleneck)
+
+
+# ------------------------------------- the next-token cell (``st21b-is-8k``)
+#: What the v5e's compiler allows one program (it refused PR 35's whole-pool
+#: reference with "Used 19.73G of 15.75G hbm").
+HBM_LIMIT = int(15.75 * 2 ** 30)
+TOKEN_CELL = "st21b-is-8k"
+
+
+def test_token_cell_step_fits_one_v5e(v5e_devices):
+    """The cell's own step (the pool of 10 sequences of 8,192 tokens scored
+    a row at a time, one trained on) at the published widths: the
+    attention is splash-attention's Mosaic kernels, and the compiler's
+    peak (state, bfloat16 weights, one row's activations and logits) lies
+    under the chip's limit. Three quarters of a minute."""
+    from perfbench.cell import Cell
+
+    fields = Cell(TOKEN_CELL).train_config_fields(seed=7, trace=False)
+    compiled = _compile_trainer_step(v5e_devices, 1, **fields)
+    text = compiled.as_text()
+    assert "splash" in text and "mercury_score_draw_kernel" in text
+    memory = compiled.memory_analysis()
+    # the state alone: 370.5 M parameters x 12 B (parameters, mu, nu)
+    assert memory.argument_size_in_bytes > 4.4e9
+    assert memory.peak_memory_in_bytes < HBM_LIMIT, memory
+
+
+def _reference_block(devices, program, quantize=None):
+    """One of the two programs the plain reference's training side runs a
+    row at a time for the token cell (``perfbench/reference.py``:
+    ``score_pool``'s ``score`` and ``make_loss_and_grad``'s ``add_block``
+    at ``check.train_block_rows`` rows), compiled for one v5e at the
+    configuration's own shapes."""
+    from mercury_tpu.models import create_model
+    from perfbench import reference
+    from perfbench.cell import Cell
+
+    cell = Cell(TOKEN_CELL)
+    arch, fields = cell.config["reference"], cell.config["train_config"]
+    rows = int(cell.config["check"]["train_block_rows"])
+    fam = reference.family(arch)
+    model = create_model(fields["model"], num_classes=fields["num_classes"],
+                         cut=tuple(fields["model_cut"]))
+    sh = NamedSharding(Mesh(np.array(devices[:1]), ("data",)), P())
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, a.dtype), jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 128), jnp.int32)))["params"])
+    tokens = shaped((rows, fields["seq_len"]), jnp.int32)
+
+    def weighted(p, x, y, scaled_probs):
+        return fam.example_loss(
+            fam.forward(p, None, x, arch, quantize), y) / scaled_probs
+
+    if program == "score":
+        return jax.jit(lambda p, x, y: weighted(p, x, y, 1.0)).lower(
+            params, tokens, tokens).compile()
+
+    def add_block(so_far, p, *block):
+        part = jax.value_and_grad(
+            lambda *a: jnp.sum(weighted(*a)))(p, *block)
+        return jax.tree.map(jnp.add, so_far, part)
+
+    return jax.jit(add_block, donate_argnums=0).lower(
+        (shaped((), jnp.float32), params), params, tokens, tokens,
+        shaped((rows,), jnp.float32)).compile()
+
+
+@pytest.mark.parametrize("program", ["score", "grad"])
+def test_token_cell_reference_blocks_fit_one_v5e(v5e_devices, program):
+    """The float32 reference of one row of 8,192 tokens, scored (20 s to
+    compile) and differentiated into the gradient sum (50 s): blocked
+    attention and a checkpoint a layer keep the peak well under the chip's
+    limit, parameters and the gradient sum included, beside nothing else
+    (the trainer is closed by then)."""
+    memory = _reference_block(v5e_devices, program).memory_analysis()
+    assert memory.peak_memory_in_bytes < HBM_LIMIT, memory
+    assert memory.temp_size_in_bytes < 8 * 2 ** 30, memory
