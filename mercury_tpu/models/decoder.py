@@ -25,6 +25,17 @@ not the kernel's (a head size or a length that is no multiple of 128) — a
 blockwise XLA form: a block of queries against the slice of keys its mask can
 reach, each block under ``jax.checkpoint``.
 
+**Routing.** Every token is routed over all the layer's experts; the (choice,
+token) pairs are sorted with those of the experts held here first, and every
+array that follows the sort (the gather of the tokens' rows, the grouped
+products' operands, the rows that return) has a static bound of rows, not
+``k * T``: twice what uniform routing gives a holder of ``held`` of ``E``
+experts (``models/moe.py::pair_bound``, from the shapes alone; a quarter of
+the pairs for 8 of 64). A row of tokens whose held pairs outnumber the bound
+runs over all ``k * T`` rows under the other arm of one ``lax.cond``: no pair
+is dropped and nothing has a capacity. A layer held whole has no bound and
+no ``cond``.
+
 **Precision.** Parameters in ``param_dtype``; matrix products in
 ``compute_dtype`` with float32 accumulation; the residual stream, RMSNorm,
 the rotation, softmax and the token loss in float32; the router's product
@@ -35,7 +46,8 @@ in a float32 forward.
 Scopes (metadata only): ``mercury_attention`` (projections, rotation, the
 attention), ``mercury_moe`` (router, grouping, expert products, return) with
 ``mercury_moe_route`` nested in it (``mercury_lm_head`` is the seam's). The
-last layer's routing is sowed into the ``MOE_LOAD`` collection.
+last layer's load and the share of all the routed layers that ran over the
+bounded rows are sowed into the ``MOE_LOAD`` collection.
 """
 
 from __future__ import annotations
@@ -289,15 +301,17 @@ class CausalDecoder(nn.Module):
             # of x itself, and fewer near-ties of its top-k fall the
             # other way
             x = embed[ids].astype(jnp.float32)
-            load = (jnp.zeros((), jnp.float32),) * 2
+            bounded = 0.0
             for i, block in enumerate(blocks):
-                x, load = jax.checkpoint(functools.partial(
+                x, (*load, fits) = jax.checkpoint(functools.partial(
                     self._layer, index=i, first_expert=first))(x, block)
-            return rms_norm(x, final_norm, w.norm_eps).astype(cd), load
+                bounded += fits / layers
+            return (rms_norm(x, final_norm, w.norm_eps).astype(cd),
+                    (*load, bounded))
 
         hidden, load = lax.map(row, tokens)
-        for name, value in zip(("held_pair_share", "load_max_over_mean"),
-                               load):
+        for name, value in zip(("held_pair_share", "load_max_over_mean",
+                                "bounded_share"), load):
             self.sow(MOE_LOAD, name, jnp.mean(value))
         return hidden, head.astype(cd)
 

@@ -26,6 +26,7 @@ returned alongside the output.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -186,44 +187,77 @@ class MoEMLP(nn.Module):
 
 
 # ----------------------------------------------- top-k over a share, no drops
-#: The flax collection a model sows its last routed layer's ``load`` into
-#: (:func:`routed_experts`): ``held_pair_share``, ``load_max_over_mean``.
+#: The flax collection a model sows its routed layers' ``load`` into
+#: (:func:`routed_experts`): ``held_pair_share``, ``load_max_over_mean``,
+#: ``bounded_share``.
 MOE_LOAD = "moe_load"
 
+#: The sorted pairs are cut to this many times the rows that uniform routing
+#: gives the holder of a share (``k * T * held / E``). A holder of an eighth
+#: read 0.4 to 1.5 times its uniform share over seeds and steps (PERF.md
+#: section 6), so twice leaves room and still cuts the rows to a quarter;
+#: past it the uncut rows run: the factor decides a speed, never a result.
+ROWS_OVER_UNIFORM = 2
+#: The cut is a whole number of these: a row tile of the grouped products.
+_ROW_TILE = 128
+
+
+def pair_bound(pairs: int, held: int, experts: int) -> int:
+    """The rows kept of ``pairs`` sorted (choice, token) pairs by a holder
+    of ``held`` of ``experts`` experts: ``ROWS_OVER_UNIFORM`` times its
+    uniform share, rounded up to a row tile; all of them where that is no
+    fewer (a layer held whole). A function of shapes alone."""
+    tiles = -(-ROWS_OVER_UNIFORM * pairs * held // (experts * _ROW_TILE))
+    return min(pairs, tiles * _ROW_TILE)
+
+
+def _by_slot(x, slot):
+    """A row of ``x [R, D]`` for each pair, by its sorted slot; the pairs
+    whose slot lies past the ``R`` rows kept read zero. With every row kept
+    (``R = k * T``) it is the permutation ``x[slot]``."""
+    rows = x.shape[0]
+    if rows == slot.shape[0]:
+        return x[slot]
+    return jnp.where((slot < rows)[:, None],
+                     x[jnp.minimum(slot, rows - 1)], 0)
+
 
 @jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation of the rows: its transpose is the
-    gather by the inverse, not a scatter."""
-    return x[perm]
+def _rows_back(x, slot, order):
+    """:func:`_by_slot` of ``x [R, D]``, whose row ``s`` is pair
+    ``order[s]``'s: its transpose is the gather of ``R`` rows by ``order``,
+    not a scatter."""
+    return _by_slot(x, slot)
 
 
-def _permute_fwd(x, perm, inverse):
-    return x[perm], inverse
+def _rows_back_fwd(x, slot, order):
+    return _by_slot(x, slot), order
 
 
-def _permute_bwd(inverse, g):
-    return g[inverse], None, None
+def _rows_back_bwd(order, g):
+    return g[order], None, None
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+_rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 
 
 @jax.custom_vjp
-def _rows_of_pairs(h, order, inverse):
-    """A row of ``h [T, D]`` for each of the ``k * T`` (choice, token)
-    pairs in sorted order (pair ``p`` is token ``p % T``); its transpose
-    sums each token's ``k`` rows, gathered by the inverse."""
+def _rows_of_pairs(h, order, slot):
+    """A row of ``h [T, D]`` for each of the first ``R`` of the ``k * T``
+    (choice, token) pairs in sorted order (``order [R]``; pair ``p`` is
+    token ``p % T``); its transpose sums each token's ``k`` rows, gathered
+    by ``slot``."""
     return h[order % h.shape[0]]
 
 
-def _rows_of_pairs_fwd(h, order, inverse):
-    return h[order % h.shape[0]], (inverse, h.shape[0])
+def _rows_of_pairs_fwd(h, order, slot):
+    return h[order % h.shape[0]], (slot, h.shape[0])
 
 
 def _rows_of_pairs_bwd(res, g):
-    inverse, t = res
-    return jnp.sum(g[inverse].reshape(-1, t, g.shape[-1]), axis=0), None, None
+    slot, t = res
+    return (jnp.sum(_by_slot(g, slot).reshape(-1, t, g.shape[-1]), axis=0),
+            None, None)
 
 
 _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
@@ -241,7 +275,9 @@ def route_top_k(router_logits, top_k: int, first_expert: int, held: int):
     expert with the pairs of experts held elsewhere last: ``order[s]`` is
     the pair in sorted slot ``s``, ``slot`` its inverse, ``group_sizes``
     the pairs of each held expert. Nothing has a capacity: every pair has
-    a slot."""
+    a slot, and the held pairs have the first ``sum(group_sizes)`` of them,
+    which is what lets :func:`routed_experts` cut the rows that follow the
+    sort to a bound and lose none."""
     with jax.named_scope("mercury_moe_route"):
         logits, chosen = lax.top_k(router_logits, top_k)
         weights = jax.nn.softmax(logits, axis=-1)
@@ -258,30 +294,20 @@ def route_top_k(router_logits, top_k: int, first_expert: int, held: int):
         return weights, slot, order, group_sizes, is_held
 
 
-def routed_experts(h, router_logits, gate, up, down, top_k: int,
-                   first_expert: int = 0):
-    """Top-k routed ReGLU experts over the share of them held here, with no
-    capacity and no dropped token, whatever the imbalance: ``h [T, D]``,
-    ``router_logits [T, E]`` float32 over all ``E`` experts, ``gate`` /
-    ``up`` ``[held, D, F]`` and ``down`` ``[held, F, D]`` the held experts'
-    weights. Every token is routed over all ``E``; the (token, expert)
-    pairs whose expert is held are grouped by expert and go through three
-    grouped matrix products (``lax.ragged_dot``; pairs of experts held
-    elsewhere lie past the last group and are not computed); the outputs
-    return to their tokens weighted. On one chip there is no exchange.
-    Returns ``(y [T, D] float32, load)`` with ``load = (held_pair_share,
-    load_max_over_mean)``: the share of pairs that fell on held experts
-    (``held / E`` at uniform routing) and the pairs of the busiest held
-    expert over the mean."""
+def _experts_of_rows(h, weights, gate, up, down, slot, order, group_sizes,
+                     is_held):
+    """The held experts' weighted outputs ``y [T, D]`` float32 from the
+    first ``R = order.shape[0]`` sorted pairs, which must hold every held
+    pair (``sum(group_sizes) <= R``): every array between the sort and the
+    return to the tokens has ``R`` rows."""
     t, d = h.shape
-    weights, slot, order, group_sizes, is_held = route_top_k(
-        router_logits, top_k, first_expert, gate.shape[0])
+    rows, top_k = order.shape[0], weights.shape[1]
     with jax.named_scope("mercury_moe_route"):
-        # A row of h for every pair, in sorted order: [k * T, D]. The rows
+        # A row of h for every pair kept, in sorted order: [R, D]. The rows
         # past the last group are no product's business, forward or
         # backward: the select keeps what a kernel leaves there out of h's
         # gradient.
-        in_a_group = (jnp.arange(t * top_k, dtype=jnp.int32)
+        in_a_group = (jnp.arange(rows, dtype=jnp.int32)
                       < jnp.sum(group_sizes))[:, None]
         pairs = jnp.where(in_a_group, _rows_of_pairs(h, order, slot), 0)
 
@@ -293,11 +319,99 @@ def routed_experts(h, router_logits, gate, up, down, top_k: int,
               * grouped(pairs, up)).astype(h.dtype)
     out = grouped(hidden, down).astype(h.dtype)
     with jax.named_scope("mercury_moe_route"):
-        back = _permute(out, slot, order).reshape(top_k, t, d)
+        # The return gathers a row for every pair out of the R (timed in
+        # the step against a scatter-add of the R weighted rows, which cost
+        # ten times the gather: PERF.md section 6, PR 40).
+        back = _rows_back(out, slot, order).reshape(top_k, t, d)
         # rows past the last group were never written: select, not scale
         back = jnp.where(is_held.T[..., None], back, 0)
-        y = jnp.sum(back * weights.T[..., None], axis=0)
+        return jnp.sum(back * weights.T[..., None], axis=0)
+
+
+def _cut(bound: int, routing):
+    """The routing's arrays ``(slot, order, group_sizes, is_held)`` with
+    the sorted pairs cut to the first ``bound``."""
+    slot, order, *rest = routing
+    return (slot, order[:bound], *rest)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _bounded_or_whole(bound: int, fits, floats, routing):
+    """:func:`_experts_of_rows` of ``(*floats, *routing)`` over ``bound``
+    rows where the held pairs fit in them (``fits``, a traced bool), over
+    all ``k * T`` where not: one ``lax.cond`` forward and one backward.
+    ``lax.cond``'s own derivative would keep both arms' residuals, zeros
+    for the arm not taken, and so write the ``k * T``-row arrays on the
+    bounded path after all; here the residuals are the operands, and the
+    arm taken is differentiated inside the backward ``cond`` (the caller's
+    ``jax.checkpoint`` recomputes the layer there anyway)."""
+    return lax.cond(
+        fits, lambda f, r: _experts_of_rows(*f, *_cut(bound, r)),
+        lambda f, r: _experts_of_rows(*f, *r), floats, routing)
+
+
+def _bounded_or_whole_fwd(bound, fits, floats, routing):
+    return (_bounded_or_whole(bound, fits, floats, routing),
+            (fits, floats, routing))
+
+
+def _bounded_or_whole_bwd(bound, res, g):
+    fits, floats, routing = res
+
+    def pull(cut):
+        def arm(g, floats, routing):
+            return jax.vjp(lambda *f: _experts_of_rows(*f, *cut(routing)),
+                           *floats)[1](g)
+        return arm
+
+    return (None, lax.cond(fits, pull(functools.partial(_cut, bound)),
+                           pull(tuple), g, floats, routing), None)
+
+
+_bounded_or_whole.defvjp(_bounded_or_whole_fwd, _bounded_or_whole_bwd)
+
+
+def routed_experts(h, router_logits, gate, up, down, top_k: int,
+                   first_expert: int = 0):
+    """Top-k routed ReGLU experts over the share of them held here, with no
+    capacity and no dropped token, whatever the imbalance: ``h [T, D]``,
+    ``router_logits [T, E]`` float32 over all ``E`` experts, ``gate`` /
+    ``up`` ``[held, D, F]`` and ``down`` ``[held, F, D]`` the held experts'
+    weights. Every token is routed over all ``E``; the (token, expert)
+    pairs whose expert is held are grouped by expert and go through three
+    grouped matrix products (``lax.ragged_dot``; pairs of experts held
+    elsewhere lie past the last group and are not computed); the outputs
+    return to their tokens weighted. On one chip there is no exchange.
+
+    **The bound.** The sort puts the held pairs first, so the gathers, the
+    selects, the casts and the products' operands that follow it have
+    ``C = pair_bound(k * T, held, E)`` rows, not ``k * T``: twice what
+    uniform routing gives this holder (``ROWS_OVER_UNIFORM``), from the
+    shapes alone. Where the held pairs of a row of tokens outnumber ``C``,
+    the same function runs over all ``k * T`` rows under the other arm of
+    one ``lax.cond``: the bound is no capacity, and the two arms compute
+    the same sums. A layer held whole (``held == E``) has ``C = k * T``
+    and traces no ``cond``.
+
+    Returns ``(y [T, D] float32, load)`` with ``load = (held_pair_share,
+    load_max_over_mean, bounded)``: the share of pairs that fell on held
+    experts (``held / E`` at uniform routing), the pairs of the busiest
+    held expert over the mean, and 1.0 where the bounded rows ran (0.0
+    where all ``k * T`` did; 1.0 where no ``cond`` was traced)."""
+    pairs, held = h.shape[0] * top_k, gate.shape[0]
+    weights, slot, order, group_sizes, is_held = route_top_k(
+        router_logits, top_k, first_expert, held)
+    floats = (h, weights, gate, up, down)
+    routing = (slot, order, group_sizes, is_held)
+    bound = pair_bound(pairs, held, router_logits.shape[-1])
+    if bound == pairs:
+        y, fits = _experts_of_rows(*floats, *routing), jnp.ones((), bool)
+    else:
+        fits = jnp.sum(group_sizes) <= bound
+        y = _bounded_or_whole(bound, fits, floats, routing)
+    with jax.named_scope("mercury_moe_route"):
         sizes = group_sizes.astype(jnp.float32)
-        load = (jnp.sum(sizes) / (t * top_k),
-                jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9))
+        load = (jnp.sum(sizes) / pairs,
+                jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9),
+                fits.astype(jnp.float32))
     return y, load

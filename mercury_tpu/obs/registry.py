@@ -31,6 +31,9 @@ METRIC_KEYS: Dict[str, str] = {
         "share of the (token, expert) pairs that fell on experts held here",
     "moe/load_max_over_mean":
         "pairs of the busiest held expert over the mean of the held ones",
+    "moe/bounded_share":
+        "share of the train pass's routed layers (all of them) whose held "
+        "pairs fit the bound on the sorted rows (1 where no bound is traced)",
     "train/eval_loss": "train-split eval loss (inference mode)",
     "train/eval_acc": "train-split eval accuracy (inference mode)",
     # test/* — eval pass over the held-out split

@@ -164,8 +164,8 @@ def _finish(ctx: StepContext, state, upd, drawn: Drawn, stream, ema, k_next,
         "train/sparse_rate": lax.pmean(upd["sparse_rate"], axis),
         "train/moe_aux": lax.pmean(upd["moe_aux"], axis),
     }
-    # Routing of the last layer of routed experts in the train pass
-    # (models/decoder.py sows it; no other model does).
+    # Routing of the routed experts in the train pass (models/decoder.py
+    # sows it; no other model does).
     for name, value in upd["moe_load"].items():
         metrics[f"moe/{name}"] = lax.pmean(value, axis)
     if mode.telemetry:

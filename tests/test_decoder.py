@@ -14,7 +14,7 @@ from chip_smoke import register_tiny_lm
 from mercury_tpu import TrainConfig
 from mercury_tpu.data.tokens import zipf_tokens
 from mercury_tpu.models import LM_WIDTHS, create_model
-from mercury_tpu.models import decoder
+from mercury_tpu.models import decoder, moe
 from mercury_tpu.models.moe import route_top_k, routed_experts
 from mercury_tpu.sampling.importance import (
     sequence_loss,
@@ -122,12 +122,12 @@ def test_the_shares_of_four_holders_add_up_to_the_uncut_layer():
     h = jnp.asarray(rng.standard_normal((T, 64)), jnp.float32)
     r = jnp.asarray(rng.standard_normal((T, 16)), jnp.float32)
     gate, up, down = _experts(16)
-    whole, (share, _) = routed_experts(h, r, gate, up, down, 3, 0)
+    whole, (share, *_) = routed_experts(h, r, gate, up, down, 3, 0)
     assert float(share) == 1.0
     parts = 0.0
     for first in range(0, 16, 4):
         held = slice(first, first + 4)
-        y, (share, _) = routed_experts(h, r, gate[held], up[held],
+        y, (share, *_) = routed_experts(h, r, gate[held], up[held],
                                        down[held], 3, first)
         parts = parts + y
         assert 0.0 < float(share) < 1.0
@@ -152,7 +152,7 @@ def test_no_token_is_dropped_when_one_held_expert_takes_them_all():
     gate, up, down = _experts(4)
     weights, _, _, sizes, is_held = route_top_k(r, 3, 0, 4)
     assert int(sizes[1]) == T and int(sizes[2]) == int(sizes[3]) == 0
-    y, (_, busiest) = routed_experts(h, r, gate, up, down, 3, 0)
+    y, (_, busiest, _) = routed_experts(h, r, gate, up, down, 3, 0)
     # the held choices are expert 1 and, for some tokens, expert 0
     _, chosen = jax.lax.top_k(r, 3)
     per_choice = jnp.stack([jnp.stack(
@@ -166,6 +166,152 @@ def test_no_token_is_dropped_when_one_held_expert_takes_them_all():
     g = jax.grad(lambda gate: jnp.sum(routed_experts(
         h, r, gate, up, down, 3, 0)[0]))(gate)
     assert float(jnp.abs(g[1]).max()) > 0 and float(jnp.abs(g[2]).max()) == 0
+
+
+# The bound on the rows that follow the sort: 2 of 16 experts held at top-3
+# and T = 256 is a bound of 2 x 768 x 2 / 16 = 192 -> 256 rows under the 768
+# pairs (at the file's T = 32 the bound is all 96 and the cases above trace
+# the uncut program).
+LONG_T, HELD, FIRST = 256, 2, 4
+
+
+def _a_holder_of_two(seed, forced=False):
+    rng = np.random.default_rng(seed)
+    h = jnp.asarray(rng.standard_normal((LONG_T, 64)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((LONG_T, 16)), jnp.float32)
+    if forced:      # every token's first two choices are the held experts
+        r = r.at[:, FIRST:FIRST + HELD].add(10.0)
+    return (h, r) + _experts(HELD, seed)
+
+
+def _dense_over_held(h, r, gate, up, down):
+    logits, chosen = jax.lax.top_k(r, 3)
+    w = jax.nn.softmax(logits, -1)
+    return sum(
+        jnp.sum(jnp.where(chosen == FIRST + e, w, 0.0), -1)[:, None]
+        * ((jax.nn.relu(h @ gate[e]) * (h @ up[e])) @ down[e])
+        for e in range(HELD))
+
+
+def _value_and_grads(fn, args):
+    def loss(*a):
+        y, load = fn(*a, 3, FIRST)
+        return jnp.sum(jnp.square(y)), load
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+        *args)
+
+
+@pytest.mark.parametrize("forced, bounded", [(False, 1.0), (True, 0.0)],
+                         ids=["under-the-bound", "over-the-bound"])
+def test_the_bounded_rows_and_the_uncut_ones_are_one_sum(monkeypatch, forced,
+                                                         bounded):
+    """Held pairs under the bound (a share near 2/16 against 1/3 of the
+    pairs) take the bounded rows, held pairs over it (every token's first
+    two choices forced onto the two held experts: 2/3) all ``k * T``: both
+    give the dense sum over the chosen and held experts, no pair dropped,
+    and the values and the gradients to ``h``, the router's logits and the
+    three weights that the uncut program gives on the same inputs."""
+    args = _a_holder_of_two(7, forced)
+    assert moe.pair_bound(3 * LONG_T, HELD, 16) == 256
+    (got, (share, _, fits)), grads = _value_and_grads(routed_experts, args)
+    assert float(fits) == bounded
+    assert (float(share) * 3 * LONG_T <= 256) == bool(bounded)
+    if forced:
+        assert float(share) == pytest.approx(2 / 3)
+    y, _ = routed_experts(*args, 3, FIRST)
+    np.testing.assert_allclose(y, _dense_over_held(*args), atol=1e-5)
+    # the uncut program: a factor under which the bound is all the pairs
+    monkeypatch.setattr(moe, "ROWS_OVER_UNIFORM", 8)
+    assert moe.pair_bound(3 * LONG_T, HELD, 16) == 3 * LONG_T
+    (want, (_, _, fits)), uncut = _value_and_grads(routed_experts, args)
+    assert float(fits) == 1.0
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name, a, b in zip(("h", "router", "gate", "up", "down"), grads,
+                          uncut):
+        assert float(jnp.abs(b).max()) > 0, name
+        np.testing.assert_allclose(a, b, atol=1e-5, err_msg=name)
+
+
+def _primitives(jaxpr, arm=None):
+    """Every equation of a jaxpr and of the jaxprs inside it; of a ``cond``
+    only branch ``arm`` where one is named."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        inner = []
+        for name, value in eqn.params.items():
+            if eqn.primitive.name == "cond" and name == "branches" \
+                    and arm is not None:
+                value = [value[arm]]
+            for v in value if isinstance(value, (tuple, list)) else [value]:
+                v = getattr(v, "jaxpr", v)
+                if hasattr(v, "eqns"):
+                    inner.append(v)
+        for sub in inner:
+            yield from _primitives(sub, arm)
+
+
+def test_a_layer_held_whole_traces_no_cond():
+    """``held == E``: the bound is all the pairs, the program is the uncut
+    one and holds no ``cond``, forward or differentiated; a holder of a
+    share under the bound has one forward and one more backward."""
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.standard_normal((LONG_T, 64)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((LONG_T, 16)), jnp.float32)
+
+    def conds(experts, first):
+        def loss(h, r, *w):
+            return jnp.sum(routed_experts(h, r, *w, 3, first)[0])
+        fwd = jax.make_jaxpr(loss)(h, r, *experts).jaxpr
+        bwd = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+            h, r, *experts).jaxpr
+        return [sum(e.primitive.name == "cond" for e in _primitives(j))
+                for j in (fwd, bwd)]
+
+    assert conds(_experts(16), 0) == [0, 0]
+    assert conds(_experts(HELD), FIRST) == [1, 2]
+
+
+def test_the_bounded_arm_holds_no_array_of_all_the_pairs_but_the_return():
+    """A walk over the jaxpr of the bounded arm, forward and ``jax.grad``
+    (top level and the ``cond``s' bounded branches; T = 512, so that the
+    bound, 384 rows of the 1,536 pairs, is neither T nor ``k * T``). The
+    grouped products see ``C`` rows, nothing of ``F`` columns has ``k * T``
+    rows, and what has ``k * T`` rows of ``D`` columns is the return to the
+    tokens alone: the gather of every pair's row out of the experts' ``[C,
+    D]`` output with its select and weighting and, backward, the same
+    gather of the rows' gradients out of ``[C, D]`` on their way to ``h``.
+    No gather reads ``k * T`` rows of ``h`` itself, and the backward pass
+    keeps no residual of the arm not taken (``lax.cond``'s own derivative
+    would)."""
+    t, k, d, f = 512, 3, 64, 32
+    rng = np.random.default_rng(11)
+    h = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((t, 16)), jnp.float32)
+    experts = _experts(HELD, 11)
+    bound = moe.pair_bound(k * t, HELD, 16)
+    assert bound == 384
+
+    def loss(*a):
+        return jnp.sum(routed_experts(*a, k, FIRST)[0])
+
+    def rows(shape):
+        return int(np.prod(shape[:-1])) if len(shape) >= 2 else 0
+
+    for fn, gathers in ((loss, 1),
+                        (jax.grad(loss, argnums=(0, 1, 2, 3, 4)), 3)):
+        jaxpr = jax.make_jaxpr(fn)(h, r, *experts).jaxpr
+        seen = 0
+        for eqn in _primitives(jaxpr, arm=1):
+            ins = [v.aval.shape for v in eqn.invars if hasattr(v, "aval")]
+            outs = [v.aval.shape for v in eqn.outvars]
+            for shape in ins + outs:
+                assert not (rows(shape) == k * t and shape[-1] == f), eqn
+            if eqn.primitive.name.startswith("ragged_dot"):
+                assert all(k * t not in shape for shape in ins + outs), eqn
+            if eqn.primitive.name == "gather" and outs[0] == (k * t, d):
+                assert ins[0] == (bound, d), eqn
+                seen += 1
+        assert seen == gathers
 
 
 # ---------------------------------------------------------------- the seam
@@ -290,10 +436,12 @@ def test_fit_on_the_token_dataset(world):
         events = t.tracer.snapshot()
     assert len(records) == 2 and np.isfinite(records[-1]["train/loss"])
     assert 0.0 < records[-1]["moe/held_pair_share"] <= 1.0
+    assert 0.0 <= records[-1]["moe/bounded_share"] <= 1.0
     loads = [e for e in events if e["name"] == "trainer/moe_load"]
     assert len(loads) == 2
-    assert loads[-1]["args"]["held_pair_share"] == pytest.approx(
-        records[-1]["moe/held_pair_share"])
+    for name in ("held_pair_share", "bounded_share"):
+        assert loads[-1]["args"][name] == pytest.approx(
+            records[-1][f"moe/{name}"])
     units = [e for e in events if e["name"] == "trainer/bn_moment_units"]
     assert units and units[-1]["args"]["units"] == 0
 
