@@ -10,8 +10,9 @@ seeded synthetic data, and checks what comes out:
   under ln 10 at the end), the four eval keys, a save → restore round trip,
   20 steps of the uniform arm, three ``scan_steps=25`` chunks, ten
   pipelined steps of the token path (``smallthinker-tiny`` on
-  ``tokens_zipf``: rows of ids, a per-sequence loss), zero compiles after
-  each trainer's first call;
+  ``tokens_zipf``: rows of ids, a per-sequence loss) and ten more through
+  the decoder's other mixer and routing rule (``latent-tiny``), zero
+  compiles after each trainer's first call;
 - the Pallas kernels really compiled (Mosaic custom call in the compiled
   step, nothing in interpret mode) and each matches its jax-native twin
   standalone on the chip, at the shapes ``Trainer`` produces;
@@ -356,6 +357,16 @@ def run(tiny: bool = False) -> Dict[str, Any]:
         model=register_tiny_lm(), dataset="tokens_zipf",
         model_cut=(4, 0, 4), num_classes=96, seq_len=32, batch_size=2,
         presample_batches=3, augmentation="none", pipelined_scoring=True)
+    # The same path through the other mixer and the other routing rule
+    # (latent attention, a sigmoid router with a selection bias, a shared
+    # expert, a leading dense layer) on a share of the heads: heads of
+    # 128 + 64 against 128 at T = 128 are the splash kernel's on the chip.
+    out["one_chip_latent_tokens"] = _train_phase(
+        "one_chip_latent_tokens", tiny, 10, loss_below=5.0, world_size=1,
+        model=register_tiny_latent_lm(), dataset="tokens_zipf",
+        model_cut=(3, 0, 4, 0, 2), num_classes=96, seq_len=128,
+        batch_size=2, presample_batches=3, augmentation="none",
+        pipelined_scoring=True)
     out["kernels"] = _kernel_phase(tiny)
     if len(jax.devices()) >= 4:
         four = _train_phase("four_chip_is", tiny, steps,
@@ -378,6 +389,24 @@ def register_tiny_lm() -> str:
         num_experts=16, top_k=3, expert_width=32, window=8,
         rope_theta=10_000.0))
     return "smallthinker-tiny"
+
+
+def register_tiny_latent_lm() -> str:
+    """:func:`register_tiny_lm`'s twin for the decoder's other mixer and
+    routing rule: latent attention with heads of 128 + 64 against 128 (the
+    kernel's, where the sequence is a multiple of 128), a sigmoid router
+    with a selection bias over 16 SwiGLU experts, a shared expert, one
+    leading dense layer. Returns its name."""
+    from mercury_tpu.models.decoder import LM_WIDTHS, Latent, LMWidths
+
+    LM_WIDTHS.setdefault("latent-tiny", LMWidths(
+        num_layers=3, d_model=64, num_heads=4, num_kv_heads=4, head_dim=128,
+        num_experts=16, top_k=3, expert_width=32, window=None,
+        rope_theta=10_000.0, latent=Latent(kv_rank=32, rope_dim=64,
+                                           v_head_dim=128),
+        router="sigmoid", routed_scale=2.448, activation="silu",
+        shared_width=64, dense_layers=1, dense_width=96))
+    return "latent-tiny"
 
 
 def main() -> int:

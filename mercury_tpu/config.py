@@ -30,10 +30,12 @@ class TrainConfig:
                                      # array datasets carry their own shapes
     # Token models and datasets (models/decoder.py, data/tokens.py). The
     # model's name selects the published widths; what a chip holds of it is
-    # the cut: (layers kept, first expert held, experts held), None = whole.
+    # the cut: (layers kept, first expert held, experts held) or, with a
+    # share of the attention heads too, (layers, first expert, experts,
+    # first head, heads); None = whole.
     # The vocabulary rows held are ``num_classes`` (None: the dataset's own
     # slice); ``seq_len`` is the length of the token dataset's sequences.
-    model_cut: Optional[Tuple[int, int, int]] = None
+    model_cut: Optional[Tuple[int, ...]] = None
     seq_len: int = 8192
 
     # Parallelism -----------------------------------------------------------

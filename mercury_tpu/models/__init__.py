@@ -58,9 +58,10 @@ def create_model(
 
     Names: ``resnet18/34/50/101/152``, ``vgg11/13/16/19``, ``mobilenetv2``,
     ``bilstm_attention``, ``transformer``, ``vit``, and the causal decoders
-    of ``LM_WIDTHS`` (``smallthinker-21b-a3b``: the name selects the
-    published widths, ``num_classes`` the vocabulary rows held, ``cut`` the
-    layers and experts held). ``bn_axis_name`` enables
+    of ``LM_WIDTHS`` (``smallthinker-21b-a3b``, ``kanana-2-30b-a3b``: the
+    name selects the published widths and with them the mixer and the
+    routing rule, ``num_classes`` the vocabulary rows held, ``cut`` the
+    layers, experts and heads held). ``bn_axis_name`` enables
     cross-replica synced BatchNorm over the given mesh axis (ignored by
     models without BN).
     """

@@ -189,7 +189,7 @@ class MoEMLP(nn.Module):
 # ----------------------------------------------- top-k over a share, no drops
 #: The flax collection a model sows its routed layers' ``load`` into
 #: (:func:`routed_experts`): ``held_pair_share``, ``load_max_over_mean``,
-#: ``bounded_share``.
+#: ``bounded_share``, and ``bias_moved_share`` where the router has a bias.
 MOE_LOAD = "moe_load"
 
 #: The sorted pairs are cut to this many times the rows that uniform routing
@@ -198,6 +198,14 @@ MOE_LOAD = "moe_load"
 #: section 6), so twice leaves room and still cuts the rows to a quarter;
 #: past it the uncut rows run: the factor decides a speed, never a result.
 ROWS_OVER_UNIFORM = 2
+#: And to no fewer than one in this many of the pairs. A thinner holder's
+#: share swings by more of itself (8 of 128 experts under the sigmoid rule
+#: read 0.05 to 1.9 times the uniform sixteenth at the log gates, and on one
+#: seed of six a layer passed twice it in most rows: 5 % of the step, PERF.md
+#: section 6: PR 41's chip runs), and what the bound cuts is by then the
+#: lesser part of the routing: ``top_k``, the sorts and the router's product
+#: run over all ``k * T`` pairs whatever the bound.
+ROWS_AT_LEAST_ONE_IN = 4
 #: The cut is a whole number of these: a row tile of the grouped products.
 _ROW_TILE = 128
 
@@ -205,10 +213,12 @@ _ROW_TILE = 128
 def pair_bound(pairs: int, held: int, experts: int) -> int:
     """The rows kept of ``pairs`` sorted (choice, token) pairs by a holder
     of ``held`` of ``experts`` experts: ``ROWS_OVER_UNIFORM`` times its
-    uniform share, rounded up to a row tile; all of them where that is no
-    fewer (a layer held whole). A function of shapes alone."""
-    tiles = -(-ROWS_OVER_UNIFORM * pairs * held // (experts * _ROW_TILE))
-    return min(pairs, tiles * _ROW_TILE)
+    uniform share and no fewer than one in ``ROWS_AT_LEAST_ONE_IN``, rounded
+    up to a row tile; all of them where that is no fewer (a layer held
+    whole). A function of shapes alone."""
+    rows = max(-(-ROWS_OVER_UNIFORM * pairs * held // experts),
+               -(-pairs // ROWS_AT_LEAST_ONE_IN))
+    return min(pairs, -(-rows // _ROW_TILE) * _ROW_TILE)
 
 
 def _by_slot(x, slot):
@@ -263,13 +273,24 @@ def _rows_of_pairs_bwd(res, g):
 _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
 
 
-def route_top_k(router_logits, top_k: int, first_expert: int, held: int):
+def route_top_k(router_logits, top_k: int, first_expert: int, held: int,
+                bias=None, scale: float = 1.0):
     """Top-k routing of ``[T, E]`` float32 router logits over ALL ``E``
     experts, for a layer that holds experts ``first_expert ..
     first_expert + held - 1``: ``(weights [T, k], slot [k * T], order
-    [k * T], group_sizes [held], is_held [T, k])``. The weights are the
-    softmax over the ``k`` chosen logits (ties to the lower index). The
-    ``k * T`` (choice, token) pairs, choice-major (pair ``c * T + t`` is
+    [k * T], group_sizes [held], is_held [T, k])``, and with a ``bias`` a
+    sixth, ``bias_moved``.
+
+    **Two rules** of choice and weight, by ``bias``. None: the ``k``
+    largest logits, weighted by the softmax over those ``k`` (ties to the
+    lower index). A ``bias [E]`` (DeepSeek-V3's ``noaux_tc``): scores
+    ``s = sigmoid(logits)``; the ``k`` largest of ``s + bias`` are chosen,
+    and weighted by the UNBIASED scores, ``scale * s_i / (sum of the
+    chosen s_j + 1e-20)``: the bias enters the choice alone, so its
+    gradient is zero. ``bias_moved`` is the share of the (token, choice)
+    pairs that ``s`` alone would not have chosen.
+
+    The ``k * T`` (choice, token) pairs, choice-major (pair ``c * T + t`` is
     token ``t``'s ``c``-th choice: a ``[k, T, D]`` array of their rows has
     whole tiles, which ``[T, k, D]`` at ``k = 6`` has not), are sorted by
     expert with the pairs of experts held elsewhere last: ``order[s]`` is
@@ -279,8 +300,20 @@ def route_top_k(router_logits, top_k: int, first_expert: int, held: int):
     which is what lets :func:`routed_experts` cut the rows that follow the
     sort to a bound and lose none."""
     with jax.named_scope("mercury_moe_route"):
-        logits, chosen = lax.top_k(router_logits, top_k)
-        weights = jax.nn.softmax(logits, axis=-1)
+        moved = ()
+        if bias is None:
+            logits, chosen = lax.top_k(router_logits, top_k)
+            weights = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(router_logits)
+            _, chosen = lax.top_k(scores + bias.astype(scores.dtype), top_k)
+            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            weights = scale * picked / (
+                jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+            # a chosen expert that k or more unbiased scores lie above is
+            # one the bias brought in
+            above = jnp.sum(scores[:, None, :] > picked[:, :, None], axis=-1)
+            moved = (jnp.mean((above >= top_k).astype(jnp.float32)),)
         local = chosen - first_expert
         is_held = (local >= 0) & (local < held)
         key = jnp.where(is_held, local, held).T.reshape(-1)
@@ -291,15 +324,15 @@ def route_top_k(router_logits, top_k: int, first_expert: int, held: int):
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :],
             axis=0, dtype=jnp.int32)
-        return weights, slot, order, group_sizes, is_held
+        return (weights, slot, order, group_sizes, is_held, *moved)
 
 
-def _experts_of_rows(h, weights, gate, up, down, slot, order, group_sizes,
-                     is_held):
+def _experts_of_rows(activation, h, weights, gate, up, down, slot, order,
+                     group_sizes, is_held):
     """The held experts' weighted outputs ``y [T, D]`` float32 from the
     first ``R = order.shape[0]`` sorted pairs, which must hold every held
     pair (``sum(group_sizes) <= R``): every array between the sort and the
-    return to the tokens has ``R`` rows."""
+    return to the tokens has ``R`` rows. ``activation`` is the gate's."""
     t, d = h.shape
     rows, top_k = order.shape[0], weights.shape[1]
     with jax.named_scope("mercury_moe_route"):
@@ -315,7 +348,7 @@ def _experts_of_rows(h, weights, gate, up, down, slot, order, group_sizes,
         return lax.ragged_dot(x, w, group_sizes,
                               preferred_element_type=jnp.float32)
 
-    hidden = (jax.nn.relu(grouped(pairs, gate))
+    hidden = (activation(grouped(pairs, gate))
               * grouped(pairs, up)).astype(h.dtype)
     out = grouped(hidden, down).astype(h.dtype)
     with jax.named_scope("mercury_moe_route"):
@@ -335,8 +368,8 @@ def _cut(bound: int, routing):
     return (slot, order[:bound], *rest)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _bounded_or_whole(bound: int, fits, floats, routing):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _bounded_or_whole(bound: int, activation, fits, floats, routing):
     """:func:`_experts_of_rows` of ``(*floats, *routing)`` over ``bound``
     rows where the held pairs fit in them (``fits``, a traced bool), over
     all ``k * T`` where not: one ``lax.cond`` forward and one backward.
@@ -346,22 +379,23 @@ def _bounded_or_whole(bound: int, fits, floats, routing):
     arm taken is differentiated inside the backward ``cond`` (the caller's
     ``jax.checkpoint`` recomputes the layer there anyway)."""
     return lax.cond(
-        fits, lambda f, r: _experts_of_rows(*f, *_cut(bound, r)),
-        lambda f, r: _experts_of_rows(*f, *r), floats, routing)
+        fits, lambda f, r: _experts_of_rows(activation, *f, *_cut(bound, r)),
+        lambda f, r: _experts_of_rows(activation, *f, *r), floats, routing)
 
 
-def _bounded_or_whole_fwd(bound, fits, floats, routing):
-    return (_bounded_or_whole(bound, fits, floats, routing),
+def _bounded_or_whole_fwd(bound, activation, fits, floats, routing):
+    return (_bounded_or_whole(bound, activation, fits, floats, routing),
             (fits, floats, routing))
 
 
-def _bounded_or_whole_bwd(bound, res, g):
+def _bounded_or_whole_bwd(bound, activation, res, g):
     fits, floats, routing = res
 
     def pull(cut):
         def arm(g, floats, routing):
-            return jax.vjp(lambda *f: _experts_of_rows(*f, *cut(routing)),
-                           *floats)[1](g)
+            return jax.vjp(
+                lambda *f: _experts_of_rows(activation, *f, *cut(routing)),
+                *floats)[1](g)
         return arm
 
     return (None, lax.cond(fits, pull(functools.partial(_cut, bound)),
@@ -371,23 +405,42 @@ def _bounded_or_whole_bwd(bound, res, g):
 _bounded_or_whole.defvjp(_bounded_or_whole_fwd, _bounded_or_whole_bwd)
 
 
+def gated_mlp(h, gate, up, down, activation):
+    """``(activation(h W_gate) * (h W_up)) W_down`` of every token: a dense
+    layer's MLP, and the shared experts beside the routed ones (as one MLP
+    of their widths together). ``h [T, D]`` in the compute precision ->
+    ``[T, D]`` float32."""
+    def dot(x, w):
+        return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+    hidden = (activation(dot(h, gate)) * dot(h, up)).astype(h.dtype)
+    return dot(hidden, down)
+
+
 def routed_experts(h, router_logits, gate, up, down, top_k: int,
-                   first_expert: int = 0):
-    """Top-k routed ReGLU experts over the share of them held here, with no
-    capacity and no dropped token, whatever the imbalance: ``h [T, D]``,
-    ``router_logits [T, E]`` float32 over all ``E`` experts, ``gate`` /
-    ``up`` ``[held, D, F]`` and ``down`` ``[held, F, D]`` the held experts'
-    weights. Every token is routed over all ``E``; the (token, expert)
-    pairs whose expert is held are grouped by expert and go through three
-    grouped matrix products (``lax.ragged_dot``; pairs of experts held
-    elsewhere lie past the last group and are not computed); the outputs
-    return to their tokens weighted. On one chip there is no exchange.
+                   first_expert: int = 0, *, bias=None, scale: float = 1.0,
+                   activation=jax.nn.relu, shared=None):
+    """Top-k routed gated experts (ReGLU, or ``activation``'s) over the
+    share of them held here, with no capacity and no dropped token,
+    whatever the imbalance: ``h [T, D]``, ``router_logits [T, E]`` float32
+    over all ``E`` experts, ``gate`` / ``up`` ``[held, D, F]`` and ``down``
+    ``[held, F, D]`` the held experts' weights. Every token is routed over
+    all ``E`` by one of :func:`route_top_k`'s two rules (``bias`` and
+    ``scale`` are the second's); the (token, expert) pairs whose expert is
+    held are grouped by expert and go through three grouped matrix
+    products (``lax.ragged_dot``; pairs of experts held elsewhere lie past
+    the last group and are not computed); the outputs return to their
+    tokens weighted. On one chip there is no exchange. ``shared`` (``(gate
+    [D, S], up [D, S], down [S, D])``) is the MLP every token goes through
+    beside its routed experts, added unweighted: every holder of a share
+    computes it alike.
 
     **The bound.** The sort puts the held pairs first, so the gathers, the
     selects, the casts and the products' operands that follow it have
     ``C = pair_bound(k * T, held, E)`` rows, not ``k * T``: twice what
-    uniform routing gives this holder (``ROWS_OVER_UNIFORM``), from the
-    shapes alone. Where the held pairs of a row of tokens outnumber ``C``,
+    uniform routing gives this holder (``ROWS_OVER_UNIFORM``) and no fewer
+    than a quarter of the pairs (``ROWS_AT_LEAST_ONE_IN``), from the shapes
+    alone. Where the held pairs of a row of tokens outnumber ``C``,
     the same function runs over all ``k * T`` rows under the other arm of
     one ``lax.cond``: the bound is no capacity, and the two arms compute
     the same sums. A layer held whole (``held == E``) has ``C = k * T``
@@ -397,21 +450,27 @@ def routed_experts(h, router_logits, gate, up, down, top_k: int,
     load_max_over_mean, bounded)``: the share of pairs that fell on held
     experts (``held / E`` at uniform routing), the pairs of the busiest
     held expert over the mean, and 1.0 where the bounded rows ran (0.0
-    where all ``k * T`` did; 1.0 where no ``cond`` was traced)."""
+    where all ``k * T`` did; 1.0 where no ``cond`` was traced); under the
+    rule with a bias a fourth, ``bias_moved_share`` (the share of the
+    pairs that the unbiased scores would not have chosen)."""
     pairs, held = h.shape[0] * top_k, gate.shape[0]
-    weights, slot, order, group_sizes, is_held = route_top_k(
-        router_logits, top_k, first_expert, held)
+    weights, slot, order, group_sizes, is_held, *moved = route_top_k(
+        router_logits, top_k, first_expert, held, bias, scale)
     floats = (h, weights, gate, up, down)
     routing = (slot, order, group_sizes, is_held)
     bound = pair_bound(pairs, held, router_logits.shape[-1])
     if bound == pairs:
-        y, fits = _experts_of_rows(*floats, *routing), jnp.ones((), bool)
+        y = _experts_of_rows(activation, *floats, *routing)
+        fits = jnp.ones((), bool)
     else:
         fits = jnp.sum(group_sizes) <= bound
-        y = _bounded_or_whole(bound, fits, floats, routing)
+        y = _bounded_or_whole(bound, activation, fits, floats, routing)
+    if shared is not None:
+        with jax.named_scope("mercury_moe_shared"):
+            y = y + gated_mlp(h, *shared, activation)
     with jax.named_scope("mercury_moe_route"):
         sizes = group_sizes.astype(jnp.float32)
         load = (jnp.sum(sizes) / pairs,
                 jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9),
-                fits.astype(jnp.float32))
+                fits.astype(jnp.float32), *moved)
     return y, load

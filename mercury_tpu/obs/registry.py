@@ -34,6 +34,9 @@ METRIC_KEYS: Dict[str, str] = {
     "moe/bounded_share":
         "share of the train pass's routed layers (all of them) whose held "
         "pairs fit the bound on the sorted rows (1 where no bound is traced)",
+    "moe/bias_moved_share":
+        "share of the (token, choice) pairs that the router's selection bias "
+        "chose and the unbiased scores would not (sigmoid routers only)",
     "train/eval_loss": "train-split eval loss (inference mode)",
     "train/eval_acc": "train-split eval accuracy (inference mode)",
     # test/* — eval pass over the held-out split

@@ -169,8 +169,9 @@ def _apply(ctx: StepContext, module, params, batch_stats, images,
 def moe_load(model_state) -> Dict[str, jax.Array]:
     """What a model of routed experts sowed of its routing
     (``models/decoder.py``): ``{"held_pair_share": ...,
-    "load_max_over_mean": ..., "bounded_share": ...}``, empty for every
-    other model."""
+    "load_max_over_mean": ..., "bounded_share": ...}``, with
+    ``"bias_moved_share"`` where its router has a selection bias; empty for
+    every other model."""
     return {name: value[-1] for name, value in
             model_state.get(MOE_LOAD, {}).items()}
 

@@ -1395,15 +1395,14 @@ class Trainer:
     def _note_moe_load(self, record: Dict[str, Any]) -> None:
         """The instant ``trainer/moe_load``: what share of the (token,
         expert) pairs fell on the experts held here, the busiest held
-        expert's pairs over the mean, and what share of the routed layers
-        ran over the bounded rows (``models/moe.py::routed_experts``), in
-        the train pass of the logged step."""
-        if "moe/held_pair_share" in record:
-            self.tracer.instant(
-                "trainer/moe_load", cat="trainer",
-                held_pair_share=float(record["moe/held_pair_share"]),
-                load_max_over_mean=float(record["moe/load_max_over_mean"]),
-                bounded_share=float(record["moe/bounded_share"]))
+        expert's pairs over the mean, what share of the routed layers
+        ran over the bounded rows (``models/moe.py::routed_experts``) and,
+        where the router has a selection bias, what share of the pairs the
+        bias chose, in the train pass of the logged step."""
+        load = {key[len("moe/"):]: float(value)
+                for key, value in record.items() if key.startswith("moe/")}
+        if load:
+            self.tracer.instant("trainer/moe_load", cat="trainer", **load)
 
     def _fit(self, num_epochs: Optional[int]) -> Dict[str, float]:
         cfg = self.config

@@ -19,7 +19,8 @@ def test_body_runs_tiny_on_the_cpu_mesh():
     Any failed check raises."""
     out = chip_smoke.run(tiny=True)
     assert set(out) == {"one_chip_is", "one_chip_uniform", "one_chip_scan",
-                        "one_chip_tokens", "kernels", "four_chip_is"}
+                        "one_chip_tokens", "one_chip_latent_tokens", "kernels",
+                        "four_chip_is"}
     for name, facts in out.items():
         if name != "kernels":
             assert facts["compiles_after_first"] == 0, name

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chip_smoke import register_tiny_lm
+from chip_smoke import register_tiny_latent_lm, register_tiny_lm
 from mercury_tpu import TrainConfig
 from mercury_tpu.data.tokens import zipf_tokens
 from mercury_tpu.models import LM_WIDTHS, create_model
@@ -25,6 +25,11 @@ from mercury_tpu.train.stages import row_fns
 
 TINY = register_tiny_lm()
 WIDTHS = LM_WIDTHS[TINY]
+#: The decoder's other mixer and routing rule at the CPU's size: latent
+#: attention (4 heads of 128 + 64 against 128 over a latent of 32), a
+#: sigmoid router with a selection bias over 16 SwiGLU experts top-3, a
+#: shared expert of 64, one leading dense layer of 96.
+LATENT = register_tiny_latent_lm()
 VOCAB, T = 96, 32
 
 
@@ -467,3 +472,199 @@ def test_uniform_sampling_takes_token_rows_too():
                          pipelined_scoring=False, trace=False)) as t:
         out = t.fit(num_epochs=3)
     assert np.isfinite(out["test/eval_loss"])
+
+
+# --------------------------------- the other mixer and the other routing rule
+def test_the_kernel_takes_latent_heads_and_is_the_blockwise_form():
+    """The kept route of latent attention on the TPU: the splash kernel
+    (interpreted here) with queries and keys of 192 = 128 + 64 against
+    values of 128, one query head to each key/value head, against the XLA
+    form; forward and gradient. A head that is no multiple of 64, or
+    values that are no multiple of 128, stay with the XLA form."""
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.standard_normal((2, 1, 256, 192)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, 256, 192)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, 256, 128)), jnp.float32)
+    q = q * 192 ** -0.5
+    assert decoder.splash_takes(256, 192, 128)
+    assert not decoder.splash_takes(256, 192, 64)
+    assert not decoder.splash_takes(256, 96, 128)
+    want, gw = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(
+        decoder.blockwise_attention(*a, None, 128))), (0, 1, 2))(q, k, v)
+    got, gg = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(
+        decoder.splash_attention(*a, None))), (0, 1, 2))(q, k, v)
+    assert got.shape == () and float(want) > 0
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for a, b in zip(gg, gw):
+        np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+def test_rotating_halves_of_permuted_columns_is_the_interleaved_rotation():
+    """Latent attention rotates interleaved pairs ``(2i, 2i + 1)``; the
+    program permutes the rotated columns once and rotates halves. The
+    scores of a query and a key are those of the interleaved rotation
+    written straight."""
+    rng = np.random.default_rng(4)
+    x, y = (jnp.asarray(rng.standard_normal((T, 2, 8)), jnp.float32)
+            for _ in range(2))
+
+    def interleaved(a, theta=10_000.0):
+        inv = 1.0 / theta ** (np.arange(0, 8, 2) / 8)
+        angle = np.arange(T)[:, None, None] * inv[None, None, :]
+        even, odd = a[..., 0::2], a[..., 1::2]
+        return jnp.stack([even * np.cos(angle) - odd * np.sin(angle),
+                          odd * np.cos(angle) + even * np.sin(angle)],
+                         -1).reshape(a.shape)
+
+    pairs = [0, 2, 4, 6, 1, 3, 5, 7]
+    np.testing.assert_array_equal(decoder.side_by_side(x), x[..., pairs])
+    got = [decoder.rotate_half(decoder.side_by_side(a), 10_000.0)
+           for a in (x, y)]
+    np.testing.assert_allclose(got[0], interleaved(x)[..., pairs], atol=1e-5)
+    np.testing.assert_allclose(
+        jnp.einsum("qhd,khd->hqk", *got),
+        jnp.einsum("qhd,khd->hqk", interleaved(x), interleaved(y)),
+        atol=1e-4)
+
+
+def _sigmoid_by_hand(r, b, k=6, scale=2.448):
+    """The rule in NumPy, a token at a time."""
+    s = 1.0 / (1.0 + np.exp(-np.asarray(r, np.float64)))
+    chosen = np.argsort(-(s + b), axis=-1, kind="stable")[:, :k]
+    picked = np.take_along_axis(s, chosen, -1)
+    return chosen, scale * picked / picked.sum(-1, keepdims=True), s
+
+
+def test_the_sigmoid_rule_by_hand():
+    """(iv) Four tokens over 128 experts, top-6: the choice is by score plus
+    bias, the weights are the UNBIASED scores of the chosen, normalised and
+    scaled, and sum to 2.448; a bias that lifts a seventh-ranked expert
+    over the sixth flips that choice and leaves every weight's source
+    where it was; ``bias_moved`` counts exactly the flipped pairs."""
+    rng = np.random.default_rng(5)
+    r = jnp.asarray(rng.standard_normal((4, 128)), jnp.float32)
+    flat = jnp.zeros((128,), jnp.float32)
+    weights, _, _, sizes, is_held, moved = route_top_k(
+        r, 6, 0, 128, flat, 2.448)
+    chosen, want, s = _sigmoid_by_hand(r, np.zeros(128))
+    np.testing.assert_allclose(weights, want, rtol=1e-5)
+    np.testing.assert_allclose(jnp.sum(weights, -1), 2.448, rtol=1e-6)
+    assert float(moved) == 0.0 and int(jnp.sum(sizes)) == 24
+    assert bool(is_held.all())
+    # lift token 0's seventh expert over its sixth by a bias on it alone
+    order0 = np.argsort(-s[0], kind="stable")
+    sixth, seventh = int(order0[5]), int(order0[6])
+    bias = np.zeros(128, np.float32)
+    bias[seventh] = float(s[0, sixth] - s[0, seventh]) + 1e-3
+    biased, *_, moved = route_top_k(r, 6, 0, 128, jnp.asarray(bias), 2.448)
+    chosen_b, want_b, _ = _sigmoid_by_hand(r, bias)
+    assert seventh in chosen_b[0] and sixth not in chosen_b[0]
+    np.testing.assert_allclose(biased, want_b, rtol=1e-5)
+    np.testing.assert_allclose(jnp.sum(biased, -1), 2.448, rtol=1e-6)
+    # the lifted expert's weight is its unbiased score's, not the biased one
+    at = list(chosen_b[0]).index(seventh)
+    assert float(biased[0, at]) == pytest.approx(
+        2.448 * s[0, seventh] / s[0, chosen_b[0]].sum(), rel=1e-5)
+    flipped = sum(len(set(a) - set(b)) for a, b in zip(chosen_b, chosen))
+    assert flipped >= 1
+    assert float(moved) == pytest.approx(flipped / 24)
+
+
+def test_the_bound_has_a_floor_of_a_quarter_of_the_pairs():
+    """Twice the uniform share where that is a quarter of the pairs or more
+    (the cell of 8 of 64 experts: the rows it always had), a quarter where
+    the holder is thinner (8 of 128: twice its sixteenth was too few on
+    one seed of six on the chip, PERF.md section 6), all the pairs for a
+    layer held whole."""
+    pairs = 6 * 8192
+    assert moe.pair_bound(pairs, 8, 64) == pairs // 4
+    assert moe.pair_bound(pairs, 8, 128) == pairs // 4
+    assert moe.pair_bound(pairs, 1, 128) == pairs // 4
+    assert moe.pair_bound(pairs, 32, 64) == pairs
+    assert moe.pair_bound(pairs, 24, 64) == 3 * pairs // 4
+
+
+def test_no_token_is_dropped_under_the_sigmoid_rule_when_one_expert_takes_all():
+    """(vi) Every token's first choice by score plus bias is held expert 1
+    (the bias alone puts it there): its group holds all ``T`` pairs, none
+    is cut, and its weight is from the unbiased score."""
+    rng = np.random.default_rng(6)
+    h = jnp.asarray(rng.standard_normal((T, 64)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((T, 16)), jnp.float32)
+    bias = jnp.zeros((16,)).at[1].set(2.0).at[2:4].set(-2.0)
+    gate, up, down = _experts(4)
+    weights, _, _, sizes, is_held, _ = route_top_k(r, 3, 0, 4, bias, 2.448)
+    assert int(sizes[1]) == T and int(sizes[2]) == int(sizes[3]) == 0
+    y, _ = routed_experts(h, r, gate, up, down, 3, 0, bias=bias,
+                          scale=2.448, activation=jax.nn.silu)
+    chosen, want_w, _ = _sigmoid_by_hand(r, np.asarray(bias), 3)
+    np.testing.assert_allclose(weights, want_w, rtol=1e-5)
+    outs = jnp.stack([(jax.nn.silu(h @ gate[e]) * (h @ up[e])) @ down[e]
+                      for e in range(4)], 0)
+    want = sum(jnp.where((chosen[:, c] < 4)[:, None],
+                         want_w[:, c, None]
+                         * outs[np.clip(chosen[:, c], 0, 3), np.arange(T)],
+                         0.0) for c in range(3))
+    np.testing.assert_allclose(y, want, atol=1e-5)
+
+
+def test_fit_leaves_the_selection_bias_where_it_was_seeded():
+    """(v) ``Trainer.fit()`` on the other mixer and rule, on a share of the
+    heads: the loss is finite, the log record and the tracer's instant
+    carry ``bias_moved_share``, and the bias leaf (gradient exactly zero:
+    it enters the choice alone) is bit for bit what it was after Adam's
+    nine steps while its neighbours moved."""
+    from mercury_tpu.train import Trainer
+
+    records = []
+    with Trainer(_config(model=LATENT, model_cut=(3, 0, 4, 2, 2))) as t:
+        before = jax.device_get(t.state.params)
+        assert before["layer1"]["q"].shape == (64, 2 * 192)
+        assert "router" not in before["layer0"]
+        t.logger.add_observer(lambda rec: records.append(dict(rec)))
+        out = t.fit(num_epochs=9)
+        after = jax.device_get(t.state.params)
+        mu = jax.device_get(t.state.opt_state)
+        events = t.tracer.snapshot()
+    assert np.isfinite(out["test/eval_loss"])
+    for layer in ("layer1", "layer2"):
+        np.testing.assert_array_equal(after[layer]["router_bias"],
+                                      before[layer]["router_bias"])
+        assert np.abs(after[layer]["router"]
+                      - before[layer]["router"]).max() > 0
+    zeros = [np.asarray(leaf) for path, leaf in
+             jax.tree_util.tree_leaves_with_path(mu)
+             if "router_bias" in jax.tree_util.keystr(path)]
+    assert zeros and all(not z.any() for z in zeros)   # mu and nu: exact 0
+    assert 0.0 <= records[-1]["moe/bias_moved_share"] <= 1.0
+    loads = [e for e in events if e["name"] == "trainer/moe_load"]
+    assert set(loads[-1]["args"]) >= {
+        "held_pair_share", "load_max_over_mean", "bounded_share",
+        "bias_moved_share"}
+
+
+@pytest.mark.parametrize("cut, match", [
+    ((3, 0, 4, 0), "neither"),
+    ((3, 0, 4, 3, 2), "key/value groups"),
+    ((3, 0, 4, 0, 5), "key/value groups"),
+    ((3, 14, 4, 0, 2), "does not lie inside"),
+])
+def test_a_cut_outside_the_model_is_refused(cut, match):
+    model = create_model(LATENT, num_classes=VOCAB, cut=cut)
+    with pytest.raises(ValueError, match=match):
+        model.init(jax.random.key(0), jnp.zeros((1, T), jnp.int32))
+
+
+def test_a_head_share_of_grouped_query_attention_is_whole_groups():
+    """The five-field cut on the first mixer: a share of the query heads
+    brings its key/value heads (4 query heads to 1 here, so all or
+    nothing); the three-field form still means all heads."""
+    model = create_model(TINY, num_classes=VOCAB, cut=(4, 0, 4, 0, 4))
+    three = create_model(TINY, num_classes=VOCAB, cut=(4, 0, 4))
+    shapes = [jax.eval_shape(lambda m=m: m.init(
+        jax.random.key(0), jnp.zeros((1, T), jnp.int32))) for m in
+        (model, three)]
+    assert shapes[0] == shapes[1]
+    with pytest.raises(ValueError, match="key/value groups"):
+        create_model(TINY, num_classes=VOCAB, cut=(4, 0, 4, 0, 2)).init(
+            jax.random.key(0), jnp.zeros((1, T), jnp.int32))

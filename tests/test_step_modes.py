@@ -32,6 +32,10 @@ import pytest
 #: The token row's model: ``chip_smoke.register_tiny_lm`` adds it to the
 #: registry of the checkout that is traced (:func:`build`).
 TINY_LM = "smallthinker-tiny"
+TINY_LATENT_LM = "latent-tiny"
+#: Which function of ``chip_smoke`` adds each.
+REGISTERS = {TINY_LM: "register_tiny_lm",
+             TINY_LATENT_LM: "register_tiny_latent_lm"}
 
 BASE: Dict[str, Any] = dict(
     model="smallcnn", dataset="synthetic", world_size=2, batch_size=8,
@@ -93,6 +97,12 @@ def _matrix() -> List[Tuple[str, Dict[str, Any]]]:
             model=TINY_LM, dataset="tokens_zipf",
             model_cut=(2, 0, 4), num_classes=96, seq_len=32,
             augmentation="none", batch_size=2, pipelined_scoring=True)),
+        # the same through the decoder's other mixer and routing rule, on a
+        # share of the heads (the five-field cut)
+        ("pipelined-latent-tokens", dict(
+            model=TINY_LATENT_LM, dataset="tokens_zipf",
+            model_cut=(2, 0, 4, 0, 2), num_classes=96, seq_len=32,
+            augmentation="none", batch_size=2, pipelined_scoring=True)),
     ]
     return rows
 
@@ -104,10 +114,10 @@ def _register(model) -> None:
     """The token row's model is no published one: the checkout's
     ``chip_smoke.py`` adds it to the registry (ImportError from a checkout
     older than the row)."""
-    if model == TINY_LM:
-        from chip_smoke import register_tiny_lm
+    if model in REGISTERS:
+        import chip_smoke
 
-        register_tiny_lm()
+        getattr(chip_smoke, REGISTERS[model])()
 
 
 def build(fields: Dict[str, Any]):
