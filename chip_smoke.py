@@ -12,7 +12,9 @@ seeded synthetic data, and checks what comes out:
   pipelined steps of the token path (``smallthinker-tiny`` on
   ``tokens_zipf``: rows of ids, a per-sequence loss) and ten more through
   the decoder's other mixer and routing rule (``latent-tiny``), zero
-  compiles after each trainer's first call;
+  compiles after each trainer's first call; beside each token phase the
+  head's kernel over vocabulary blocks against the plain form, one
+  sequence at its benchmark cell's shape;
 - the Pallas kernels really compiled (Mosaic custom call in the compiled
   step, nothing in interpret mode) and each matches its jax-native twin
   standalone on the chip, at the shapes ``Trainer`` produces;
@@ -325,6 +327,49 @@ def _kernel_phase(tiny: bool) -> Dict[str, Any]:
     return facts
 
 
+def _head_check(phase: str, tiny: bool, d: int, v: int) -> Dict[str, Any]:
+    """The head of one sequence of 8,192 tokens at a token cell's shape
+    (hidden ``d``, vocabulary ``v``, bfloat16 operands): the kernel over
+    vocabulary blocks (``ops.head_nll_pallas``) against the plain form that
+    writes the ``[T, V]`` logits. Half the labels are the plain logits'
+    argmax, so the hits say something. ``tiny``: 32 x 64 x 96 in blocks the
+    interpreter walks quickly."""
+    import jax
+    import jax.numpy as jnp
+
+    from mercury_tpu.ops import head_nll_pallas
+    from mercury_tpu.sampling.importance import _token_rows_plain
+
+    t, blocks = 8192, None
+    if tiny:
+        t, d, v, blocks = 32, 64, 96, (16, 128)
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(v), 4)
+    hidden = jax.random.normal(k1, (t, d)).astype(jnp.bfloat16)
+    head = (jax.random.normal(k2, (d, v)) * d ** -0.5).astype(jnp.bfloat16)
+    best = jnp.argmax(jnp.dot(hidden, head,
+                              preferred_element_type=jnp.float32), -1)
+    labels = jnp.where(jax.random.bernoulli(k3, 0.5, (t,)), best,
+                       jax.random.randint(k4, (t,), 0, v))
+    nll_k, hit_k = jax.jit(lambda *a: head_nll_pallas(*a, blocks))(
+        hidden, head, labels)
+    nll_p, hit_p = jax.jit(_token_rows_plain)(hidden, head, labels)
+    facts = dict(shape=f"{t}x{d}x{v}",
+                 loss_kernel=float(nll_k.mean()), loss_plain=float(nll_p.mean()),
+                 hit_kernel=float(hit_k.mean()), hit_plain=float(hit_p.mean()),
+                 token_loss_max_abs=float(jnp.max(jnp.abs(nll_k - nll_p))),
+                 hits_differ=int(jnp.sum(hit_k != hit_p)))
+    _say(f"{phase}/head", **facts)
+    # float32 sums of the same bfloat16 products in another order, exp/log
+    # from two compilers: 1e-6 on the mean at both shapes on the v5e (PR
+    # 44); a hit may differ where two logits tie to rounding.
+    _require(abs(facts["loss_kernel"] - facts["loss_plain"])
+             <= 1e-5 * facts["loss_plain"], f"{phase}/head: loss {facts}")
+    _require(facts["token_loss_max_abs"] <= 1e-3, f"{phase}/head: {facts}")
+    _require(facts["hits_differ"] <= t // 1024, f"{phase}/head: {facts}")
+    _require(facts["hit_plain"] > 0.4, f"{phase}/head: {facts}")
+    return facts
+
+
 # -------------------------------------------------------------------- run
 def run(tiny: bool = False) -> Dict[str, Any]:
     """The smoke body. ``tiny=True`` is the ``smallcnn`` miniature for the
@@ -357,6 +402,8 @@ def run(tiny: bool = False) -> Dict[str, Any]:
         model=register_tiny_lm(), dataset="tokens_zipf",
         model_cut=(4, 0, 4), num_classes=96, seq_len=32, batch_size=2,
         presample_batches=3, augmentation="none", pipelined_scoring=True)
+    out["one_chip_tokens"]["head"] = _head_check(
+        "one_chip_tokens", tiny, 2560, 18992)       # st21b-is-8k
     # The same path through the other mixer and the other routing rule
     # (latent attention, a sigmoid router with a selection bias, a shared
     # expert, a leading dense layer) on a share of the heads: heads of
@@ -367,6 +414,8 @@ def run(tiny: bool = False) -> Dict[str, Any]:
         model_cut=(3, 0, 4, 0, 2), num_classes=96, seq_len=128,
         batch_size=2, presample_batches=3, augmentation="none",
         pipelined_scoring=True)
+    out["one_chip_latent_tokens"]["head"] = _head_check(
+        "one_chip_latent_tokens", tiny, 2048, 16032)    # kn2-is-8k
     out["kernels"] = _kernel_phase(tiny)
     if len(jax.devices()) >= 4:
         four = _train_phase("four_chip_is", tiny, steps,

@@ -23,13 +23,23 @@ A third kernel serves the model, not the sampler:
    tile in VMEM, the Gram product on the MXU, and ``h`` never reaches HBM
    (``models/resnet.py::_stat_from_input``; PERF.md §6, PR 32).
 
+A fourth serves the loss seam of rows of per-token labels:
+
+4. :func:`head_nll_pallas` — a sequence's token loss and hits from blocks of
+   the vocabulary: a grid of (token blocks, vocabulary blocks) forms one
+   float32 tile of ``hidden @ head`` in VMEM and folds it, lane by lane,
+   into a running log-sum-exp, the label's logit and a running argmax, so
+   the ``[T, V]`` logits never reach HBM
+   (``sampling/importance.py::sequence_loss`` where nothing differentiates
+   the pass; PERF.md §6, PR 45).
+
 Uniform variates are passed in (from ``jax.random``) rather than drawn with
 the in-kernel TPU PRNG, so the draw is reproducible from a JAX key and the
 kernels run identically under ``interpret=True`` on CPU (how the test suite
 exercises them without a chip).
 
-Kernels 1 and 2 are a single block each, no grid. The draw kernel holds its scores
-lane-dense — ``[N/128, 128]`` f32, 4 bytes per candidate — because Mosaic
+Kernels 1 and 2 are a single block each, no grid; 3 and 4 run over one. The
+draw kernel holds its scores lane-dense — ``[N/128, 128]`` f32, 4 bytes per candidate — because Mosaic
 tiles an ``[N, 1]`` f32 column ``(8, 128)``, 512 bytes per candidate: a
 50,000-slot table is 0.2 MB lane-dense and 24 MB as a column, past the
 16 MB scoped-VMEM limit of a v5e. ``tests/test_tpu_aot.py`` compiles every
@@ -405,3 +415,159 @@ def input_moments_pallas(y: jax.Array, mean: jax.Array, mul: jax.Array,
     # the stacked positions' diagonal blocks (one block where none is)
     return (s.reshape(stack, k).sum(0),
             sum(g[i * k:(i + 1) * k, i * k:(i + 1) * k] for i in range(stack)))
+
+
+# ----------------------------------------------------------------- kernel 4
+#: Rows of tokens x columns of the vocabulary of one grid step, which takes
+#: the whole contraction. On the v5e, one sequence of 8,192 tokens alone:
+#: 4.87 ms at hidden 2,560 x vocabulary 18,992 and 3.37 ms at 2,048 x 16,032
+#: (83 / 81 % of the peak; the plain form 6.09 / 4.63), within 1 % of the best
+#: of five shapes; 2,048 columns are slower by a quarter (PERF.md §6, PR 45).
+HEAD_BLOCKS = (1024, 1024)
+#: What a grid step may hold in VMEM: the double-buffered operand blocks,
+#: the float32 tile and the fold's temporaries. Past the 16 MiB a kernel is
+#: given unasked, inside the v5e's 128 MiB.
+_HEAD_VMEM_BYTES = 64 << 20
+
+
+def _head_vmem_bytes(d: int, itemsize: int, blocks) -> int:
+    """Bytes of a grid step: both operand blocks twice (Pallas's double
+    buffering), three float32 tiles (the product and the fold's
+    temporaries), the four lane-wise carries."""
+    rows, columns = blocks
+    return (2 * (rows + columns) * d * itemsize + 3 * rows * columns * 4
+            + 4 * rows * _LANES * 4)
+
+
+def head_nll_takes(t: int, d: int, itemsize: int = 2, blocks=None) -> bool:
+    """Whether :func:`head_nll_pallas` takes ``t`` tokens of hidden size
+    ``d`` in operands of ``itemsize`` bytes: whole blocks of tokens, and a
+    contraction whose blocks fit the kernel's VMEM (hidden 2,560 in
+    bfloat16: 34 MiB of the 64). Any vocabulary: its last block is masked
+    inside the kernel. ``blocks``: :data:`HEAD_BLOCKS` where not given."""
+    blocks = blocks or HEAD_BLOCKS
+    return (t % blocks[0] == 0
+            and _head_vmem_bytes(d, itemsize, blocks) <= _HEAD_VMEM_BYTES)
+
+
+def _head_nll_kernel(hidden_ref, head_ref, labels_ref, nll_ref, hit_ref,
+                     logits_ref, max_ref, sum_ref, picked_ref, arg_ref,
+                     *, vocab: int):
+    """One ``[rows, columns]`` float32 tile of the logits, folded into what
+    is carried a token across the vocabulary blocks (the inner grid axis),
+    LANE BY LANE: ``[rows, 128]`` each, lane ``l`` standing for the columns
+    ``l mod 128``, so that the fold is elementwise, straight-line code and
+    nothing crosses lanes until the last block (a fold that reduces each
+    tile across its lanes, or walks it in a loop, costs the kernel 25-50 %
+    on the v5e: PERF.md §6, PR 45). Carried: the running maximum, the sum of
+    exponentials rescaled to it, the label's logit, and the 128-column
+    group the maximum was first seen in. The tile is written to
+    ``logits_ref`` and folded from there: folding the product's value as it
+    is reads 5 % slower. ``labels_ref`` and the outputs: ``[rows, 1]``."""
+    j, last = pl.program_id(1), pl.num_programs(1) - 1
+    columns = logits_ref.shape[1]
+    groups = columns // _LANES
+
+    @pl.when(j == 0)
+    def _():
+        # finite, so that a lane that has seen no column yet (or never
+        # will: a vocabulary under 128) rescales by exp(0) and not exp(nan)
+        max_ref[...] = jnp.full_like(max_ref, jnp.finfo(jnp.float32).min)
+        sum_ref[...] = jnp.zeros_like(sum_ref)
+        picked_ref[...] = jnp.zeros_like(picked_ref)
+        arg_ref[...] = jnp.zeros_like(arg_ref)
+
+    logits_ref[...] = jnp.dot(hidden_ref[...], head_ref[...],
+                              preferred_element_type=jnp.float32)
+
+    def fold(valid: int):
+        """The tile's first ``valid`` columns (static) into the carries."""
+        lane = lax.broadcasted_iota(jnp.int32, max_ref.shape, 1)
+        label = labels_ref[...] - j * columns       # within the tile
+        tiles = []
+        for g in range(pl.cdiv(valid, _LANES)):
+            x = logits_ref[:, g * _LANES:(g + 1) * _LANES]
+            if (g + 1) * _LANES > valid:    # columns past the head
+                x = jnp.where(lane < valid - g * _LANES, x, -jnp.inf)
+            tiles.append(x)
+        before = best = max_ref[...]
+        group = arg_ref[...]
+        for g, x in enumerate(tiles):
+            # ``>``: the lowest index wins a tie, in and across blocks
+            better = x > best
+            group = jnp.where(better, j * groups + g, group)
+            best = jnp.where(better, x, best)
+        total = sum_ref[...] * jnp.exp(before - best)
+        picked = picked_ref[...]
+        for g, x in enumerate(tiles):
+            total = total + jnp.exp(x - best)
+            picked = picked + jnp.where(lane + g * _LANES == label, x, 0.0)
+        max_ref[...] = best
+        arg_ref[...] = group
+        sum_ref[...] = total
+        picked_ref[...] = picked
+
+    tail = vocab % columns      # columns of the last block, if not whole
+    if tail:
+        pl.when(j < last)(lambda: fold(columns))
+        pl.when(j == last)(lambda: fold(tail))
+    else:
+        fold(columns)
+
+    @pl.when(j == last)
+    def _():
+        # across the lanes, once a token block
+        lanes_max = max_ref[...]
+        best = jnp.max(lanes_max, axis=1, keepdims=True)
+        total = jnp.sum(sum_ref[...] * jnp.exp(lanes_max - best), axis=1,
+                        keepdims=True)
+        nll_ref[...] = (best + jnp.log(total)
+                        - jnp.sum(picked_ref[...], axis=1, keepdims=True))
+        column = arg_ref[...] * _LANES + lax.broadcasted_iota(
+            jnp.int32, lanes_max.shape, 1)
+        first = jnp.min(jnp.where(lanes_max == best, column, vocab), axis=1,
+                        keepdims=True)
+        hit_ref[...] = (first == labels_ref[...]).astype(jnp.float32)
+
+
+def head_nll_pallas(hidden: jax.Array, head: jax.Array, labels: jax.Array,
+                    blocks=None) -> Tuple[jax.Array, jax.Array]:
+    """``(nll [T], hit [T])`` in f32 of one sequence: the token negative
+    log-likelihood ``logsumexp(z_t) − z_t[y_t]`` and whether ``argmax(z_t)
+    == y_t`` (lowest index on a tie, as ``jnp.argmax``), for the float32
+    logits ``z = hidden [T, D] @ head [D, V]`` of the operands as they are
+    given (bfloat16 on the cells) — which are formed a ``blocks[0] x
+    blocks[1]`` tile at a time in VMEM and never written. ``labels [T]``
+    int, each in ``[0, V)``. Shapes: :func:`head_nll_takes`. Not
+    differentiable: the seam's ``custom_vjp`` gives the differentiated pass
+    the plain form (``sampling/importance.py``)."""
+    (t, d), v = hidden.shape, head.shape[1]
+    rows, columns = blocks = blocks or HEAD_BLOCKS
+    if (not head_nll_takes(t, d, hidden.dtype.itemsize, blocks) or rows % 8
+            or columns % _LANES):
+        raise ValueError(f"{t} tokens of hidden size {d} are not whole "
+                         f"blocks of {rows} x {columns} within the kernel's "
+                         f"VMEM, or these no whole tiles of 8 x {_LANES}")
+    # under a ``shard_map`` that checks them: a token's numbers vary as it does
+    column = jax.ShapeDtypeStruct((t, 1), jnp.float32,
+                                  vma=jax.typeof(hidden).vma)
+    token = pl.BlockSpec((rows, 1), lambda i, j: (i, 0))
+    carry = functools.partial(pltpu.VMEM, (rows, _LANES))
+    nll, hit = pl.pallas_call(
+        functools.partial(_head_nll_kernel, vocab=v),
+        grid=(t // rows, pl.cdiv(v, columns)),
+        in_specs=[pl.BlockSpec((rows, d), lambda i, j: (i, 0)),
+                  pl.BlockSpec((d, columns), lambda i, j: (0, j)),
+                  token],
+        out_specs=(token, token),
+        out_shape=(column, column),
+        scratch_shapes=[pltpu.VMEM((rows, columns), jnp.float32),
+                        carry(jnp.float32), carry(jnp.float32),
+                        carry(jnp.float32), carry(jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_HEAD_VMEM_BYTES),
+        name="mercury_head_nll",
+        interpret=_interpret(),
+    )(hidden, head, labels.reshape(t, 1).astype(jnp.int32))
+    return nll[:, 0], hit[:, 0]

@@ -37,6 +37,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from mercury_tpu.ops import head_nll_pallas, head_nll_takes
+
 
 # Numerical floor applied to smoothed scores before normalization
 # (guards the all-zero pool). Shared with the telemetry clip-rate
@@ -78,32 +80,82 @@ def per_sample_loss(
     return nll
 
 
-def sequence_loss(hidden: jax.Array, head: jax.Array,
-                  labels: jax.Array) -> jax.Array:
+def _token_rows_plain(hidden: jax.Array, head: jax.Array, labels: jax.Array
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """``(nll [T], hit [T])`` of one sequence from its whole ``[T, V]``
+    float32 logits: one product, then ``logsumexp``, the label's logit and
+    ``argmax`` read them back."""
+    logits = jnp.dot(hidden, head, preferred_element_type=jnp.float32)
+    nll = (jax.nn.logsumexp(logits, axis=-1)
+           - jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0])
+    return nll, (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+
+
+@jax.custom_vjp
+def _token_rows_by_blocks(hidden: jax.Array, head: jax.Array,
+                          labels: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """:func:`_token_rows_plain`'s two outputs, by the way the pass needs
+    them (one algorithm, as ``models/resnet.py::_closing_unit``). Nothing
+    differentiates it (the scoring pass, ``evaluate()``): one kernel over
+    blocks of the vocabulary (``ops.head_nll_pallas``), which holds a tile
+    of the logits in VMEM and writes two numbers a token. Under
+    ``jax.grad`` the backward needs the whole logits anyway, so the
+    ``custom_vjp`` rule is the vjp of the plain form and the
+    differentiated pass is what it was."""
+    return head_nll_pallas(hidden, head, labels)
+
+
+_token_rows_by_blocks.defvjp(
+    lambda *args: jax.vjp(_token_rows_plain, *args),
+    lambda vjp, cotangents: vjp(cotangents))
+
+
+def head_takes_kernel(hidden: jax.Array, use_kernel: bool) -> bool:
+    """Whether :func:`sequence_loss` gives rows of ``hidden`` (``[..., T,
+    D]``) to the kernel where nothing differentiates it: asked for
+    (``StepMode.use_pallas``) and of a shape it takes
+    (``ops.head_nll_takes``). Else the plain form, everywhere."""
+    return use_kernel and head_nll_takes(*hidden.shape[-2:],
+                                         hidden.dtype.itemsize)
+
+
+def sequence_loss(hidden: jax.Array, head: jax.Array, labels: jax.Array,
+                  use_kernel: bool = False) -> jax.Array:
     """The next-token loss of ONE sequence and its share of positions
     predicted right, ``[2]`` float32: ``hidden [T, D]`` the final hidden
     states, ``head [D, V]`` the output projection, ``labels [T]`` the ids
     that follow. The loss is the mean over the ``T`` positions of the token
-    negative log-likelihood over the ``V`` rows, from float32 logits."""
+    negative log-likelihood over the ``V`` rows, from float32 logits.
+
+    What runs under ``mercury_lm_head``: the plain form
+    (:func:`_token_rows_plain`: the ``[T, V]`` logits written once and read
+    back three times), or with ``use_kernel``, at shapes the kernel takes
+    (:func:`head_takes_kernel`), :func:`_token_rows_by_blocks`: the kernel
+    where nothing differentiates the pass, the plain form and its transpose
+    under ``jax.grad``."""
     with jax.named_scope("mercury_lm_head"):
-        logits = jnp.dot(hidden, head, preferred_element_type=jnp.float32)
-        nll = (jax.nn.logsumexp(logits, axis=-1)
-               - jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0])
-        hit = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+        rows = (_token_rows_by_blocks
+                if head_takes_kernel(hidden, use_kernel)
+                else _token_rows_plain)
+        nll, hit = rows(hidden, head, labels)
         return jnp.stack([jnp.mean(nll), jnp.mean(hit)])
 
 
-def sequence_rows(outputs, labels: jax.Array) -> jax.Array:
+def sequence_rows(outputs, labels: jax.Array,
+                  use_kernel: bool = False) -> jax.Array:
     """Rows of per-token labels reduced to the unit that is scored and
     drawn, a sequence: ``outputs`` is what a model of per-token logits
     returns, ``(hidden [n, T, D], head [D, V])`` (``models/decoder.py``),
     ``labels [n, T]``; ``[n, 2]`` float32, a row's :func:`sequence_loss`
     and hit share. The head's product and the token loss run a row at a
-    time (``lax.map``), each under ``jax.checkpoint``: no more than one
-    row's ``[T, V]`` logits ever exist, forward or backward."""
+    time (``lax.map``), each under ``jax.checkpoint``: in the train pass no
+    more than one row's ``[T, V]`` logits ever exist, forward or backward;
+    with ``use_kernel`` (:func:`sequence_loss`) the passes that nothing
+    differentiates hold none at all."""
     hidden, head = outputs
+    row_loss = jax.checkpoint(sequence_loss, static_argnums=(3,))
     return jax.lax.map(
-        lambda row: jax.checkpoint(sequence_loss)(row[0], head, row[1]),
+        lambda row: row_loss(row[0], head, row[1], use_kernel),
         (hidden, labels))
 
 

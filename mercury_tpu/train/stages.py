@@ -19,12 +19,19 @@ returned to what is carried of it, then ``loss``, ``score`` and ``hits`` a
 row. One class label a row: the logits are carried whole. Rows of per-token
 labels (``StepMode.token_rows``): the model returns hidden states and its
 head, and ``reduce`` is ``sampling.importance.sequence_rows`` (head and token
-loss a row at a time, so that no more than a row's logits exist): ``[n, 2]``,
-a row's loss, which is its score too, and its hit share.
+loss a row at a time): ``[n, 2]``, a row's loss, which is its score too, and
+its hit share. Under ``StepMode.use_pallas``, at shapes the kernel takes, a
+pass that nothing differentiates (the scoring pass, ``evaluate()``) takes
+loss and hits from blocks of the vocabulary in one kernel
+(``ops.head_nll_pallas``) and writes no logits; the train pass runs the plain
+product and its transpose, one row's logits at a time (the seam's
+``custom_vjp``). How many rows of a step took the kernel is counted as the
+step is traced (``trace_facts["head_kernel_rows"]``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -45,6 +52,7 @@ from mercury_tpu.obs.diagnostics import global_grad_norm
 from mercury_tpu.parallel import collectives as coll
 from mercury_tpu.sampling.importance import (
     ema_update,
+    head_takes_kernel,
     per_sample_grad_norm_bound,
     per_sample_loss,
     pool_mean,
@@ -93,14 +101,17 @@ def row_fns(token_rows: bool = False, use_pallas: bool = False,
             importance_score: str = "loss") -> RowFns:
     """The loss seam of rows of per-token labels (``token_rows``: loss =
     score = the mean over a sequence's positions of the token negative
-    log-likelihood, hits the share of positions predicted right) or of one
-    class label a row (cross-entropy, by the Pallas kernel under
-    ``use_pallas``; scored by the loss or by the gradient-norm bound)."""
+    log-likelihood, hits the share of positions predicted right; under
+    ``use_pallas`` by the kernel over vocabulary blocks where nothing
+    differentiates the pass) or of one class label a row (cross-entropy, by
+    the Pallas kernel under ``use_pallas``; scored by the loss or by the
+    gradient-norm bound)."""
     if token_rows:
         def column(i):
             return lambda carried, labels: carried[:, i]
 
-        return RowFns(sequence_rows, column(0), column(0), column(1))
+        return RowFns(functools.partial(sequence_rows, use_kernel=use_pallas),
+                      column(0), column(0), column(1))
 
     if use_pallas:
         from mercury_tpu.ops import per_sample_nll_pallas as loss
@@ -148,6 +159,19 @@ def _note_moment_units(ctx: StepContext, model_state) -> None:
             jax.tree_util.tree_leaves(model_state.get(MOMENT_UNITS, {})))
 
 
+def _note_head_rows(ctx: StepContext, outputs) -> None:
+    """A forward that nothing differentiates is reduced through the loss
+    seam (token rows have one such pass a step, the pool's scoring): its
+    rows' heads run in the kernel over vocabulary blocks
+    (``head_kernel_rows``) or, where that is not asked for or refuses the
+    shape, in the plain form (``head_plain_rows``)."""
+    if ctx.trace_facts is not None and ctx.mode.token_rows:
+        hidden = outputs[0]
+        kernel = head_takes_kernel(hidden, ctx.mode.use_pallas)
+        ctx.trace_facts["head_kernel_rows"] = hidden.shape[0] * kernel
+        ctx.trace_facts["head_plain_rows"] = hidden.shape[0] * (not kernel)
+
+
 def _apply(ctx: StepContext, module, params, batch_stats, images,
            moment_units: bool, labels=None):
     """Train-mode ``module.apply`` with the collections it may write:
@@ -163,6 +187,8 @@ def _apply(ctx: StepContext, module, params, batch_stats, images,
         mutable.append(MOMENT_UNITS)
     outputs, written = module.apply(variables, images, train=True,
                                     mutable=mutable)
+    if moment_units:
+        _note_head_rows(ctx, outputs)
     return ctx.rows.reduce(outputs, labels), written
 
 

@@ -351,8 +351,12 @@ def make_train_step(
     ``trace_facts`` (optional) is filled as the step is traced with what
     only the trace knows: ``bn_moment_units``, how many conv+BN units of
     the scoring forward take their batch statistic from input moments
-    (``models/resnet.py::_closing_unit``); ``Trainer`` reports it as the
-    instant ``trainer/bn_moment_units``.
+    (``models/resnet.py::_closing_unit``), and for rows of per-token labels
+    ``head_kernel_rows`` / ``head_plain_rows``, how many rows of the
+    scoring pass take loss and hits from the kernel over vocabulary blocks
+    and how many from whole logits (``sampling/importance.py::
+    sequence_loss``); ``Trainer`` reports them as the instants
+    ``trainer/bn_moment_units`` and ``trainer/head_kernel_rows``.
 
     SHARDING CONTRACT (graftlint Layer 3, ``lint/sharding.py``,
     docs/LINT.md): the inputs are pinned with ``with_sharding_constraint``
@@ -574,7 +578,7 @@ def make_per_class_epoch(
 def make_eval_epoch(
     model, mean: np.ndarray, std: np.ndarray, eval_augmentation: str = "none",
     mesh: Optional[Mesh] = None, axis: str = "data",
-    token_rows: bool = False,
+    token_rows: bool = False, use_pallas: bool = False,
 ) -> Callable[..., Tuple[jax.Array, jax.Array, jax.Array]]:
     """One-dispatch full-split eval → ``(loss_sum, correct, count)``: the
     reference's ``evaluate`` walks a DataLoader batch-by-batch from the host
@@ -585,9 +589,11 @@ def make_eval_epoch(
     the live non-IID path normalizes only (``cifar10/data_loader.py:92-96``).
     ``token_rows``: rows of token ids with per-token labels, read through
     the step's own loss seam (``stages.row_fns``): the loss of a row is the
-    mean over its positions, its hit the share predicted right.
+    mean over its positions, its hit the share predicted right; nothing
+    differentiates the pass, so under ``use_pallas`` both come from the
+    kernel over vocabulary blocks, at shapes it takes.
     """
-    rows = row_fns(token_rows)
+    rows = row_fns(token_rows, use_pallas and token_rows)
 
     def init():
         return (jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))

@@ -550,7 +550,8 @@ class Trainer:
                                           else "none",
                                           mesh=eval_mesh,
                                           axis=config.mesh_axis,
-                                          token_rows=self._token_rows)
+                                          token_rows=self._token_rows,
+                                          use_pallas=mode.use_pallas)
         # --- fault-injection plane (mercury_tpu/faults.py): built BEFORE
         # every subsystem that hooks into it (metric writer, prefetch
         # pipeline, scorer fleet, checkpoint writes, the fit loop). None
@@ -1390,6 +1391,12 @@ class Trainer:
             self.tracer.instant(
                 "trainer/bn_moment_units", cat="trainer",
                 units=self._trace_facts.get("bn_moment_units", 0))
+            # Likewise how many token rows of a step take loss and hits
+            # from the head's kernel over vocabulary blocks.
+            self.tracer.instant(
+                "trainer/head_kernel_rows", cat="trainer",
+                rows=self._trace_facts.get("head_kernel_rows", 0),
+                plain_rows=self._trace_facts.get("head_plain_rows", 0))
             return final_metrics
 
     def _note_moe_load(self, record: Dict[str, Any]) -> None:

@@ -25,6 +25,11 @@ def test_body_runs_tiny_on_the_cpu_mesh():
         if name != "kernels":
             assert facts["compiles_after_first"] == 0, name
     assert out["one_chip_is"]["restored_step"] == out["one_chip_is"]["steps"]
+    for name in ("one_chip_tokens", "one_chip_latent_tokens"):
+        head = out[name]["head"]    # the kernel, interpreted, beside the plain
+        assert head["shape"] == "32x64x96" and head["hits_differ"] == 0
+        assert head["loss_kernel"] == pytest.approx(head["loss_plain"],
+                                                    rel=1e-5)
     assert out["one_chip_is"]["mosaic_in_step"] is False  # off the chip
 
 

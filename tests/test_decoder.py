@@ -332,7 +332,10 @@ def test_the_row_at_a_time_head_and_loss_are_the_whole_product():
     rows = jax.lax.map(lambda a: sequence_loss(a[0], head, a[1]),
                        (hidden, labels))
     seam = row_fns(token_rows=True)
-    assert seam.reduce is sequence_rows
+    assert seam.reduce.func is sequence_rows
+    assert seam.reduce.keywords == {"use_kernel": False}
+    assert row_fns(token_rows=True, use_pallas=True).reduce.keywords == {
+        "use_kernel": True}
     np.testing.assert_array_equal(seam.reduce((hidden, head), labels), rows)
     logits = hidden @ head
     np.testing.assert_array_equal(token_logits((hidden, head)), logits)
@@ -449,6 +452,10 @@ def test_fit_on_the_token_dataset(world):
             records[-1][f"moe/{name}"])
     units = [e for e in events if e["name"] == "trainer/bn_moment_units"]
     assert units and units[-1]["args"]["units"] == 0
+    # off the TPU the head's kernel is not asked for: the pool's six rows
+    heads = [e for e in events if e["name"] == "trainer/head_kernel_rows"]
+    assert len(heads) == 1
+    assert (heads[0]["args"]["rows"], heads[0]["args"]["plain_rows"]) == (0, 6)
 
 
 @pytest.mark.parametrize("fields, match", [
@@ -472,6 +479,187 @@ def test_uniform_sampling_takes_token_rows_too():
                          pipelined_scoring=False, trace=False)) as t:
         out = t.fit(num_epochs=3)
     assert np.isfinite(out["test/eval_loss"])
+
+
+# ------------------------------------- the head's kernel behind the seam
+#: Blocks the tiny models' rows (T 32) are whole blocks of; the vocabulary of
+#: 96 is one masked block.
+SMALL_BLOCKS = (16, 128)
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """The blocks are read as a row's loss is traced, and jax keeps that
+    trace by the function and its shapes: forget it on the way in and out."""
+    from mercury_tpu.ops import mercury_kernels
+
+    monkeypatch.setattr(mercury_kernels, "HEAD_BLOCKS", SMALL_BLOCKS)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _scoped(jaxpr, prefix=""):
+    """``(scopes, equation)`` of a jaxpr and of the jaxprs inside it, the
+    scopes those of every enclosing equation and its own."""
+    for eqn in jaxpr.eqns:
+        path = f"{prefix}/{eqn.source_info.name_stack}"
+        yield path, eqn
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else [value]:
+                v = getattr(v, "jaxpr", v)
+                if hasattr(v, "eqns"):
+                    yield from _scoped(v, path)
+
+
+def _head_kernels(jaxpr):
+    return [path for path, eqn in _scoped(jaxpr)
+            if eqn.primitive.name == "pallas_call"
+            and eqn.params["name"] == "mercury_head_nll"]
+
+
+def _whole_logits(jaxpr, rows=T, vocab=VOCAB):
+    """Scopes of the head's products that write one row's ``[T, V]``
+    logits."""
+    return [path for path, eqn in _scoped(jaxpr)
+            if eqn.primitive.name == "dot_general"
+            and "mercury_lm_head" in path
+            and eqn.outvars[0].aval.shape == (rows, vocab)]
+
+
+def _rows_operands(dtype, seed=7, n=3, d=64):
+    rng = np.random.default_rng(seed)
+    hidden = jnp.asarray(rng.standard_normal((n, T, d)), dtype)
+    head = jnp.asarray(rng.standard_normal((d, VOCAB)) * 0.1, dtype)
+    return hidden, head, jnp.asarray(rng.integers(0, VOCAB, (n, T)), jnp.int32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_kernels_rows_are_the_plain_rows_and_their_gradient(small_blocks,
+                                                                dtype):
+    """With the kernel on, a pass that nothing differentiates reads loss
+    and hits off the kernel (to rounding, and exactly); ``jax.grad`` through
+    ``sequence_rows`` is the plain form's gradient to the bit: the
+    ``custom_vjp`` rule IS the plain form and its transpose, the same
+    operations in the same order under the same ``jax.checkpoint``."""
+    hidden, head, labels = _rows_operands(dtype)
+    plain = sequence_rows((hidden, head), labels)
+    rows = jax.jit(lambda h, w: sequence_rows((h, w), labels,
+                                              use_kernel=True))(hidden, head)
+    np.testing.assert_allclose(rows[:, 0], plain[:, 0], rtol=1e-5)
+    np.testing.assert_array_equal(rows[:, 1], plain[:, 1])
+    forward = jax.make_jaxpr(lambda h, w: sequence_rows(
+        (h, w), labels, use_kernel=True))(hidden, head).jaxpr
+    assert len(_head_kernels(forward)) == 1 and not _whole_logits(forward)
+    assert "mercury_lm_head" in _head_kernels(forward)[0]
+
+    def loss(use_kernel):
+        return lambda h, w: jnp.sum(
+            sequence_rows((h, w), labels, use_kernel=use_kernel)[:, 0]
+            * jnp.arange(1.0, 4.0))
+
+    grads = jax.grad(loss(True), argnums=(0, 1))
+    for got, want in zip(grads(hidden, head),
+                         jax.grad(loss(False), argnums=(0, 1))(hidden, head)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got.astype(jnp.float32),
+                                      want.astype(jnp.float32))
+    backward = jax.make_jaxpr(grads)(hidden, head).jaxpr
+    assert not _head_kernels(backward)
+    assert len(_whole_logits(backward)) == 2      # forward, and recomputed
+
+
+@pytest.mark.parametrize("t, d, takes", [(T, 64, True), (T + 8, 64, False),
+                                         (T, 2 ** 19, False)])
+def test_a_shape_the_kernel_refuses_runs_the_plain_form(small_blocks, t, d,
+                                                        takes):
+    from mercury_tpu.sampling.importance import head_takes_kernel
+
+    hidden = jax.ShapeDtypeStruct((2, t, d), jnp.float32)
+    head = jax.ShapeDtypeStruct((d, VOCAB), jnp.float32)
+    labels = jnp.asarray(np.random.default_rng(t).integers(
+        0, VOCAB, (2, t)), jnp.int32)
+    assert head_takes_kernel(hidden, True) is takes
+    assert head_takes_kernel(hidden, False) is False
+    jaxpr = jax.make_jaxpr(lambda h, w: sequence_rows(
+        (h, w), labels, use_kernel=True))(hidden, head).jaxpr
+    assert len(_head_kernels(jaxpr)) == int(takes)
+    assert len(_whole_logits(jaxpr, t)) == int(not takes)
+    if not takes and d == 64:
+        rng = np.random.default_rng(t + d)
+        hidden = jnp.asarray(rng.standard_normal((2, t, d)), jnp.float32)
+        head = jnp.asarray(rng.standard_normal((d, VOCAB)) * 0.1, jnp.float32)
+        np.testing.assert_array_equal(
+            sequence_rows((hidden, head), labels, use_kernel=True),
+            sequence_rows((hidden, head), labels))
+
+
+def test_the_default_blocks_refuse_the_tiny_rows_and_the_trace_says_so():
+    """``use_pallas`` at T 32 with the chip's blocks: every head in the plain
+    form, and ``trace_facts`` / the instant count the pool's rows as such."""
+    from mercury_tpu.train import Trainer
+
+    with Trainer(_config(use_pallas=True)) as t:
+        t.fit(num_epochs=1)
+        assert t._trace_facts["head_kernel_rows"] == 0
+        assert t._trace_facts["head_plain_rows"] == 6
+        events = t.tracer.snapshot()
+        step = jax.make_jaxpr(t.train_step)(
+            t.state, t._step_x, t._step_y, t.dataset.shard_indices).jaxpr
+    assert not _head_kernels(step)
+    heads = [e for e in events if e["name"] == "trainer/head_kernel_rows"]
+    assert (heads[-1]["args"]["rows"], heads[-1]["args"]["plain_rows"]) == (0, 6)
+
+
+@pytest.mark.parametrize("model", [TINY, LATENT], ids=["softmax", "latent"])
+def test_the_step_runs_the_kernel_where_nothing_differentiates(small_blocks,
+                                                               model):
+    """A token step traced with ``use_pallas``: the scoring pass's head is
+    the kernel under ``mercury_scoring`` > ``mercury_lm_head`` and writes no
+    ``[T, V]`` logits; the train pass holds the plain product (forward and
+    recomputed backward) and no kernel; ``evaluate()``'s pass is the kernel
+    too; the pool's six rows are counted."""
+    from mercury_tpu.train import Trainer
+
+    cut = (4, 0, 4) if model == TINY else (2, 0, 4, 0, 2)
+    with Trainer(_config(model=model, model_cut=cut, use_pallas=True)) as t:
+        step = jax.make_jaxpr(t.train_step)(
+            t.state, t._step_x, t._step_y, t.dataset.shard_indices).jaxpr
+        assert t._trace_facts["head_kernel_rows"] == 6
+        assert t._trace_facts["head_plain_rows"] == 0
+        evaluate = jax.make_jaxpr(t.eval_epoch)(
+            t.state.params, t.state.batch_stats, *t._eval_arrays(False)).jaxpr
+        out = t.fit(num_epochs=2)
+        events = t.tracer.snapshot()
+    kernels = _head_kernels(step)     # the pool's scoring, and its priming
+    assert kernels and all(
+        "mercury_train" not in path
+        and path.index("mercury_scoring") < path.index("mercury_lm_head")
+        for path in kernels)
+    logits = _whole_logits(step)
+    assert len(logits) == 2 and all(
+        "mercury_train" in path and "mercury_scoring" not in path
+        for path in logits)
+    assert len(_head_kernels(evaluate)) == 1 and not _whole_logits(evaluate)
+    assert "mercury_lm_head" in _head_kernels(evaluate)[0]
+    assert np.isfinite(out["test/eval_loss"])
+    heads = [e for e in events if e["name"] == "trainer/head_kernel_rows"]
+    assert (heads[-1]["args"]["rows"], heads[-1]["args"]["plain_rows"]) == (6, 0)
+
+
+def test_the_kernels_eval_is_the_plain_eval(small_blocks):
+    """``evaluate()`` with the kernel against the same parameters read
+    through whole logits: the loss to rounding, the hits to the digit."""
+    from mercury_tpu.train import Trainer
+
+    with Trainer(_config(use_pallas=True)) as kernel, \
+            Trainer(_config(use_pallas=False)) as plain:
+        plain.state = plain.state.replace(params=kernel.state.params)
+        got, want = kernel.evaluate(), plain.evaluate()
+    assert got["test/eval_loss"] == pytest.approx(want["test/eval_loss"],
+                                                  rel=1e-5)
+    assert got["test/eval_acc"] == want["test/eval_acc"]
 
 
 # --------------------------------- the other mixer and the other routing rule

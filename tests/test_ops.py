@@ -3,7 +3,9 @@ match the jax-native version bit-for-bit-ish, its VJP must match autodiff,
 and the fused score/draw must match the importance pipeline — probs to
 rounding, draws to an inverse-CDF reference on the same uniforms. The
 fused uint8 ingest chain (jax-native) must match the unfused
-normalize→augment chain bit-for-bit at f32."""
+normalize→augment chain bit-for-bit at f32. The head's kernel over
+vocabulary blocks must give the plain form's token loss to rounding and its
+hits exactly."""
 
 import jax
 import jax.numpy as jnp
@@ -17,8 +19,17 @@ from mercury_tpu.data.pipeline import (
     normalize_images,
     select_crop_flip,
 )
-from mercury_tpu.ops import per_sample_nll_pallas, score_and_draw_pallas
-from mercury_tpu.sampling.importance import importance_probs, per_sample_loss
+from mercury_tpu.ops import (
+    head_nll_pallas,
+    head_nll_takes,
+    per_sample_nll_pallas,
+    score_and_draw_pallas,
+)
+from mercury_tpu.sampling.importance import (
+    _token_rows_plain,
+    importance_probs,
+    per_sample_loss,
+)
 
 
 @pytest.fixture(scope="module")
@@ -216,6 +227,114 @@ def raw_uint8():
 def _unfused_ingest(key, raw, out_dtype=None):
     out = augment_batch(key, normalize_images(raw, _MEAN, _STD))
     return out if out_dtype is None else out.astype(out_dtype)
+
+
+class TestHeadNLL:
+    """``head_nll_pallas`` (interpret mode, blocks of 32 tokens x 128
+    columns) against ``sequence_loss``'s plain form: the token loss to 1e-5
+    relative, the hits exactly."""
+
+    BLOCKS = (32, 128)
+
+    @staticmethod
+    def _operands(t, d, v, dtype, seed=0, scale=1.0):
+        rng = np.random.default_rng(seed)
+        hidden = jnp.asarray(rng.standard_normal((t, d)) * scale, dtype)
+        head = jnp.asarray(rng.standard_normal((d, v)) * d ** -0.5, dtype)
+        labels = rng.integers(0, v, t).astype(np.int32)
+        return hidden, head, labels
+
+    def _agree(self, hidden, head, labels, blocks=None):
+        labels = jnp.asarray(labels)
+        nll, hit = jax.jit(lambda *a: head_nll_pallas(
+            *a, blocks or self.BLOCKS))(hidden, head, labels)
+        want_nll, want_hit = _token_rows_plain(hidden, head, labels)
+        assert nll.shape == hit.shape == labels.shape
+        assert nll.dtype == hit.dtype == jnp.float32
+        assert np.isfinite(np.asarray(nll)).all()
+        np.testing.assert_allclose(nll, want_nll, rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(hit, want_hit)
+        return np.asarray(nll), np.asarray(hit)
+
+    @pytest.mark.parametrize("t, d, v", [
+        (32, 16, 128),      # one block of each
+        (64, 16, 256),      # two token blocks, whole vocabulary blocks
+        (32, 200, 128),     # a contraction of more than one lane tile
+        (32, 16, 200),      # a masked tail of 72 columns
+        (96, 48, 333),      # all at once, three vocabulary blocks
+        (32, 16, 72),       # a vocabulary smaller than one block
+    ], ids=lambda n: str(n))
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bf16", "f32"])
+    def test_is_the_plain_form(self, t, d, v, dtype):
+        self._agree(*self._operands(t, d, v, dtype, seed=t + d + v))
+
+    @pytest.mark.parametrize("where", ["first_column", "last_column",
+                                       "tail_block", "block_edge"])
+    def test_finds_the_labels_logit_wherever_it_lies(self, where):
+        hidden, head, labels = self._operands(32, 16, 200, jnp.bfloat16, 1)
+        labels[:] = {"first_column": 0, "last_column": 199,
+                     "tail_block": 150, "block_edge": 128}[where]
+        # the label's column made the largest logit of half the rows and
+        # the smallest of the others
+        head = head.at[:, labels[0]].set(0.0)
+        hidden = hidden.at[::2, 0].set(8.0).at[1::2, 0].set(-8.0)
+        head = head.at[0, labels[0]].set(4.0)
+        _, hit = self._agree(hidden, head, labels)
+        assert hit[::2].all() and not hit[1::2].any()
+
+    @pytest.mark.parametrize("columns", [(5, 9), (5, 150), (130, 199),
+                                         (0, 128)],
+                             ids=["one_block", "across_blocks", "in_the_tail",
+                                  "block_firsts"])
+    def test_the_lowest_index_wins_an_exact_tie(self, columns):
+        """Small whole numbers: every product and sum is exact in any
+        order, so the two columns' logits are equal to the bit."""
+        rng = np.random.default_rng(2)
+        hidden = jnp.asarray(rng.integers(-3, 4, (32, 16)), jnp.float32)
+        hidden = hidden.at[:, 0].set(3.0)
+        head = np.asarray(rng.integers(-1, 2, (16, 200)), np.float32)
+        head[:, columns[1]] = head[:, columns[0]] = 0.0
+        head[0, columns[0]] = head[0, columns[1]] = 40.0
+        labels = np.full(32, columns[0], np.int32)
+        labels[1::2] = columns[1]
+        _, hit = self._agree(hidden, jnp.asarray(head), labels)
+        assert hit[::2].all() and not hit[1::2].any()
+
+    def test_whole_number_logits_tie_as_argmax_lets_them(self):
+        rng = np.random.default_rng(3)
+        hidden = jnp.asarray(rng.integers(-2, 3, (64, 16)), jnp.float32)
+        head = jnp.asarray(rng.integers(-2, 3, (16, 333)), jnp.float32)
+        logits = np.asarray(hidden @ head)
+        assert ((logits == logits.max(-1, keepdims=True)).sum(-1) > 1).any()
+        self._agree(hidden, head, logits.argmax(-1).astype(np.int32))
+
+    def test_logits_past_the_range_of_exp(self):
+        """One row of logits in the hundreds, its maximum in the LAST
+        block: a sum of exponentials not rescaled to the running maximum
+        overflows (exp(89) is past float32), or underflows to log(0)."""
+        hidden, head, labels = self._operands(32, 16, 333, jnp.float32, 4)
+        hidden = hidden.at[3].multiply(300.0).at[3, 0].set(300.0)
+        head = head.at[0, 300].set(3.0)
+        logits = np.asarray(hidden @ head)
+        assert logits[3].max() > 800 and logits[3].argmax() == 300
+        nll, hit = self._agree(hidden, head, labels)
+        assert nll[3] > 100.0
+
+    def test_other_blocks_and_the_shapes_refused(self):
+        hidden, head, labels = self._operands(64, 32, 600, jnp.bfloat16, 5)
+        self._agree(hidden, head, labels, blocks=(64, 256))   # two groups
+        self._agree(hidden, head, labels, blocks=(16, 512))   # four, a tail
+        assert head_nll_takes(8192, 2560) and head_nll_takes(8192, 2048)
+        assert not head_nll_takes(8192 + 512, 2560)     # half a token block
+        assert not head_nll_takes(32, 64)               # the tiny models
+        # the contraction's blocks have to fit the kernel's VMEM
+        assert head_nll_takes(8192, 4096) and not head_nll_takes(8192, 8192)
+        assert not head_nll_takes(8192, 4096, itemsize=4)
+        with pytest.raises(ValueError, match="whole"):
+            head_nll_pallas(hidden, head, jnp.asarray(labels), (48, 128))
+        with pytest.raises(ValueError, match="whole"):
+            head_nll_pallas(hidden, head, jnp.asarray(labels), (64, 192))
 
 
 class TestAugmentNormalize:

@@ -154,6 +154,25 @@ class TestKernelsCompileForV5e:
             v5e_devices, (shape, dtype), *[((k,), jnp.float32)] * 3)
         assert text.count("tpu_custom_call") == 1
 
+    @pytest.mark.parametrize("d, v", [(2560, 18992), (2048, 16032)],
+                             ids=["st21b-is-8k", "kn2-is-8k"])
+    def test_head_nll(self, v5e_devices, mosaic, d, v):
+        """The head's kernel over vocabulary blocks at the two token cells'
+        shapes, bfloat16 operands: one sequence of 8,192 tokens, a
+        vocabulary that is no multiple of 128 (the last block masked), the
+        whole contraction's operand blocks, the 1,024 x 1,024 float32 tile
+        and the fold's temporaries in the VMEM the call asks for. What leaves is two float32 numbers a token."""
+        assert mercury_kernels.head_nll_takes(8192, d)
+        compiled = _compiled(
+            mercury_kernels.head_nll_pallas, v5e_devices,
+            ((8192, d), jnp.bfloat16), ((d, v), jnp.bfloat16),
+            ((8192,), jnp.int32))
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert "mercury_head_nll" in text
+        assert f"f32[8192,{v}]" not in text
+        assert compiled.memory_analysis().output_size_in_bytes < 2 ** 17
+
     def test_synced_unit_checks_vma(self, v5e_devices, mosaic):
         """A ``Bottleneck`` with ``bn_axis_name`` under a ``shard_map`` that
         checks varying manual axes, over the four chips: the kernel's sums
@@ -458,14 +477,27 @@ def test_token_cell_step_fits_one_v5e(v5e_devices, cell):
     attention is splash-attention's Mosaic kernels (grouped-query heads of
     128; latent attention's of 192 against 128), and the compiler's peak
     (state, bfloat16 weights, one row's activations and logits) lies under
-    the chip's limit. Three quarters of a minute to a minute and a half
-    each."""
+    the chip's limit. The scoring pass's head is the kernel over vocabulary
+    blocks (``mercury_head_nll`` under ``mercury_scoring``) and the only
+    whole ``[8192, V]`` float32 logits are the train pass's. Three quarters
+    of a minute to a minute and a half each."""
     from perfbench.cell import Cell
 
     fields = Cell(cell).train_config_fields(seed=7, trace=False)
     compiled = _compile_trainer_step(v5e_devices, 1, **fields)
     text = compiled.as_text()
     assert "splash_mqa" in text and "mercury_score_draw_kernel" in text
+    vocab = fields["num_classes"]
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "mercury_head_nll" in line]
+    assert kernels and all("mercury_scoring" in line
+                           and "mercury_train" not in line
+                           for line in kernels)
+    logits = [line for line in text.splitlines()
+              if f"f32[8192,{vocab}]" in line and "op_name" in line]
+    assert logits and all("mercury_train" in line
+                          and "mercury_scoring" not in line
+                          for line in logits)
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 0.99 * TOKEN_CELLS[cell]
     assert memory.peak_memory_in_bytes < HBM_LIMIT, memory
