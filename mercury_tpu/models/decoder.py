@@ -69,15 +69,20 @@ router's product, its sigmoid and its top-k in float32 from the normalised
 float32 stream, so that as few of its near-ties as the products' rounding
 allows fall the other way than in a float32 forward.
 
-Scopes (metadata only): ``mercury_attention`` (projections, rotation, the
-attention; latent attention whole under ``mercury_mla`` inside it, with what
-it adds around the kernel — ``kv_a``, the latent's norm, ``kv_b``, the split
-and the broadcast of the shared key part — under ``mercury_mla_latent``),
-``mercury_moe`` (router, grouping, expert products, return, the shared
-experts) with ``mercury_moe_route`` and ``mercury_moe_shared`` nested in it,
-``mercury_dense_mlp`` (a leading dense layer's MLP); ``mercury_lm_head`` is
-the seam's. The last layer's load and the share of all the routed layers that
-ran over the bounded rows are sowed into the ``MOE_LOAD`` collection.
+Scopes (metadata only; ``docs/OBSERVABILITY.md`` has the table): around the
+rows ``mercury_rows`` (``lax.map``; the once-a-pass casts before it hold a
+third of a percent of a step and have no scope); in a row ``mercury_embed``, ``mercury_norm`` (every
+``rms_norm`` and the cast behind it), ``mercury_attention`` (rotation, the
+attention, the transposes around it; the products into and out of the heads
+under ``mercury_attention_proj`` inside it; latent attention whole under
+``mercury_mla``, with what it adds around the kernel under
+``mercury_mla_latent``), ``mercury_moe`` (router, grouping, expert products,
+return, the shared experts) with ``mercury_moe_route`` and
+``mercury_moe_shared`` nested in it, ``mercury_dense_mlp`` (a leading dense
+layer's MLP); ``mercury_lm_head`` is the seam's. A branch's closing sum lies
+in the branch's scope: a fusion is booked to its root. The last layer's load
+and the share of all the routed layers that ran over the bounded rows are
+sowed into the ``MOE_LOAD`` collection.
 """
 
 from __future__ import annotations
@@ -176,9 +181,18 @@ def windowed_and_rotated(widths: LMWidths, index: int) -> Tuple[bool, bool]:
 
 
 def rms_norm(x, scale, eps: float):
-    x = x.astype(jnp.float32)
-    return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
-                         + eps) * scale.astype(jnp.float32)
+    with jax.named_scope("mercury_norm"):
+        x = x.astype(jnp.float32)
+        return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
+                             + eps) * scale.astype(jnp.float32)
+
+
+def normed_as(h, dtype):
+    """A norm's float32 output ``h`` in ``dtype``. The compiler fuses the
+    norm's last two products into the cast and books the fusion to the
+    cast's path: so the cast lies in the norm's scope."""
+    with jax.named_scope("mercury_norm"):
+        return h.astype(dtype)
 
 
 def rotate_half(x, theta: float, offset: int = 0):
@@ -306,8 +320,9 @@ def grouped_query_attention(w: LMWidths, h, p, windowed: bool, rotated: bool,
     cd, t, hd = h.dtype, h.shape[0], w.head_dim
 
     def heads(name):
-        return jnp.dot(h, p[name], preferred_element_type=jnp.float32
-                       ).reshape(t, -1, hd)
+        with jax.named_scope("mercury_attention_proj"):
+            return jnp.dot(h, p[name], preferred_element_type=jnp.float32
+                           ).reshape(t, -1, hd)
 
     q, k, v = heads("q"), heads("k"), heads("v")
     if rotated:
@@ -346,14 +361,15 @@ def latent_attention(w: LMWidths, h, p, attend):
     t, hd, rd = h.shape[0], w.head_dim, lat.rope_dim
 
     def dot(x, name):
-        return jnp.dot(x, p[name], preferred_element_type=jnp.float32)
+        with jax.named_scope("mercury_attention_proj"):
+            return jnp.dot(x, p[name], preferred_element_type=jnp.float32)
 
     q = dot(h, "q").reshape(t, -1, hd + rd)
     n = q.shape[1]
     with jax.named_scope("mercury_mla_latent"):
         down = dot(h, "kv_a")                               # [T, rank + rd]
-        latent = rms_norm(down[:, :lat.kv_rank], p["kv_norm"],
-                          w.norm_eps).astype(cd)
+        latent = normed_as(rms_norm(down[:, :lat.kv_rank], p["kv_norm"],
+                                    w.norm_eps), cd)
         up = dot(latent, "kv_b").reshape(t, n, hd + lat.v_head_dim)
         k_rope = down[:, None, lat.kv_rank:]                # one head
     q_rope, k_rope = (rotate_half(a, w.rope_theta)
@@ -526,7 +542,8 @@ class CausalDecoder(nn.Module):
             # the products' input rounding alone, not by eight roundings
             # of x itself, and fewer near-ties of its top-k fall the
             # other way
-            x = embed[ids].astype(jnp.float32)
+            with jax.named_scope("mercury_embed"):
+                x = embed[ids].astype(jnp.float32)
             bounded, load = 0.0, ()
             for i, block in enumerate(blocks):
                 x, routing = jax.checkpoint(functools.partial(
@@ -535,10 +552,14 @@ class CausalDecoder(nn.Module):
                     bounded += routing[2] / routed
                     load = routing
             # the last routed layer's load, with all of them's bounded share
-            return (rms_norm(x, final_norm, w.norm_eps).astype(cd),
+            return (normed_as(rms_norm(x, final_norm, w.norm_eps), cd),
                     load and (*load[:2], bounded, *load[3:]))
 
-        hidden, load = lax.map(row, tokens)
+        # what lax.map itself does (a row sliced out, its outputs written
+        # into the stack) and whatever else of a row's body bears no leaf's
+        # name is the scope's own
+        with jax.named_scope("mercury_rows"):
+            hidden, load = lax.map(row, tokens)
         for name, value in zip(("held_pair_share", "load_max_over_mean",
                                 "bounded_share", "bias_moved_share"), load):
             self.sow(MOE_LOAD, name, jnp.mean(value))
@@ -554,7 +575,7 @@ class CausalDecoder(nn.Module):
             # this rule's router reads what attention reads
             with jax.named_scope("mercury_moe"):
                 router_logits = self._router_logits(h, p)
-        h = h.astype(cd)
+        h = normed_as(h, cd)
         with jax.named_scope("mercury_attention"):
             if w.latent is None:
                 attn = grouped_query_attention(w, h, p, windowed, rotated,
@@ -562,13 +583,15 @@ class CausalDecoder(nn.Module):
             else:
                 with jax.named_scope("mercury_mla"):
                     attn = latent_attention(w, h, p, self._attend)
-            x = x + jnp.dot(attn, p["o"], preferred_element_type=jnp.float32)
+            with jax.named_scope("mercury_attention_proj"):
+                x = x + jnp.dot(attn, p["o"],
+                                preferred_element_type=jnp.float32)
         activation = _ACTIVATIONS[w.activation]
         # the MLP's norm lies in the MLP's scope, as it always has
         with jax.named_scope("mercury_dense_mlp" if dense else "mercury_moe"):
             h2 = rms_norm(x, p["post_norm"], w.norm_eps)
             if dense:
-                return x + gated_mlp(h2.astype(cd), p["dense_gate"],
+                return x + gated_mlp(normed_as(h2, cd), p["dense_gate"],
                                      p["dense_up"], p["dense_down"],
                                      activation), ()
             rule = {}
@@ -579,9 +602,10 @@ class CausalDecoder(nn.Module):
                 rule["shared"] = (p["shared_gate"], p["shared_up"],
                                   p["shared_down"])
             y, load = routed_experts(
-                h2.astype(cd), router_logits, p["gate"], p["up"], p["down"],
-                w.top_k, first_expert, activation=activation, **rule)
-        return x + y, load
+                normed_as(h2, cd), router_logits, p["gate"], p["up"],
+                p["down"], w.top_k, first_expert, activation=activation,
+                **rule)
+            return x + y, load
 
     @staticmethod
     def _router_logits(h, p):

@@ -154,9 +154,10 @@ def sequence_rows(outputs, labels: jax.Array,
     differentiates hold none at all."""
     hidden, head = outputs
     row_loss = jax.checkpoint(sequence_loss, static_argnums=(3,))
-    return jax.lax.map(
-        lambda row: row_loss(row[0], head, row[1], use_kernel),
-        (hidden, labels))
+    with jax.named_scope("mercury_rows"):
+        return jax.lax.map(
+            lambda row: row_loss(row[0], head, row[1], use_kernel),
+            (hidden, labels))
 
 
 def token_logits(outputs) -> jax.Array:

@@ -515,3 +515,17 @@ class TestSelectIngestTrajectory:
         assert len(new_leaves) == len(old_leaves)
         for a, b in zip(new_leaves, old_leaves):
             np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("fields, path, rows", [
+        (dict(), "select", "flat"),
+        (dict(cutout=True), "chain", "nhwc"),
+    ])
+    def test_the_instant_says_which_ingest_the_step_is_built_with(
+            self, mesh1, fields, path, rows):
+        """``trace=True`` leaves one ``trainer/ingest_path`` instant, what
+        ``_ingest_path`` says: no metric reads it, this test holds it."""
+        with Trainer(hs_cfg(trace=True, **fields), mesh=mesh1) as tr:
+            marks = [e["args"] for e in tr.tracer.snapshot()
+                     if e["name"] == "trainer/ingest_path"]
+            assert [(m["path"], m["rows"]) for m in marks] == [(path, rows)]
+            assert tr._ingest_path == path
