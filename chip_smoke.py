@@ -14,7 +14,8 @@ seeded synthetic data, and checks what comes out:
   the decoder's other mixer and routing rule (``latent-tiny``), zero
   compiles after each trainer's first call; beside each token phase the
   head's kernel over vocabulary blocks against the plain form, one
-  sequence at its benchmark cell's shape;
+  sequence at its benchmark cell's shape, and beside the first the
+  attention's query operand in one pass against the plain forms;
 - the Pallas kernels really compiled (Mosaic custom call in the compiled
   step, nothing in interpret mode) and each matches its jax-native twin
   standalone on the chip, at the shapes ``Trainer`` produces;
@@ -370,6 +371,53 @@ def _head_check(phase: str, tiny: bool, d: int, v: int) -> Dict[str, Any]:
     return facts
 
 
+def _operands_check(phase: str, tiny: bool, heads: int, hd: int,
+                    theta: float) -> Dict[str, Any]:
+    """The query operand of one sequence of 8,192 tokens at a token cell's
+    shape (``heads`` of ``hd``, rotated and scaled): the one-pass kernel
+    (``ops.rope_heads_pallas``) against ``rotate_half``, scale, cast and
+    transpose as the plain path writes them, and the two gradients of a
+    scalar of the operand. ``tiny``: 40 tokens of 2 heads, one block."""
+    import jax
+    import jax.numpy as jnp
+
+    from mercury_tpu.models.decoder import rope_tables, rotate_half
+    from mercury_tpu.ops import mercury_kernels
+
+    t, heads = (40, 2) if tiny else (8192, heads)
+    k1, k2 = jax.random.split(jax.random.key(heads))
+    x = jax.random.normal(k1, (t, heads * hd))
+    c = jax.random.normal(k2, (heads, t, hd))
+    scale, tables = hd ** -0.5, rope_tables(t, hd, theta)
+
+    def plain(x):
+        x = rotate_half(x.reshape(t, heads, hd), theta) * scale
+        return x.astype(jnp.bfloat16).transpose(1, 0, 2)
+
+    def kernel(x):
+        return mercury_kernels.rope_heads_pallas(x, tables, hd, scale,
+                                                 jnp.bfloat16)
+
+    def both(fn):
+        return jax.jit(jax.grad(
+            lambda x: jnp.sum(fn(x).astype(jnp.float32) * c)))(x), \
+            jax.jit(fn)(x)
+
+    (grad_k, out_k), (grad_p, out_p) = both(kernel), both(plain)
+    out_k, out_p = (a.astype(jnp.float32) for a in (out_k, out_p))
+    facts = dict(shape=f"{t}x{heads}x{hd}",
+                 operand_differs=float(jnp.mean(out_k != out_p)),
+                 operand_max_abs=float(jnp.max(jnp.abs(out_k - out_p))),
+                 grad_max_abs=float(jnp.max(jnp.abs(grad_k - grad_p))))
+    _say(f"{phase}/operands", **facts)
+    # float32 on both sides, a multiply-add contracted here and not there:
+    # the last bit, which a cast to bfloat16 shows once in thousands
+    _require(facts["operand_differs"] <= 1e-2, f"{phase}/operands: {facts}")
+    _require(facts["operand_max_abs"] <= 2 ** -5, f"{phase}/operands: {facts}")
+    _require(facts["grad_max_abs"] <= 1e-5, f"{phase}/operands: {facts}")
+    return facts
+
+
 # -------------------------------------------------------------------- run
 def run(tiny: bool = False) -> Dict[str, Any]:
     """The smoke body. ``tiny=True`` is the ``smallcnn`` miniature for the
@@ -404,6 +452,8 @@ def run(tiny: bool = False) -> Dict[str, Any]:
         presample_batches=3, augmentation="none", pipelined_scoring=True)
     out["one_chip_tokens"]["head"] = _head_check(
         "one_chip_tokens", tiny, 2560, 18992)       # st21b-is-8k
+    out["one_chip_tokens"]["operands"] = _operands_check(
+        "one_chip_tokens", tiny, 28, 128, 1_500_000.0)
     # The same path through the other mixer and the other routing rule
     # (latent attention, a sigmoid router with a selection bias, a shared
     # expert, a leading dense layer) on a share of the heads: heads of

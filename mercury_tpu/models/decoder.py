@@ -195,15 +195,32 @@ def normed_as(h, dtype):
         return h.astype(dtype)
 
 
+def _rope_angles(t: int, hd: int, theta: float, offset: int = 0):
+    """The angles of rotate-half, ``[T, hd]`` float32: column ``i`` and
+    column ``i + hd/2`` turn by ``position / theta ** (2i / hd)``."""
+    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    angle = (offset + jnp.arange(t, dtype=jnp.float32))[:, None] * inv[None]
+    return jnp.concatenate([angle, angle], -1)
+
+
 def rotate_half(x, theta: float, offset: int = 0):
     """RoPE (rotate-half over the whole head) of ``x [T, H, hd]`` float32
     at positions ``offset .. offset + T - 1``."""
     t, hd = x.shape[0], x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    angle = (offset + jnp.arange(t, dtype=jnp.float32))[:, None] * inv[None]
-    angle = jnp.concatenate([angle, angle], -1)[:, None, :]
+    angle = _rope_angles(t, hd, theta, offset)[:, None, :]
     x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
     return x * jnp.cos(angle) + jnp.concatenate([-x2, x1], -1) * jnp.sin(angle)
+
+
+def rope_tables(t: int, hd: int, theta: float):
+    """``(cos, signed sin)`` of :func:`rotate_half`'s angles at positions
+    ``0 .. t - 1``, each ``[T, hd]`` float32, the sine's first half negated:
+    ``rotate_half(x) == x * cos + roll(x, hd/2) * signed sin`` to the bit
+    (``(-a) * b == a * (-b)``). Made once a row, for every layer
+    (``ops.rope_heads_pallas`` reads them)."""
+    angle = _rope_angles(t, hd, theta)
+    sign = jnp.where(jnp.arange(hd) < hd // 2, -1.0, 1.0)
+    return jnp.cos(angle), jnp.sin(angle) * sign
 
 
 def side_by_side(x):
@@ -311,27 +328,39 @@ def splash_takes(t: int, head_dim: int, v_head_dim: Optional[int] = None
 
 
 def grouped_query_attention(w: LMWidths, h, p, windowed: bool, rotated: bool,
-                            attend):
+                            attend, tables=None):
     """Grouped-query attention of ``h [T, D]`` (the compute precision) by
     the matrices ``p["q"]``, ``p["k"]``, ``p["v"]`` of the heads held ->
     the heads' outputs joined, ``[T, heads x head_dim]``. ``attend(q, k, v,
     window)`` is the attention itself (shapes as
-    :func:`_masked_attention`)."""
+    :func:`_masked_attention`). With ``tables`` (:func:`rope_tables`) each
+    operand is made from its float32 product in one pass
+    (``ops.rope_heads_pallas``: rotation, scale, cast and the head-major
+    layout as one read and one write); without, by the plain forms."""
     cd, t, hd = h.dtype, h.shape[0], w.head_dim
 
-    def heads(name):
+    def product(name):
         with jax.named_scope("mercury_attention_proj"):
-            return jnp.dot(h, p[name], preferred_element_type=jnp.float32
-                           ).reshape(t, -1, hd)
+            return jnp.dot(h, p[name], preferred_element_type=jnp.float32)
 
-    q, k, v = heads("q"), heads("k"), heads("v")
-    if rotated:
-        q, k = (rotate_half(a, w.rope_theta) for a in (q, k))
-    q = q * (hd ** -0.5)
-    kv_heads = k.shape[1]
-    q = q.astype(cd).reshape(t, kv_heads, q.shape[1] // kv_heads, hd)
-    q = q.transpose(1, 2, 0, 3)                         # [KV, G, T, hd]
-    k, v = (a.astype(cd).transpose(1, 0, 2) for a in (k, v))
+    if tables is not None:
+        def operand(name, rope, scale=1.0):
+            return mercury_kernels.rope_heads_pallas(product(name), rope, hd,
+                                                     scale, cd)
+
+        rope = tables if rotated else None
+        q = operand("q", rope, hd ** -0.5)
+        k, v = operand("k", rope), operand("v", None)
+        q = q.reshape(k.shape[0], -1, t, hd)                # [KV, G, T, hd]
+    else:
+        q, k, v = (product(name).reshape(t, -1, hd) for name in "qkv")
+        if rotated:
+            q, k = (rotate_half(a, w.rope_theta) for a in (q, k))
+        q = q * (hd ** -0.5)
+        kv_heads = k.shape[1]
+        q = q.astype(cd).reshape(t, kv_heads, q.shape[1] // kv_heads, hd)
+        q = q.transpose(1, 2, 0, 3)                         # [KV, G, T, hd]
+        k, v = (a.astype(cd).transpose(1, 0, 2) for a in (k, v))
     attn = attend(q, k, v, w.window if windowed else None)
     return attn.transpose(2, 0, 1, 3).reshape(t, -1)
 
@@ -504,6 +533,25 @@ class CausalDecoder(nn.Module):
                              f"(one of {sorted(_ACTIVATIONS)})")
         return layers, first, held, heads
 
+    def _one_pass(self) -> bool:
+        """Whether a layer's three operands of the attention are made from
+        their products in one pass: on the TPU, at widths the kernel takes
+        (grouped-query heads of whole lanes; latent attention's 128 + 64,
+        rotated in part, are not)."""
+        w = self.widths
+        width, rope = (
+            (w.head_dim, w.head_dim) if w.latent is None else
+            (w.head_dim + w.latent.rope_dim, w.latent.rope_dim))
+        return self.use_pallas and mercury_kernels.rope_heads_takes(width,
+                                                                    rope)
+
+    def operand_sites(self) -> Tuple[int, int]:
+        """``(one-pass, plain)``: how many operands of the attention (three
+        a layer) one forward makes in the one-pass form, and how many by
+        the plain forms."""
+        sites = 3 * self._sizes()[0]
+        return (sites, 0) if self._one_pass() else (0, sites)
+
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         w, pd, cd = self.widths, self.param_dtype, self.compute_dtype
@@ -534,8 +582,16 @@ class CausalDecoder(nn.Module):
                    for name, a in block.items()} for block in blocks]
         if w.latent is not None:
             blocks = [pairs_side_by_side(w, block) for block in blocks]
+        one_pass = self._one_pass()
 
         def row(ids):
+            # the rotation's cosines and signed sines, once for all the
+            # row's layers, where the operands are made in one pass (made
+            # outside the map, once a pass, they cost the v5e's compiler
+            # 600 MiB of peak: it then runs the pool's rows after the
+            # train pass and holds their stack across it; PERF.md §6)
+            tables = (rope_tables(tokens.shape[1], w.head_dim, w.rope_theta)
+                      if one_pass else None)
             # the residual stream stays float32 (each layer adds its two
             # float32-accumulated products to it unrounded): what the
             # router reads then differs from the plain float32 forward by
@@ -547,7 +603,8 @@ class CausalDecoder(nn.Module):
             bounded, load = 0.0, ()
             for i, block in enumerate(blocks):
                 x, routing = jax.checkpoint(functools.partial(
-                    self._layer, index=i, first_expert=first))(x, block)
+                    self._layer, index=i, first_expert=first))(x, block,
+                                                               tables)
                 if routing:     # a dense layer routes nothing
                     bounded += routing[2] / routed
                     load = routing
@@ -566,7 +623,7 @@ class CausalDecoder(nn.Module):
         return hidden, head.astype(cd)
 
     # ------------------------------------------------------------ a layer
-    def _layer(self, x, p, index: int, first_expert: int):
+    def _layer(self, x, p, tables, index: int, first_expert: int):
         w, cd = self.widths, self.compute_dtype
         windowed, rotated = windowed_and_rotated(w, index)
         dense = index < w.dense_layers
@@ -579,7 +636,7 @@ class CausalDecoder(nn.Module):
         with jax.named_scope("mercury_attention"):
             if w.latent is None:
                 attn = grouped_query_attention(w, h, p, windowed, rotated,
-                                               self._attend)
+                                               self._attend, tables)
             else:
                 with jax.named_scope("mercury_mla"):
                     attn = latent_attention(w, h, p, self._attend)

@@ -33,12 +33,22 @@ A fourth serves the loss seam of rows of per-token labels:
    (``sampling/importance.py::sequence_loss`` where nothing differentiates
    the pass; PERF.md §6, PR 45).
 
+A fifth serves a decoder's attention:
+
+5. :func:`rope_heads_pallas` — an operand of the attention kernel from its
+   float32 product in one pass: a grid of (token blocks, heads) reads the
+   ``[T, heads x hd]`` product a head's block at a time, rotates it (where
+   the layer rotates), scales it (queries), casts it and writes it head-major,
+   ``[heads, T, hd]``; the transpose is the two ``BlockSpec``s' index maps. Its
+   ``custom_vjp`` is the same kernel the other way (``models/decoder.py::
+   grouped_query_attention``; PERF.md §6, PR 47).
+
 Uniform variates are passed in (from ``jax.random``) rather than drawn with
 the in-kernel TPU PRNG, so the draw is reproducible from a JAX key and the
 kernels run identically under ``interpret=True`` on CPU (how the test suite
 exercises them without a chip).
 
-Kernels 1 and 2 are a single block each, no grid; 3 and 4 run over one. The
+Kernels 1 and 2 are a single block each, no grid; 3 to 5 run over one. The
 draw kernel holds its scores lane-dense — ``[N/128, 128]`` f32, 4 bytes per candidate — because Mosaic
 tiles an ``[N, 1]`` f32 column ``(8, 128)``, 512 bytes per candidate: a
 50,000-slot table is 0.2 MB lane-dense and 24 MB as a column, past the
@@ -571,3 +581,108 @@ def head_nll_pallas(hidden: jax.Array, head: jax.Array, labels: jax.Array,
         interpret=_interpret(),
     )(hidden, head, labels.reshape(t, 1).astype(jnp.int32))
     return nll[:, 0], hit[:, 0]
+
+
+# ----------------------------------------------------------------- kernel 5
+#: Tokens of one grid step of :func:`rope_heads_pallas` (a head's block of
+#: the product, the two tables' and the operand's: 3.5 MiB double-buffered
+#: at heads of 128).
+ROPE_BLOCK = 1024
+
+
+def rope_heads_takes(head_dim: int, rope_dim: int) -> bool:
+    """Whether :func:`rope_heads_pallas` takes heads of ``head_dim`` of
+    which ``rope_dim`` columns are rotated: a head is whole lanes (its
+    columns of the flat product are a block of their own), rotated whole or
+    not at all (a roll by half the head pairs the columns). Any number of
+    tokens and of heads."""
+    return head_dim % _LANES == 0 and rope_dim in (0, head_dim)
+
+
+def _rope_heads_kernel(x_ref, *refs, scale: float, transposed: bool):
+    """One head's ``[rows, hd]`` block in float32: ``y = (x * cos + roll(x,
+    hd/2) * sin) * scale`` with ``sin`` signed (minus on the first half), or,
+    ``transposed``, the same linear map's transpose ``dx = g' * cos +
+    roll(g' * sin, hd/2)`` of ``g' = g * scale``: the order of operations of
+    ``rotate_half``, the scale and the cast, and of their autodiff. No
+    tables: no rotation."""
+    *tables, out_ref = refs
+    x = x_ref[...].astype(jnp.float32)
+    if transposed and scale != 1.0:
+        x = x * scale
+    if tables:
+        cos, sin = (ref[...] for ref in tables)
+        half = x.shape[-1] // 2
+        if transposed:
+            x = x * cos + pltpu.roll(x * sin, half, 1)
+        else:
+            x = x * cos + pltpu.roll(x, half, 1) * sin
+    if not transposed and scale != 1.0:
+        x = x * scale
+    out_ref[...] = x.astype(out_ref.dtype)
+
+
+def _rope_heads_call(x, tables, head_dim: int, scale: float, dtype,
+                     transposed: bool):
+    """The kernel one way (``x [T, heads x hd]`` -> ``[heads, T, hd]``) or
+    the other (``transposed``): which side is flat and which head-major is
+    the index maps' alone."""
+    tables = tuple(tables or ())
+    if (not rope_heads_takes(head_dim, tables[0].shape[1] if tables else 0)
+            or x.shape[-1] % head_dim):
+        raise ValueError(
+            f"{x.shape[-1]} columns in heads of {head_dim}, the tables "
+            f"{[a.shape for a in tables]}: not whole heads of whole lanes "
+            f"({_LANES}) rotated whole or not at all")
+    if transposed:
+        heads, t, _ = x.shape
+        out_shape = (t, heads * head_dim)
+    else:
+        t, heads = x.shape[0], x.shape[1] // head_dim
+        out_shape = (heads, t, head_dim)
+    rows = min(ROPE_BLOCK, t)
+    flat = pl.BlockSpec((rows, head_dim), lambda i, h: (i, h))
+    major = pl.BlockSpec((None, rows, head_dim), lambda i, h: (h, i, 0))
+    # the heads are the inner axis: a token block's tables are read once
+    table = pl.BlockSpec((rows, head_dim), lambda i, h: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_rope_heads_kernel, scale=scale,
+                          transposed=transposed),
+        grid=(pl.cdiv(t, rows), heads),
+        in_specs=[major if transposed else flat] + [table] * len(tables),
+        out_specs=flat if transposed else major,
+        # under a ``shard_map`` that checks them: it varies as the product does
+        out_shape=jax.ShapeDtypeStruct(out_shape, dtype,
+                                       vma=jax.typeof(x).vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        name="mercury_rope_heads",
+        interpret=_interpret(),
+    )(x, *tables)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def rope_heads_pallas(x: jax.Array, tables, head_dim: int, scale: float,
+                      dtype) -> jax.Array:
+    """An operand of the attention kernel, ``[heads, T, hd]`` in ``dtype``,
+    from ONE read of its float32 product ``x [T, heads x hd]``: rotated
+    (rotate-half over the whole head) by ``tables = (cos, signed sin)``, each
+    ``[T, hd]`` float32 (``models/decoder.py::rope_tables``; None: no
+    rotation), times ``scale``, cast; all in float32 before the cast, in
+    ``rotate_half``'s order of operations. Shapes: :func:`rope_heads_takes`.
+    The map is linear: its gradient is the same kernel the other way, float32
+    ``[T, heads x hd]`` from the cotangent in ``dtype``, and keeps the tables
+    alone."""
+    return _rope_heads_call(x, tables, head_dim, scale, dtype, False)
+
+
+def _rope_heads_fwd(x, tables, head_dim, scale, dtype):
+    return _rope_heads_call(x, tables, head_dim, scale, dtype, False), tables
+
+
+def _rope_heads_bwd(head_dim, scale, dtype, tables, g):
+    dx = _rope_heads_call(g, tables, head_dim, scale, jnp.float32, True)
+    return dx, jax.tree.map(jnp.zeros_like, tables)
+
+
+rope_heads_pallas.defvjp(_rope_heads_fwd, _rope_heads_bwd)

@@ -26,7 +26,9 @@ loss and hits from blocks of the vocabulary in one kernel
 (``ops.head_nll_pallas``) and writes no logits; the train pass runs the plain
 product and its transpose, one row's logits at a time (the seam's
 ``custom_vjp``). How many rows of a step took the kernel is counted as the
-step is traced (``trace_facts["head_kernel_rows"]``).
+step is traced (``trace_facts["head_kernel_rows"]``), as is how many
+operands of the decoder's attention took the one-pass form
+(``trace_facts["rope_kernel_sites"]``).
 """
 
 from __future__ import annotations
@@ -159,17 +161,22 @@ def _note_moment_units(ctx: StepContext, model_state) -> None:
             jax.tree_util.tree_leaves(model_state.get(MOMENT_UNITS, {})))
 
 
-def _note_head_rows(ctx: StepContext, outputs) -> None:
+def _note_token_kernels(ctx: StepContext, outputs) -> None:
     """A forward that nothing differentiates is reduced through the loss
     seam (token rows have one such pass a step, the pool's scoring): its
     rows' heads run in the kernel over vocabulary blocks
     (``head_kernel_rows``) or, where that is not asked for or refuses the
-    shape, in the plain form (``head_plain_rows``)."""
+    shape, in the plain form (``head_plain_rows``); and the model that ran
+    it makes so many operands of its attention in one pass
+    (``rope_kernel_sites``) and so many by the plain forms
+    (``rope_plain_sites``): ``CausalDecoder.operand_sites``."""
     if ctx.trace_facts is not None and ctx.mode.token_rows:
         hidden = outputs[0]
         kernel = head_takes_kernel(hidden, ctx.mode.use_pallas)
         ctx.trace_facts["head_kernel_rows"] = hidden.shape[0] * kernel
         ctx.trace_facts["head_plain_rows"] = hidden.shape[0] * (not kernel)
+        (ctx.trace_facts["rope_kernel_sites"],
+         ctx.trace_facts["rope_plain_sites"]) = ctx.model.operand_sites()
 
 
 def _apply(ctx: StepContext, module, params, batch_stats, images,
@@ -188,7 +195,7 @@ def _apply(ctx: StepContext, module, params, batch_stats, images,
     outputs, written = module.apply(variables, images, train=True,
                                     mutable=mutable)
     if moment_units:
-        _note_head_rows(ctx, outputs)
+        _note_token_kernels(ctx, outputs)
     return ctx.rows.reduce(outputs, labels), written
 
 

@@ -355,8 +355,12 @@ def make_train_step(
     ``head_kernel_rows`` / ``head_plain_rows``, how many rows of the
     scoring pass take loss and hits from the kernel over vocabulary blocks
     and how many from whole logits (``sampling/importance.py::
-    sequence_loss``); ``Trainer`` reports them as the instants
-    ``trainer/bn_moment_units`` and ``trainer/head_kernel_rows``.
+    sequence_loss``), and ``rope_kernel_sites`` / ``rope_plain_sites``, how
+    many operands of a decoder's attention one forward makes from their
+    products in one pass and how many by the plain forms
+    (``models/decoder.py::CausalDecoder.operand_sites``); ``Trainer``
+    reports them as the instants ``trainer/bn_moment_units``,
+    ``trainer/head_kernel_rows`` and ``trainer/rope_kernel_sites``.
 
     SHARDING CONTRACT (graftlint Layer 3, ``lint/sharding.py``,
     docs/LINT.md): the inputs are pinned with ``with_sharding_constraint``

@@ -1397,6 +1397,12 @@ class Trainer:
                 "trainer/head_kernel_rows", cat="trainer",
                 rows=self._trace_facts.get("head_kernel_rows", 0),
                 plain_rows=self._trace_facts.get("head_plain_rows", 0))
+            # And how many operands of a decoder's attention (three a
+            # layer) are made from their products in one pass.
+            self.tracer.instant(
+                "trainer/rope_kernel_sites", cat="trainer",
+                sites=self._trace_facts.get("rope_kernel_sites", 0),
+                plain_sites=self._trace_facts.get("rope_plain_sites", 0))
             return final_metrics
 
     def _note_moe_load(self, record: Dict[str, Any]) -> None:

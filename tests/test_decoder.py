@@ -456,6 +456,8 @@ def test_fit_on_the_token_dataset(world):
     heads = [e for e in events if e["name"] == "trainer/head_kernel_rows"]
     assert len(heads) == 1
     assert (heads[0]["args"]["rows"], heads[0]["args"]["plain_rows"]) == (0, 6)
+    # nor the one-pass operands: four layers' q, k, v by the plain forms
+    assert _operand_sites(events) == [(0, 12)]
 
 
 @pytest.mark.parametrize("fields, match", [
@@ -512,10 +514,20 @@ def _scoped(jaxpr, prefix=""):
                     yield from _scoped(v, path)
 
 
-def _head_kernels(jaxpr):
+def _kernels(jaxpr, name):
     return [path for path, eqn in _scoped(jaxpr)
             if eqn.primitive.name == "pallas_call"
-            and eqn.params["name"] == "mercury_head_nll"]
+            and eqn.params["name"] == name]
+
+
+def _head_kernels(jaxpr):
+    return _kernels(jaxpr, "mercury_head_nll")
+
+
+def _operand_sites(events):
+    """``(sites, plain_sites)`` of each ``trainer/rope_kernel_sites``."""
+    return [(e["args"]["sites"], e["args"]["plain_sites"]) for e in events
+            if e["name"] == "trainer/rope_kernel_sites"]
 
 
 def _whole_logits(jaxpr, rows=T, vocab=VOCAB):
@@ -610,6 +622,9 @@ def test_the_default_blocks_refuse_the_tiny_rows_and_the_trace_says_so():
     assert not _head_kernels(step)
     heads = [e for e in events if e["name"] == "trainer/head_kernel_rows"]
     assert (heads[-1]["args"]["rows"], heads[-1]["args"]["plain_rows"]) == (0, 6)
+    # heads of 16 are no whole lanes: the operands' kernel refuses them too
+    assert not _kernels(step, "mercury_rope_heads")
+    assert _operand_sites(events) == [(0, 12)]
 
 
 @pytest.mark.parametrize("model", [TINY, LATENT], ids=["softmax", "latent"])
@@ -646,6 +661,10 @@ def test_the_step_runs_the_kernel_where_nothing_differentiates(small_blocks,
     assert np.isfinite(out["test/eval_loss"])
     heads = [e for e in events if e["name"] == "trainer/head_kernel_rows"]
     assert (heads[-1]["args"]["rows"], heads[-1]["args"]["plain_rows"]) == (6, 0)
+    # neither tiny model has heads the operands' kernel takes (16; 128 + 64
+    # rotated in part): three plain sites a layer
+    assert not _kernels(step, "mercury_rope_heads")
+    assert _operand_sites(events) == [(0, 3 * cut[0])]
 
 
 def test_the_kernels_eval_is_the_plain_eval(small_blocks):
@@ -660,6 +679,206 @@ def test_the_kernels_eval_is_the_plain_eval(small_blocks):
     assert got["test/eval_loss"] == pytest.approx(want["test/eval_loss"],
                                                   rel=1e-5)
     assert got["test/eval_acc"] == want["test/eval_acc"]
+
+
+# ------------------------- the attention's operands in one pass (PR 47)
+#: The tiny model with heads the operands' kernel takes (128: whole lanes):
+#: 2 query heads on 1 key/value head, layer 0 unrotated, layers 1-3 rotated.
+LANES = "smallthinker-tiny-128"
+LM_WIDTHS.setdefault(LANES, WIDTHS._replace(num_heads=2, head_dim=128))
+
+
+@pytest.fixture
+def small_rope_block(monkeypatch):
+    """Token blocks of 16, so that T = 32 is two whole blocks and T = 40 two
+    and a half; read as a call is traced (see ``small_blocks``)."""
+    from mercury_tpu.ops import mercury_kernels
+
+    monkeypatch.setattr(mercury_kernels, "ROPE_BLOCK", 16)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _as_written_before(x, hd, theta, scale, dtype):
+    """An operand as the plain path makes it: ``rotate_half`` (``theta``
+    None: no rotation), the scale, the cast, the transpose to head-major."""
+    x = x.reshape(x.shape[0], -1, hd)
+    if theta is not None:
+        x = decoder.rotate_half(x, theta)
+    return (x * scale).astype(dtype).transpose(1, 0, 2)
+
+
+@pytest.mark.parametrize("t", [32, 40], ids=["whole-blocks", "ragged"])
+@pytest.mark.parametrize("theta, scale", [
+    (10_000.0, 128 ** -0.5), (10_000.0, 1.0), (None, 1.0)],
+    ids=["rotated-scaled", "rotated", "neither"])
+def test_the_one_pass_operand_is_the_plain_one(small_rope_block, theta, scale,
+                                               t):
+    """A query (rotated, scaled), a key (rotated) and a value or an
+    unrotated layer's operand (cast and layout alone) from the kernel
+    (interpreted) against ``rotate_half``, scale, cast and transpose as the
+    plain path writes them: the float32 values and the float32 gradient of
+    a scalar of the operand equal to float32's rounding (XLA:CPU contracts a
+    multiply-add here and not there), so the bfloat16 operand equal but
+    where that last bit crosses a rounding boundary (one element in ten
+    thousand, by one bfloat16 step), at a T of whole blocks and at one that
+    ends inside a block."""
+    from mercury_tpu.ops import rope_heads_pallas
+
+    rng = np.random.default_rng(t)
+    x = jnp.asarray(rng.standard_normal((t, 3 * 128)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((3, t, 128)), jnp.float32)
+    tables = theta and decoder.rope_tables(t, 128, theta)
+
+    def scalar(fn):
+        return lambda x: jnp.sum(fn(x).astype(jnp.float32) * c)
+
+    def kernel(x, dtype=jnp.bfloat16):
+        return rope_heads_pallas(x, tables, 128, scale, dtype)
+
+    def plain(x, dtype=jnp.bfloat16):
+        return _as_written_before(x, 128, theta, scale, dtype)
+
+    np.testing.assert_allclose(kernel(x, jnp.float32), plain(x, jnp.float32),
+                               atol=1e-6)
+    got, want = kernel(x), plain(x)
+    assert got.shape == (3, t, 128) and got.dtype == jnp.bfloat16
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    np.testing.assert_allclose(got, want, rtol=2 ** -7)
+    assert np.mean(got != want) < 1e-3
+    np.testing.assert_allclose(jax.grad(scalar(kernel))(x),
+                               jax.grad(scalar(plain))(x), atol=1e-6)
+
+
+def test_the_hoisted_tables_are_the_per_row_ones():
+    """The tables made once a pass hold what ``rotate_half`` computes in
+    each row: the same cosines, the same sines with the first half's sign
+    turned; and a roll by half a head is its ``[-x2, x1]`` up to that
+    sign."""
+    hd, theta = 128, 1_500_000.0
+    cos, sin = decoder.rope_tables(T, hd, theta)
+    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    angle = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
+    angle = jnp.concatenate([angle, angle], -1)
+    np.testing.assert_array_equal(cos, jnp.cos(angle))
+    np.testing.assert_array_equal(sin[:, hd // 2:], jnp.sin(angle)[:, hd // 2:])
+    np.testing.assert_array_equal(sin[:, :hd // 2],
+                                  -jnp.sin(angle)[:, :hd // 2])
+    x = jnp.asarray(np.random.default_rng(2).standard_normal((T, 3, hd)),
+                    jnp.float32)
+    np.testing.assert_allclose(
+        x * cos[:, None] + jnp.roll(x, hd // 2, -1) * sin[:, None],
+        decoder.rotate_half(x, theta), atol=1e-6)
+
+
+@pytest.mark.parametrize("head_dim, rope_dim, takes", [
+    (128, 128, True), (128, 0, True), (256, 256, True), (16, 16, False),
+    (64, 64, False), (192, 64, False), (256, 64, False)])
+def test_which_heads_the_operands_kernel_takes(head_dim, rope_dim, takes):
+    """Heads of whole lanes, rotated whole or not at all: grouped-query
+    heads of 128 both ways; not the tiny model's 16, not half a lane-tile,
+    not latent attention's 128 + 64, nor any head rotated in part. A shape
+    it refuses raises in the kernel's wrapper and never reaches it from the
+    decoder."""
+    from mercury_tpu.ops import rope_heads_pallas, rope_heads_takes
+
+    assert rope_heads_takes(head_dim, rope_dim) is takes
+    if not takes:
+        tables = rope_dim and decoder.rope_tables(T, rope_dim, 10_000.0)
+        with pytest.raises(ValueError, match="whole lanes"):
+            rope_heads_pallas(jnp.zeros((T, 2 * head_dim)), tables or None,
+                              head_dim, 1.0, jnp.bfloat16)
+
+
+def _logits_and_grads(name, cut, use_pallas, seed=3):
+    model = create_model(name, num_classes=VOCAB, cut=cut,
+                         compute_dtype="float32", use_pallas=use_pallas)
+    tokens = jnp.asarray(np.random.default_rng(seed).integers(
+        0, VOCAB, (2, T)), jnp.int32)
+    params = model.init(jax.random.key(seed), tokens)["params"]
+
+    def logits(params):
+        return token_logits(model.apply({"params": params}, tokens))
+
+    jaxpr = jax.make_jaxpr(logits)(params).jaxpr
+    value, grads = jax.value_and_grad(
+        lambda p: jnp.sum(jnp.square(logits(p))))(params)
+    return model, jaxpr, logits(params), value, grads
+
+
+def test_a_decoder_with_one_pass_operands_is_the_plain_decoder(
+        small_rope_block):
+    """The whole tiny decoder at heads of 128, its operands from the kernel
+    (``use_pallas``; T = 32 keeps the attention itself blockwise on both
+    sides) against the plain forms: logits and every parameter's gradient,
+    within the tolerance the splash path is held to; twelve sites one way,
+    twelve the other, the kernel three times a layer and a row in the
+    forward."""
+    model, jaxpr, got, _, grads = _logits_and_grads(LANES, (4, 0, 4), True)
+    plain, plain_jaxpr, want, _, plain_grads = _logits_and_grads(
+        LANES, (4, 0, 4), False)
+    assert model.operand_sites() == (12, 0)
+    assert plain.operand_sites() == (0, 12)
+    kernels = _kernels(jaxpr, "mercury_rope_heads")
+    assert len(kernels) == 12 and all("mercury_attention" in path
+                                      and "mercury_attention_proj" not in path
+                                      for path in kernels)
+    assert not _kernels(plain_jaxpr, "mercury_rope_heads")
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    flat, plain_flat = (jax.tree_util.tree_leaves_with_path(g)
+                        for g in (grads, plain_grads))
+    for (path, a), (_, b) in zip(flat, plain_flat):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-4 * float(jnp.abs(b).max()) + 1e-6,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_latent_attentions_split_heads_stay_with_the_plain_forms():
+    """Latent attention's operands (128 position-free + 64 rotated columns a
+    head, one shared rotated key part) are refused by the predicate from
+    their shapes: with ``use_pallas`` the decoder traces no operands' kernel,
+    counts six plain sites in two layers and computes, bit for bit, what it
+    computes without."""
+    model, jaxpr, got, value, _ = _logits_and_grads(
+        LATENT, (2, 0, 4, 0, 2), True)
+    plain, _, want, plain_value, _ = _logits_and_grads(
+        LATENT, (2, 0, 4, 0, 2), False)
+    assert model.operand_sites() == plain.operand_sites() == (0, 6)
+    assert not _kernels(jaxpr, "mercury_rope_heads")
+    np.testing.assert_array_equal(got, want)
+    assert float(value) == float(plain_value)
+
+
+@pytest.mark.parametrize("use_pallas, sites", [(True, (12, 0)),
+                                               (False, (0, 12))],
+                         ids=["kernel", "plain"])
+def test_fit_counts_the_one_pass_operand_sites(small_rope_block, use_pallas,
+                                               sites):
+    """``trainer/rope_kernel_sites``, once a ``fit()``: with ``use_pallas``
+    at heads the kernel takes, every operand of the four layers; without,
+    none; and the step holds the kernel in the scoring pass and in the
+    train pass, forward, recomputed forward and transposed."""
+    from mercury_tpu.train import Trainer
+
+    with Trainer(_config(model=LANES, use_pallas=use_pallas)) as t:
+        step = jax.make_jaxpr(t.train_step)(
+            t.state, t._step_x, t._step_y, t.dataset.shard_indices).jaxpr
+        out = t.fit(num_epochs=2)
+        assert (t._trace_facts["rope_kernel_sites"],
+                t._trace_facts["rope_plain_sites"]) == sites
+        events = t.tracer.snapshot()
+    assert np.isfinite(out["test/eval_loss"])
+    assert _operand_sites(events) == [sites]
+    kernels = _kernels(step, "mercury_rope_heads")
+    if not use_pallas:
+        assert not kernels
+        return
+    assert all("mercury_attention" in path for path in kernels)
+    assert any("mercury_scoring" in path for path in kernels)
+    train = [path for path in kernels if "mercury_train" in path]
+    assert any("transpose" in path for path in train)
+    assert any("rematted_computation" in path for path in train)
 
 
 # --------------------------------- the other mixer and the other routing rule

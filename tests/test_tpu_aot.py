@@ -479,8 +479,15 @@ def test_token_cell_step_fits_one_v5e(v5e_devices, cell):
     (state, bfloat16 weights, one row's activations and logits) lies under
     the chip's limit. The scoring pass's head is the kernel over vocabulary
     blocks (``mercury_head_nll`` under ``mercury_scoring``) and the only
-    whole ``[8192, V]`` float32 logits are the train pass's. Three quarters
-    of a minute to a minute and a half each."""
+    whole ``[8192, V]`` float32 logits are the train pass's.
+    ``st21b-is-8k``'s operands of the attention come from
+    ``mercury_rope_heads`` (PR 47), three a layer: what the plain forms left
+    in a row's body, the rotation's halves ``f32[8192,28,64]`` (their
+    copies, a ``negate`` of its own), is gone; ``kn2-is-8k``'s latent heads
+    (128 + 64) keep the plain forms. Three quarters of a minute to a minute
+    and a half each."""
+    import re
+
     from perfbench.cell import Cell
 
     fields = Cell(cell).train_config_fields(seed=7, trace=False)
@@ -498,6 +505,21 @@ def test_token_cell_step_fits_one_v5e(v5e_devices, cell):
     assert logits and all("mercury_train" in line
                           and "mercury_scoring" not in line
                           for line in logits)
+    operands = [line for line in text.splitlines()
+                if re.match(r"\s*%mercury_rope_heads\S* = ", line)
+                and "tpu_custom_call" in line]
+    if cell == "st21b-is-8k":
+        assert operands and all("mercury_attention" in line
+                                for line in operands)
+        # an instruction of the program or of a loop's body, not one inside
+        # a fusion: "%name = f32[8192,28,64]{...} copy(" and the like
+        halves = [line for line in text.splitlines()
+                  if re.match(r"\s*(ROOT )?%\S+ = f32\[8192,28,64\]\S* "
+                              r"(negate|copy)\(", line)
+                  and "fused_computation" not in line]
+        assert not halves, halves[:3]
+    else:
+        assert not operands
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 0.99 * TOKEN_CELLS[cell]
     assert memory.peak_memory_in_bytes < HBM_LIMIT, memory

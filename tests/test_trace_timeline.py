@@ -221,6 +221,14 @@ def test_each_fit_reports_its_bn_moment_units(traced_events, traced_fit):
         e["args"]["id"] for e in fits]
 
 
+def test_an_image_models_fit_reports_no_operand_sites(traced_events):
+    """``trainer/rope_kernel_sites`` is a decoder's: once a call all the
+    same, with no operand of an attention made either way."""
+    marks = [e["args"] for e in traced_events
+             if e["name"] == "trainer/rope_kernel_sites"]
+    assert [(m["sites"], m["plain_sites"]) for m in marks] == [(0, 0)] * 2
+
+
 @pytest.mark.parametrize("model, counted", [("resnet50", 16), ("resnet18", 0)])
 def test_bn_moment_units_are_the_traced_steps_count(model, counted,
                                                     monkeypatch):
