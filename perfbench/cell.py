@@ -79,9 +79,21 @@ class Cell:
         fields.update(self.config["train_config"])
         fields.update(self.workload.get("train_config", {}))
         # ``--seed`` may pass 2**31; the program's seed feeds numpy and a
-        # jax key, which take 31 bits safely.
-        fields.update(seed=int(seed) % (2 ** 31 - 1), trace=bool(trace))
+        # jax key, which take 31 bits safely. A workload that names a
+        # ``weights_seed`` trains one set of weights on every seed's data
+        # (``data_seed``): the program draws weights, stream and data from
+        # its one ``seed``.
+        fields.update(seed=int(self.workload.get("weights_seed", seed))
+                      % (2 ** 31 - 1), trace=bool(trace))
         return fields
+
+    def data_seed(self, seed: int) -> Optional[int]:
+        """The seed of the run's data where the workload's file fixes the
+        weights' (``weights_seed``): ``--seed`` as the program would have
+        taken it. None where ``--seed`` is the program's one seed."""
+        if "weights_seed" not in self.workload:
+            return None
+        return int(seed) % (2 ** 31 - 1)
 
     @property
     def steps_per_call(self) -> int:

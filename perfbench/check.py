@@ -90,6 +90,13 @@ class Number:
         return (f"[perfbench] check {self.name}: {self.value!r} "
                 f"{self.rule} {self.limit!r} -> {'ok' if self.ok else 'FAIL'}")
 
+    def entry(self) -> Dict[str, Any]:
+        """The same for the result line (JSON has no infinity: a number
+        that is not finite goes as its name)."""
+        value = float(self.value)
+        return {"value": value if math.isfinite(value) else repr(value),
+                "rule": self.rule, "limit": self.limit, "ok": self.ok}
+
 
 def sample_rows(limits: Dict[str, Any]) -> int:
     return int(limits.get("sample_rows", SAMPLE))
@@ -148,8 +155,11 @@ def update_rms(before, after, steps: int) -> float:
     import jax
 
     a, b = jax.tree.leaves(before), jax.tree.leaves(after)
-    total = sum(float(np.sum(np.square(np.asarray(y, np.float64) - x)))
-                for x, y in zip(a, b))
+    total = 0.0
+    for x, y in zip(a, b):      # a leaf at a time, in one float64 array
+        d = np.array(y, np.float64)
+        d -= x
+        total += float(np.sum(np.square(d, out=d)))
     return (total / sum(x.size for x in a)) ** 0.5 / max(steps, 1)
 
 

@@ -214,27 +214,36 @@ def cosine_lr(count: int, peak: float, decay_steps: int) -> float:
 
 
 def adam_update(params, mu, nu, count: int, grads, lr: float,
-                b1: float, b2: float, eps: float):
-    """One Adam update (Kingma & Ba 2015, bias-corrected) on host trees of
-    float32 arrays; ``count`` is the number of updates already applied.
-    Returns ``(params, mu, nu)``."""
+                b1: float, b2: float, eps: float) -> None:
+    """One Adam update (Kingma & Ba 2015, bias-corrected) on the host, in
+    place: ``params``, ``mu``, ``nu`` and ``grads`` are lists of float32
+    leaves in one order, and each leaf of the first three is replaced by
+    its update as soon as that exists, so that no second tree does;
+    ``count`` is the number of updates already applied. A leaf's three new
+    arrays are all that is allocated for it: every other term is written
+    into two buffers of the largest leaf's size (a fresh array of this size
+    costs more in page faults than its arithmetic)."""
     import numpy as np
 
     t = count + 1
-    leaves = jax.tree.leaves
-    tree = jax.tree.structure(params)
-    new_p, new_mu, new_nu = [], [], []
-    for p, m, v, g in zip(leaves(params), leaves(mu), leaves(nu),
-                          leaves(grads)):
-        g = np.asarray(g, np.float32)
-        m = b1 * m + (1.0 - b1) * g
-        v = b2 * v + (1.0 - b2) * np.square(g)
-        step = (m / (1.0 - b1 ** t)) / (np.sqrt(v / (1.0 - b2 ** t)) + eps)
-        new_p.append(np.asarray(p - lr * step, np.float32))
-        new_mu.append(m.astype(np.float32))
-        new_nu.append(v.astype(np.float32))
-    return (jax.tree.unflatten(tree, new_p), jax.tree.unflatten(tree, new_mu),
-            jax.tree.unflatten(tree, new_nu))
+    grads = [np.asarray(g, np.float32) for g in grads]
+    room = max(g.size for g in grads)
+    buffers = np.empty(room, np.float32), np.empty(room, np.float32)
+    for i, g in enumerate(grads):
+        a, b = (buffer[:g.size].reshape(g.shape) for buffer in buffers)
+        m = np.multiply(b1, mu[i], dtype=np.float32)
+        m += np.multiply(1.0 - b1, g, out=a)
+        v = np.multiply(b2, nu[i], dtype=np.float32)
+        np.square(g, out=a)
+        v += np.multiply(1.0 - b2, a, out=a)
+        # step = (m / (1 - b1^t)) / (sqrt(v / (1 - b2^t)) + eps)
+        np.sqrt(np.divide(v, 1.0 - b2 ** t, out=a), out=a)
+        a += eps
+        np.divide(m, 1.0 - b1 ** t, out=b)
+        b /= a
+        params[i] = np.subtract(params[i], np.multiply(lr, b, out=b),
+                                dtype=np.float32)
+        mu[i], nu[i] = m, v
 
 
 def pool_slots(key, perm, cursor: int, pool_size: int):

@@ -40,6 +40,19 @@ under the cosine schedule. Compared:
 
 The draw itself (which rows the uniforms pick) is not replayed: a row
 whose CDF edge moves by a rounding is drawn differently, and rightly so.
+
+**The host's room.** The gaps read of a gradient or a change only its
+leaves' norms, so either side is a vector of one float64 a leaf and no tree:
+whatever is read of a parameter-sized tree is read a leaf at a time, in
+float64 no larger than that leaf, and a tree that has been read is let go
+before the next is made. The replay therefore consumes the recorder's
+steps: ``system_steps`` takes step 2's ``mu`` and the last step's
+``params`` out of them, ``reference_steps`` the start's ``mu`` and ``nu``
+(the reference's own from then on). At the worst instant, right after the
+window, the host holds seven float32 trees of the parameters' size (the
+start's ``params``, ``mu``, ``nu``, step 2's ``mu``, the last ``params``,
+the run's warm and final weights), and five while the reference follows the
+steps.
 """
 
 from __future__ import annotations
@@ -93,6 +106,10 @@ class Recorder:
 
     def __exit__(self, *exc) -> None:
         self.trainer.train_step = self.real
+        # the steps alone outlive the recording: a recorder that kept the
+        # trainer would keep its state on the chip, and the host copy that
+        # each of its arrays caches, through the whole reference check
+        self.trainer = self.real = None
 
     def __call__(self, *args):
         state, metrics = self.real(*args)
@@ -114,36 +131,40 @@ class Recorder:
         return state, metrics
 
 
-def _leaf_norms(tree) -> np.ndarray:
+def _leaf_norms(leaf, *trees) -> np.ndarray:
+    """The float64 norm of ``leaf(*leaves)`` for each leaf of ``trees``
+    (all of one structure): one number a leaf, and of the float64 array
+    that ``leaf`` makes only one at a time."""
     import jax
 
-    return np.array([float(np.linalg.norm(np.asarray(a, np.float64)))
-                     for a in jax.tree.leaves(tree)])
+    return np.array([float(np.linalg.norm(leaf(*leaves)))
+                     for leaves in zip(*map(jax.tree.leaves, trees))])
 
 
-def worst_leaf_gap(program, ref) -> float:
+def _minus(a, b) -> np.ndarray:
+    """``a - b`` of one leaf in float64: one new array."""
+    d = np.array(a, np.float64)
+    d -= b
+    return d
+
+
+def worst_leaf_gap(p: np.ndarray, r: np.ndarray) -> float:
     """|program's norm - reference's norm| of the worst leaf, over the
     reference's norm of that leaf or of the median leaf, whichever is
-    larger (some gradients are all but zero)."""
-    p, r = _leaf_norms(program), _leaf_norms(ref)
+    larger (some gradients are all but zero). ``p``, ``r``: the two sides'
+    leaf norms."""
     over = np.maximum(r, np.median(r))
     if not over.all():      # a reference that does not move at all
         return 0.0 if (p == r).all() else float("inf")
     return float(np.max(np.abs(p - r) / over))
 
 
-def norm_gap(program, ref) -> float:
+def norm_gap(p: np.ndarray, r: np.ndarray) -> float:
     """|program's norm - reference's norm| over the reference's norm, all
-    leaves together."""
-    p = float(np.sqrt(np.sum(np.square(_leaf_norms(program)))))
-    r = float(np.sqrt(np.sum(np.square(_leaf_norms(ref)))))
+    leaves together, from the two sides' leaf norms."""
+    p = float(np.sqrt(np.sum(np.square(p))))
+    r = float(np.sqrt(np.sum(np.square(r))))
     return abs(p - r) / r if r else (0.0 if p == r else float("inf"))
-
-
-def _diff(a, b):
-    import jax
-
-    return jax.tree.map(lambda x, y: np.asarray(x, np.float64) - y, a, b)
 
 
 #: The drawn batch in the state, by position (``PendingBatch``'s fields).
@@ -167,17 +188,22 @@ def _match_rows(rows: np.ndarray, pool: np.ndarray) -> np.ndarray:
 
 # ------------------------------------------------- the two sides of a step
 def system_steps(steps, arch) -> Dict[str, Any]:
-    """What the program made of the replayed steps: each step's loss, the
-    first gradient as the optimizer got it, the parameters' change."""
-    import jax
-
+    """What the program made of the replayed steps: each step's loss, and
+    by the leaf the norm of the first gradient as the optimizer got it and
+    of the parameters' change. Takes step 2's ``mu`` and the last step's
+    ``params`` out of ``steps``: nothing reads them again."""
     b1 = float(arch["adam"]["b1"])
+
+    def first_grad(m1, m0):
+        g = _minus(m1, b1 * m0)
+        g /= 1.0 - b1
+        return g
+
     return dict(
         losses=[s["metrics"]["train/loss"] for s in steps[1:]],
-        grad=jax.tree.map(lambda m1, m0: (np.asarray(m1, np.float64)
-                                          - b1 * m0) / (1.0 - b1),
-                          steps[1]["mu"], steps[0]["mu"]),
-        change=_diff(steps[STEPS]["params"], steps[0]["params"]))
+        grad=_leaf_norms(first_grad, steps[1].pop("mu"), steps[0]["mu"]),
+        change=_leaf_norms(_minus, steps[STEPS].pop("params"),
+                           steps[0]["params"]))
 
 
 def reference_steps(steps, arch, fields, quantize=None,
@@ -185,7 +211,10 @@ def reference_steps(steps, arch, fields, quantize=None,
     """The same of the reference, which follows the recorded batches on
     its own trajectory from the state after the priming step
     (``train_block_rows`` rows of a batch at a time, where the
-    configuration says so)."""
+    configuration says so). Takes the start's ``mu`` and ``nu`` out of
+    ``steps``: they are the reference's own from then on, each leaf let go
+    as its update exists. The start's ``params`` stay (the change's norm
+    and ``reference_weights`` read them)."""
     import jax
 
     start, adam = steps[0], arch["adam"]
@@ -198,24 +227,28 @@ def reference_steps(steps, arch, fields, quantize=None,
     loss_and_grad = reference.make_loss_and_grad(arch, quantize,
                                                  train_block_rows)
     flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731  [W,B]->[WB]
-    params, mu, nu, count = (start["params"], start["mu"], start["nu"],
-                             start["count"])
+    tree, count = jax.tree.structure(start["params"]), start["count"]
+    params = jax.tree.leaves(start["params"])
+    mu, nu = (jax.tree.leaves(start.pop(name)) for name in ("mu", "nu"))
     out: Dict[str, Any] = dict(losses=[])
     for i in range(STEPS):
         batch = steps[i]["pending"]
         loss, grads = loss_and_grad(
-            params, flat(batch[INPUTS]), flat(batch[LABELS]),
-            flat(batch[SCALED_PROBS]))
-        grads = jax.tree.map(np.asarray, grads)
+            jax.tree.unflatten(tree, params), flat(batch[INPUTS]),
+            flat(batch[LABELS]), flat(batch[SCALED_PROBS]))
+        grads = [np.asarray(g) for g in jax.tree.leaves(grads)]
         out["losses"].append(float(loss))
         if i == 0:
-            out["grad"] = grads
-        params, mu, nu = reference.adam_update(
+            out["grad"] = _leaf_norms(lambda g: np.asarray(g, np.float64),
+                                      grads)
+        reference.adam_update(
             params, mu, nu, count, grads,
             reference.cosine_lr(count, peak_lr, decay),
             float(adam["b1"]), float(adam["b2"]), float(adam["eps"]))
+        del grads       # before the next step's gradient comes to the host
         count += 1
-    out["change"] = _diff(params, start["params"])
+    out["change"] = _leaf_norms(_minus, params,
+                                jax.tree.leaves(start["params"]))
     return out
 
 
@@ -262,33 +295,37 @@ def weight_gap(system, ref) -> float:
     return float(np.sqrt(np.mean(np.square(gaps))))
 
 
-def compare(steps: List[Dict[str, Any]], dataset, arch: Dict[str, Any],
-            fields: Dict[str, Any], control: Optional[str] = None,
+def compare(system: Dict[str, Any], steps: List[Dict[str, Any]], dataset,
+            arch: Dict[str, Any], fields: Dict[str, Any],
+            control: Optional[str] = None,
             train_block_rows: Optional[int] = None):
-    """The replay's numbers from a ``Recorder``'s steps: the program
-    against the reference. ``dataset`` is ``(x_train, y_train,
-    shard_indices)`` on the host, rows as ``trainer.dataset`` holds them;
-    ``fields`` the job's ``TrainConfig`` fields (learning rate, schedule
-    length, batch and pool); ``train_block_rows`` the rows the reference
-    scores and differentiates at a time (``check.train_block_rows``; None:
-    the whole pool and the whole batch). With ``control`` (a lower
-    precision) returns a second dict as well: the reference in that
-    precision, put in the program's place."""
+    """The replay's numbers: ``system``, what ``system_steps`` read of a
+    ``Recorder``'s ``steps``, against the reference, which follows those
+    steps now. ``dataset`` is ``(x_train, y_train, shard_indices)`` on the
+    host, rows as ``trainer.dataset`` holds them; ``fields`` the job's
+    ``TrainConfig`` fields (learning rate, schedule length, batch and
+    pool); ``train_block_rows`` the rows the reference scores and
+    differentiates at a time (``check.train_block_rows``; None: the whole
+    pool and the whole batch). With ``control`` (a lower precision) returns
+    a second dict as well: the reference in that precision, put in the
+    program's place; it follows the steps first, from a start of its own
+    that shares the recorded trees, which the float32 reference then
+    consumes."""
     if len(steps) != STEPS + 1:
         raise ValueError(f"recorded {len(steps)} steps, want {STEPS + 1}")
     if steps[0]["pending"] is None:
         raise NotImplementedError(
             "the replay reads the drawn batch from the state's pending "
             "batch: the cell needs pipelined_scoring")
-    system = system_steps(steps, arch)
+    lower_side = (reference_steps([dict(steps[0])] + steps[1:], arch, fields,
+                                  control, train_block_rows)
+                  if control else None)
     ref = reference_steps(steps, arch, fields, None, train_block_rows)
     for i, (a, b) in enumerate(zip(system["losses"], ref["losses"])):
         print(f"[perfbench] replay step {i + 2}: train/loss {a!r} "
               f"reference {b!r}", flush=True)
     out = step_gaps(system, ref)
-    lower = (step_gaps(reference_steps(steps, arch, fields, control,
-                                       train_block_rows), ref)
-             if control else None)
+    lower = step_gaps(lower_side, ref) if control else None
     if int(fields["world_size"]) == 1:
         weights = reference_weights(steps, dataset, arch, fields, None,
                                     train_block_rows)

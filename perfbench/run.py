@@ -7,7 +7,9 @@ on fewer chips than the cell asks for (non-zero exit, no result line).
 
 Set-up (``setup_s``: process start to the first instant of the window)
 builds ``TrainConfig`` from the cell's two files, constructs ``Trainer``
-(weights and the stand-in data come from ``--seed``), drives its first
+(weights and the stand-in data come from ``--seed``; where the cell's file
+names a ``weights_seed``, the weights from that and the data from
+``--seed``), drives its first
 steps through ``fit()`` under the replay's recorder (``replay.py``), and
 warms up through ``fit()`` until a log gate has fired and one whole call
 has run with zero compiles. The window calls
@@ -26,6 +28,7 @@ import time
 _T0 = time.perf_counter()  # process start, as near as this file can see it
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import glob  # noqa: E402
 import os  # noqa: E402
@@ -71,13 +74,25 @@ def find_devices(chips: int, rehearsal: bool) -> Dict[str, Any]:
     return device
 
 
-def build_trainer(fields: Dict[str, Any]):
+def build_trainer(fields: Dict[str, Any], data_seed: Optional[int] = None):
     """``Trainer(TrainConfig(**fields))`` — the system under test. A
-    function of its own so that a test can break what it returns."""
-    from mercury_tpu import TrainConfig
-    from mercury_tpu.train import Trainer
+    function of its own so that a test can break what it returns.
 
-    return Trainer(TrainConfig(**fields))
+    The program draws its weights, its stream and its data from the one
+    ``seed``, and on a routed model the weights decide how many pairs fall
+    on the held experts: the seed then sets the rate's level (PERF.md
+    section 6, PR 48). With ``data_seed`` the data alone is that seed's —
+    the dataset ``TrainConfig(seed=data_seed)`` would have built, handed to
+    ``Trainer`` as a user hands it one — under the weights of
+    ``fields["seed"]``: one model, other documents."""
+    from mercury_tpu import TrainConfig
+    from mercury_tpu.train import Trainer, build_dataset
+
+    config = TrainConfig(**fields)
+    if data_seed is None:
+        return Trainer(config)
+    return Trainer(config, dataset=build_dataset(
+        config, seed_offset=data_seed - config.seed))
 
 
 def _host_copy(tree):
@@ -85,6 +100,24 @@ def _host_copy(tree):
     import numpy as np
 
     return jax.tree.map(lambda a: np.asarray(a), jax.device_get(tree))
+
+
+def _peak_rss() -> str:
+    """The process's peak resident size so far, and the peak of its cgroup
+    where that can be read (the machine ends what passes the cgroup's
+    limit): for the log, no metric."""
+    import resource
+
+    kib = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
+    text = f"{kib / 2 ** 20:.2f} GiB"
+    for path in ("/sys/fs/cgroup/memory.peak",
+                 "/sys/fs/cgroup/memory/memory.max_usage_in_bytes"):
+        try:
+            with open(path) as f:
+                return f"{text} (cgroup {int(f.read()) / 2 ** 30:.2f} GiB)"
+        except (OSError, ValueError):
+            continue
+    return text
 
 
 def _memory_stats(mesh_devices) -> Dict[str, Any]:
@@ -130,16 +163,21 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                                          reference.rows_independent(arch))
     device = find_devices(cell.chips, rehearsal is not None)
     fields = cell.train_config_fields(seed, trace)
+    data_seed = cell.data_seed(seed)
     steps_per_call = cell.steps_per_call
     log_every = int(fields["log_every"])
     say(f"cell={cell.name} seed={seed} seconds={seconds} trace={int(trace)} "
         f"device={device} jax={jax.__version__} compile_cache={cache_dir}")
-    say(f"train_config={json.dumps(fields, sort_keys=True)}")
+    say(f"train_config={json.dumps(fields, sort_keys=True)}"
+        + ("" if data_seed is None else
+           f"; the weights are seed {fields['seed']}'s, the data seed "
+           f"{data_seed}'s"))
 
     # ------------------------------------------------------------- set-up
     losses: List[float] = []
     with CompileMonitor() as setup_monitor:
-        trainer = build_trainer(fields)
+        trainer = (build_trainer(fields) if data_seed is None
+                   else build_trainer(fields, data_seed=data_seed))
     say("trainer built")
     try:
         trainer.logger.add_observer(
@@ -228,42 +266,53 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     finally:
         trainer.close()
     del trainer
+    gc.collect()    # the program's state leaves the chip and the host now
 
     # ------------------------------------------------- the reference check
+    # Whatever is read of a parameter-sized tree is read a leaf at a time
+    # and kept as its leaves' numbers, and the tree let go before the next
+    # is made (README.md, "The check's host footprint"): seven such trees
+    # now, five once the program's side of the replay is read, three when
+    # the reference starts to follow the steps.
     t_ref = time.perf_counter()
+    say(f"the window closed at a peak resident size of {_peak_rss()}")
+    system = replay.system_steps(recorder.steps, arch)
+    window_update_rms = check.update_rms(warm_weights[0], final_weights[0],
+                                         steps)
     block = check.block_rows(limits, x_test.shape[0])
-
-    def ref_side(quantize=None):
-        """(outputs of the sample at the warm weights, loss over the whole
-        test split at the final weights) by the plain reference."""
-        sample = reference.outputs(*warm_weights, x_test[idx], arch, quantize,
-                                   block_rows=check.block_rows(limits))
-        return sample, reference.eval_loss(*final_weights, x_test, y_test,
-                                           arch, quantize, block_rows=block)
-
-    ref_outputs, ref_eval_loss = ref_side()
+    # (outputs of the sample at the warm weights, loss over the whole test
+    # split at the final weights) by the plain reference, and by the control
+    ref_sides = {
+        quantize: (reference.outputs(*warm_weights, x_test[idx], arch,
+                                     quantize,
+                                     block_rows=check.block_rows(limits)),
+                   reference.eval_loss(*final_weights, x_test, y_test, arch,
+                                       quantize, block_rows=block))
+        for quantize in ((None, "fp8") if control else (None,))}
+    del warm_weights, final_weights
+    ref_outputs, ref_eval_loss = ref_sides[None]
     say(f"reference: inference and evaluate sides took "
         f"{time.perf_counter() - t_ref:.2f} s")
-    replayed = replay.compare(recorder.steps, train_split, arch, fields,
-                              "fp8" if control else None, train_block)
+    replayed = replay.compare(system, recorder.steps, train_split, arch,
+                              fields, "fp8" if control else None, train_block)
     if control:
         replayed, replayed_lower = replayed
     numbers = check.numbers(
         limits, system_outputs=system_outputs,
         ref_outputs=ref_outputs, eval_loss=evals[-1].get("test/eval_loss"),
         ref_eval_loss=ref_eval_loss, replay=replayed,
-        window_update_rms=check.update_rms(warm_weights[0],
-                                           final_weights[0], steps),
+        window_update_rms=window_update_rms,
         window_losses=window_losses, steps_counted=steps,
         steps_advanced=advanced, compiles=compiles)
     for n in numbers:
         print(n.line(), flush=True)
     say(f"reference check took {time.perf_counter() - t_ref:.2f} s (not in "
-        f"setup_s); system test/eval_loss {evals[-1].get('test/eval_loss')} "
+        f"setup_s) and ended at a peak resident size of {_peak_rss()}; "
+        f"system test/eval_loss {evals[-1].get('test/eval_loss')} "
         f"reference {ref_eval_loss}")
     extra: Dict[str, Any] = {}
     if control:
-        quant_outputs, quant_eval_loss = ref_side("fp8")
+        quant_outputs, quant_eval_loss = ref_sides["fp8"]
         extra["numbers"] = {n.name: n.value for n in numbers}
         extra["control"] = dict(
             replayed_lower,
@@ -331,7 +380,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         "metrics": {k: {"value": v, "unit": units[k]}
                     for k, v in values.items() if k in units},
         "device": device, **extra,
+        # every number compared beside its limit, last in the line
+        "checks": {n.name: n.entry() for n in numbers},
     }
+    # ... and as the last lines on standard error
+    for n in numbers:
+        print(n.line(), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return result
 

@@ -15,9 +15,10 @@ precision below the bfloat16 the configurations state) put in the program's
 place and compared with the reference in float32. So this script builds the
 trainer, records the replay's steps through ``fit()`` as ``run.py`` does,
 closes the trainer, and then follows the recorded batches with the reference
-in each precision in turn, keeping of each side only its losses and the norm
-of each leaf of its first gradient and of its parameters' change (all that
-``replay.step_gaps`` reads). The trainer then warms up as ``run.py`` warms
+in each precision in turn (each side is its losses and the norm of each leaf
+of its first gradient and of its parameters' change, all that
+``replay.step_gaps`` reads: ``replay.reference_steps`` keeps no more since
+PR 48, and ``perfbench/readings.py`` may fit again). The trainer then warms up as ``run.py`` warms
 it (through the first log gate and one call more; ``--warm 0``: not at all),
 and the inference and evaluate sides are taken at those weights: the ones a
 run's ``logit_gap`` is read at. ``--replay_seeds`` seeds (the first of
@@ -50,18 +51,9 @@ from perfbench.cell import Cell  # noqa: E402
 LOWER = "fp8"
 
 
-def leaf_norms(tree):
-    """Each leaf as the 0-d float64 norm ``replay._leaf_norms`` takes of
-    it: the gaps read nothing else of a gradient or a change."""
-    return jax.tree.map(
-        lambda a: np.float64(np.linalg.norm(np.asarray(a, np.float64))), tree)
-
-
-def worst_leaves(program, ref, top: int = 3) -> None:
-    """Print the ``top`` leaves by ``replay.worst_leaf_gap``'s ratio."""
-    paths = [jax.tree_util.keystr(path) for path, _ in
-             jax.tree_util.tree_leaves_with_path(ref)]
-    p, r = replay._leaf_norms(program), replay._leaf_norms(ref)
+def worst_leaves(paths, p, r, top: int = 3) -> None:
+    """Print the ``top`` leaves by ``replay.worst_leaf_gap``'s ratio, from
+    the two sides' leaf norms."""
     ratio = np.abs(p - r) / np.maximum(r, np.median(r))
     for i in np.argsort(-ratio)[:top]:
         print(f"[control]   {paths[i]}: change {p[i]!r} reference {r[i]!r} "
@@ -73,7 +65,7 @@ def control_of(cell: Cell, seed: int, with_replay: bool, warm: bool):
     train_block = check.train_block_rows(
         limits, reference.rows_independent(arch))
     fields = cell.train_config_fields(seed, False)
-    trainer = run.build_trainer(fields)
+    trainer = run.build_trainer(fields, cell.data_seed(seed))
     try:
         with replay.Recorder(trainer) as recorder:
             trainer.fit(num_epochs=replay.STEPS + 1)
@@ -93,27 +85,25 @@ def control_of(cell: Cell, seed: int, with_replay: bool, warm: bool):
     steps = recorder.steps
     gaps = {}
     if with_replay:
-        sides = {}
-        for precision in (None, LOWER):
-            out = replay.reference_steps(steps, arch, fields, precision,
-                                         train_block)
-            sides[precision] = dict(losses=out["losses"],
-                                    grad=leaf_norms(out.pop("grad")),
-                                    change=leaf_norms(out.pop("change")))
-            del out
-            gc.collect()
+        paths = [jax.tree_util.keystr(path) for path, _ in
+                 jax.tree_util.tree_leaves_with_path(steps[0]["params"])]
+        # the program's side first: it lets two trees go. The reference
+        # consumes the start's moments: the lower precision, which follows
+        # the steps first, gets a start of its own that shares them
+        sound = replay.system_steps(steps, arch)
+        sides = {LOWER: replay.reference_steps(
+            [dict(steps[0])] + steps[1:], arch, fields, LOWER, train_block)}
+        gc.collect()
+        sides[None] = replay.reference_steps(steps, arch, fields, None,
+                                             train_block)
         gaps.update(replay.step_gaps(sides[LOWER], sides[None]))
         # beside them, the program's own three gaps against the same
         # float32 side (what the run prints), with the leaves that decide
         # ``update_norm_gap``: its worst leaf is what a swing is traced to
-        system = replay.system_steps(steps, arch)
-        sound = dict(losses=system["losses"],
-                     grad=leaf_norms(system.pop("grad")),
-                     change=leaf_norms(system.pop("change")))
         print(f"[control] seed {seed}: the program's replayed gaps "
               f"{replay.step_gaps(sound, sides[None])}", flush=True)
         for side in (sound, sides[LOWER]):
-            worst_leaves(side["change"], sides[None]["change"])
+            worst_leaves(paths, side["change"], sides[None]["change"])
     scaled = {p: replay.reference_weights(steps, train_split, arch, fields,
                                           p, train_block)
               for p in (None, LOWER)}

@@ -236,8 +236,8 @@ def test_integer_tokens_pass_through_the_shared_functions():
     assert steps[1]["pending"][replay.INPUTS].dtype == np.int32
     # the pool of the first replayed step, rebuilt: every drawn row is found
     # again by exact match, and its N p_i is the reference's own
-    gaps = replay.compare(steps, (x, y, np.arange(len(x))[None]), TOKENS,
-                          fields)
+    gaps = replay.compare(replay.system_steps(steps, TOKENS), steps,
+                          (x, y, np.arange(len(x))[None]), TOKENS, fields)
     assert gaps["weight_gap"] == pytest.approx(0.0, abs=1e-6)
     assert max(gaps["loss_gap"], gaps["grad_norm_gap"],
                gaps["update_norm_gap"]) < 1e-3, gaps

@@ -71,7 +71,8 @@ TRANSFORMER = {
               "update_norm_gap_limit": 0.05, "weight_gap_limit": 1e-4,
               "window_update_rms_floor": 1e-5},
 }
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "checks"}
 ALL_CHECKS = {"loss_gap", "grad_norm_gap", "update_norm_gap", "logit_gap",
               "eval_loss_gap", "window_update_rms", "nonfinite_losses",
               "steps_advanced", "compiles_in_window"}
@@ -96,11 +97,18 @@ def test_whole_command_tiny(capsys, world):
     check, result line. On one worker the replay rebuilds the pool too."""
     result = run.run_cell(CELLS[0], 2 ** 31 + 5, 0.5, False,
                           rehearsal=_tiny(world_size=world))
-    out = capsys.readouterr().out
+    out, err = capsys.readouterr()
     line = json.loads(out.strip().splitlines()[-1])
     assert set(line) == RESULT_KEYS and line == result
     compared = set(re.findall(r"check (\w+): .* -> ok", out))
     assert compared == ALL_CHECKS | ({"weight_gap"} if world == 1 else set())
+    # each number beside its limit: last in the line, and the last lines of
+    # standard error
+    assert list(line)[-1] == "checks" and set(line["checks"]) == compared
+    assert all(c["ok"] and set(c) == {"value", "rule", "limit", "ok"}
+               for c in line["checks"].values())
+    assert [re.match(r"\[perfbench\] check (\w+): ", e).group(1) for e in
+            err.strip().splitlines()[-len(compared):]] == list(line["checks"])
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["attempted"] % 10 == 0
     want = {m["name"] for m in MANIFEST["end_to_end"]}
@@ -510,6 +518,14 @@ def test_check_a_passes_as_stated_and_fails_a_precision_lower(block):
     limit = (gaps[jnp.bfloat16] * control) ** 0.5
     assert check.Number("logit_gap", gaps[jnp.bfloat16], limit).ok
     assert not check.Number("logit_gap", control, limit).ok
+
+
+def test_a_number_that_is_not_finite_still_makes_a_json_line():
+    entry = check.Number("weight_gap", float("inf"), 0.1).entry()
+    assert entry == {"value": "inf", "rule": "<=", "limit": 0.1, "ok": False}
+    assert json.loads(json.dumps(entry, allow_nan=False)) == entry
+    assert check.Number("loss_gap", np.float32(0.5), 1.0).entry()["value"] \
+        == 0.5
 
 
 def test_numbers_fail_one_by_one():

@@ -96,9 +96,12 @@ def test_the_replay_passes_the_size_on(monkeypatch):
     for kept in steps[2:]:          # what the recorder no longer keeps
         del kept["mu"]
     dataset = (x, y, np.arange(len(x))[None])
-    whole = replay.compare(steps, dataset, TOKENS, fields)
+    system = replay.system_steps(steps, TOKENS)
+    # the reference consumes the start's moments: one copy of the steps each
+    whole = replay.compare(system, [dict(s) for s in steps], dataset, TOKENS,
+                           fields)
     seen = _spy_on_forward(monkeypatch, TOKENS)
-    gaps = replay.compare(steps, dataset, TOKENS, fields,
+    gaps = replay.compare(system, steps, dataset, TOKENS, fields,
                           train_block_rows=3)
     assert max(seen) == 3 and 1 in seen     # batch 4 = 3 + 1, pool 12
     assert gaps["weight_gap"] == pytest.approx(0.0, abs=1e-6)
